@@ -60,8 +60,8 @@ def run(quick: bool = False) -> dict:
         )
 
     result = {
-        "config": dict(spec=hardware.DEFAULT.name,
-                       hardware=plan_mod.hardware_hash(hardware.DEFAULT),
+        "config": dict(spec=hardware.TPU_V5E.name,
+                       hardware=plan_mod.hardware_hash(hardware.TPU_V5E),
                        measure="analytical", archs=archs),
         "rows": rows,
         "plans": plans,

@@ -13,7 +13,7 @@ from benchmarks.common import fmt_row
 from repro import hardware
 from repro.kernels.flat_gemm import pick_bk, pick_bn
 
-SPEC = hardware.DEFAULT
+SPEC = hardware.TPU_V5E
 
 
 def eq5_ratio(m: int, k: int, bn: int) -> float:

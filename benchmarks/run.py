@@ -4,7 +4,8 @@
 write ``BENCH_*.quick.json`` sidecars and an ``artifacts/
 bench_results.quick.json`` aggregate — they never overwrite the committed
 full-mode ``BENCH_*.json`` artifacts, and every artifact carries a
-``"mode"`` field recording which sweep produced it.
+``"mode"`` field recording which sweep produced it. A module that raises
+is recorded in the aggregate and makes the run exit non-zero.
 """
 from __future__ import annotations
 
@@ -12,6 +13,7 @@ import argparse
 import json
 import os
 import time
+import traceback
 
 
 def main() -> int:
@@ -37,6 +39,7 @@ def main() -> int:
                             scheduler_sweep, weight_quant)
 
     results = {}
+    failed = []
     for name, mod in [
         ("attention_softmax", attention_softmax),
         ("flat_gemm_sweep", flat_gemm_sweep),
@@ -57,9 +60,11 @@ def main() -> int:
         t0 = time.time()
         try:
             results[name] = mod.run(quick=args.quick)
-        except Exception as e:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001 - record, run the rest
+            traceback.print_exc()
             print(f"[FAIL] {name}: {e!r}")
             results[name] = {"error": repr(e)}
+            failed.append(name)
         print(f"  [{name} done in {time.time()-t0:.1f}s]")
 
     results["mode"] = "quick" if args.quick else "full"
@@ -67,6 +72,9 @@ def main() -> int:
     with open(out, "w") as f:
         json.dump(results, f, indent=2, default=str)
     print(f"\nall benchmarks done -> {out}")
+    if failed:
+        print(f"{len(failed)} benchmark module(s) failed: {', '.join(failed)}")
+        return 1
     return 0
 
 

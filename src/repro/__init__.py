@@ -1,4 +1,3 @@
 """repro — FlashDecoding++ on TPU: a JAX + Pallas training/inference framework."""
-from repro.distributed import shardmap_compat  # noqa: F401  (jax.shard_map alias)
 
 __version__ = "0.1.0"

@@ -124,7 +124,7 @@ def terms_from(
     *, arch: str, shape: str, mesh: str, chips: int,
     cost: ProbeCost, model_flops: float,
     per_device_bytes: Optional[int] = None,
-    spec: hardware.HardwareSpec = hardware.DEFAULT,
+    spec: hardware.HardwareSpec = hardware.TPU_V5E,
 ) -> RooflineTerms:
     compute_s = cost.flops / (chips * spec.peak_flops_bf16)
     memory_s = cost.bytes_accessed / (chips * spec.hbm_bw)

@@ -124,7 +124,7 @@ def _mem_time(m_eff: int, k: int, n: int, dtype_bytes: int,
 def predict_time(
     impl: Impl, m: int, k: int, n: int, *,
     dtype_bytes: int = 2,
-    spec: hardware.HardwareSpec = hardware.DEFAULT,
+    spec: hardware.HardwareSpec = hardware.TPU_V5E,
 ) -> float:
     """Roofline-style time estimate for one (M,K,N) GEMM per implementation.
 
@@ -160,7 +160,7 @@ def predict_flat_gemm_time(
     m: int, k: int, n: int, *,
     weight_dtype: str = "bf16",
     dtype_bytes: int = 2,
-    spec: hardware.HardwareSpec = hardware.DEFAULT,
+    spec: hardware.HardwareSpec = hardware.TPU_V5E,
 ) -> float:
     """ImplB roofline with the weight stream priced at its stored width.
 
@@ -261,7 +261,7 @@ def find_inflections(
     k: int, n: int, *,
     measure: MeasureFn | None = None,
     m_sweep: Iterable[int] = M_SWEEP,
-    spec: hardware.HardwareSpec = hardware.DEFAULT,
+    spec: hardware.HardwareSpec = hardware.TPU_V5E,
 ) -> DispatchEntry:
     """The paper's decision flow: sweep M, find M₁ (A→B) and M₂ (B→C)."""
     measure = measure or (
@@ -296,7 +296,7 @@ _GRID_STEP_OVERHEAD_S = 5e-7
 def predict_decode_time(
     block_k: int, s: int, kv_dim: int, *,
     dtype_bytes: int = 2,
-    spec: hardware.HardwareSpec = hardware.DEFAULT,
+    spec: hardware.HardwareSpec = hardware.TPU_V5E,
 ) -> float:
     """Roofline time for one decode-attention call at KV length ``s``.
 
@@ -316,7 +316,7 @@ def predict_decode_time(
 
 def find_block_k(
     s: int, kv_dim: int, *,
-    spec: hardware.HardwareSpec = hardware.DEFAULT,
+    spec: hardware.HardwareSpec = hardware.TPU_V5E,
     candidates: Iterable[int] = BLOCK_K_CANDIDATES,
 ) -> int:
     """Pick the decode KV grid block for a representative KV length."""
@@ -340,7 +340,7 @@ CHUNK_THRESHOLD_CANDIDATES = (1024, 2048, 4096, 8192, 16384, 32768)
 def find_chunk_threshold(
     num_heads: int, *,
     dtype_bytes: int = 4,
-    spec: hardware.HardwareSpec = hardware.DEFAULT,
+    spec: hardware.HardwareSpec = hardware.TPU_V5E,
     budget_frac: float = 0.25,
 ) -> int:
     """Largest sequence length whose materialized per-sequence (H, S, S)
@@ -372,7 +372,7 @@ def predict_chunk_prefill_time(
     page_size: int = 64,
     dtype_bytes: int = 2,
     kv_dtype: str = "bf16",
-    spec: hardware.HardwareSpec = hardware.DEFAULT,
+    spec: hardware.HardwareSpec = hardware.TPU_V5E,
 ) -> float:
     """Roofline time for the *KV side* of one whole chunked-prefill
     admission of a ``prompt_len`` prompt (the q-side GEMMs are identical
@@ -418,7 +418,7 @@ def find_fused_threshold(
     chunk: int = 64,
     page_size: int = 64,
     kv_dtype: str = "bf16",
-    spec: hardware.HardwareSpec = hardware.DEFAULT,
+    spec: hardware.HardwareSpec = hardware.TPU_V5E,
 ) -> int:
     """Smallest prompt length at which the fused chunk path beats the
     dense gather (table provisioned at ``max_seq``); prompts below it keep
@@ -444,7 +444,7 @@ def find_chunk_block(
     max_seq: int, kv_dim: int, *,
     page_size: int = 64,
     kv_dtype: str = "bf16",
-    spec: hardware.HardwareSpec = hardware.DEFAULT,
+    spec: hardware.HardwareSpec = hardware.TPU_V5E,
     candidates: Iterable[int] = CHUNK_BLOCK_CANDIDATES,
 ) -> int:
     """Pick the prefill chunk size for the fused path at a representative
@@ -475,7 +475,7 @@ def find_chunk_block(
 # ---------------------------------------------------------------------------
 
 def group_stage_overhead(
-    spec: hardware.HardwareSpec = hardware.DEFAULT, *,
+    spec: hardware.HardwareSpec = hardware.TPU_V5E, *,
     batch: int = 8, q_heads: int = 16, head_dim: int = 128,
 ) -> float:
     """Fixed cost of the extra grouped-attention stage per decode step,
@@ -492,7 +492,7 @@ def group_stage_overhead(
 
 # evaluated once at the defaults the group-threshold sweep targets
 # (steady decode: full slot batch, qwen2-class head shape)
-_GROUP_STAGE_OVERHEAD_S = group_stage_overhead(hardware.DEFAULT)
+_GROUP_STAGE_OVERHEAD_S = group_stage_overhead(hardware.TPU_V5E)
 
 
 def predict_group_decode_time(
@@ -501,7 +501,7 @@ def predict_group_decode_time(
     page_size: int = 64,
     dtype_bytes: int = 2,
     kv_dtype: str = "bf16",
-    spec: hardware.HardwareSpec = hardware.DEFAULT,
+    spec: hardware.HardwareSpec = hardware.TPU_V5E,
 ) -> float:
     """Roofline time for the KV side of one decode step over one
     shared-prefix group (the q-side work is identical across modes and
@@ -541,7 +541,7 @@ def find_group_threshold(
     max_prefix_pages: int = 64,
     tail_pages: int = 1,
     kv_dtype: str = "bf16",
-    spec: hardware.HardwareSpec = hardware.DEFAULT,
+    spec: hardware.HardwareSpec = hardware.TPU_V5E,
 ) -> int:
     """Smallest ``members * prefix_pages`` product at which grouped
     decode beats per-row prefix re-reads — the dispatch floor the slot
@@ -621,7 +621,7 @@ def param_bytes(cfg: ModelConfig, weight_dtype: str = "bf16", *,
 
 def predict_swap_time(
     pages: int, page_bytes: int, *,
-    spec: hardware.HardwareSpec = hardware.DEFAULT,
+    spec: hardware.HardwareSpec = hardware.TPU_V5E,
 ) -> float:
     """Roofline time to promote ``pages`` demoted KV pages back to the
     device pool: one bulk host→device copy over the PCIe-class link plus
@@ -636,7 +636,7 @@ def predict_reprefill_time(
     dtype_bytes: int = 2,
     kv_dtype: str = "bf16",
     weight_dtype: str = "bf16",
-    spec: hardware.HardwareSpec = hardware.DEFAULT,
+    spec: hardware.HardwareSpec = hardware.TPU_V5E,
 ) -> float:
     """Roofline time to *recompute* ``positions`` KV positions through
     the chunked-prefill path — the cost a re-admission pays for every
@@ -683,7 +683,7 @@ def find_swap_threshold(
     max_pages: int = 64,
     kv_dtype: str = "bf16",
     weight_dtype: str = "bf16",
-    spec: hardware.HardwareSpec = hardware.DEFAULT,
+    spec: hardware.HardwareSpec = hardware.TPU_V5E,
 ) -> int:
     """Smallest demoted-span page count at which promoting (bulk
     host→device copy) beats re-prefilling the same span — the
@@ -739,7 +739,7 @@ def predict_fusion_time(
     m: int = 1,
     dtype_bytes: int = 2,
     weight_dtype: str = "bf16",
-    spec: hardware.HardwareSpec = hardware.DEFAULT,
+    spec: hardware.HardwareSpec = hardware.TPU_V5E,
 ) -> float:
     """Roofline time for one decode tick at a fusion granularity.
 
@@ -770,7 +770,7 @@ def find_decode_fusion(
     cfg: ModelConfig, *,
     m: int = 1,
     weight_dtype: str = "bf16",
-    spec: hardware.HardwareSpec = hardware.DEFAULT,
+    spec: hardware.HardwareSpec = hardware.TPU_V5E,
 ) -> str:
     """Cheapest decode-tick granularity for this model (ties break toward
     the earlier, simpler mode in ``FUSION_MODES`` order: split < fused <
@@ -792,7 +792,7 @@ def find_weight_dtype(
     cfg: ModelConfig, *,
     m: int = 1,
     tol_budget: float | None = None,
-    spec: hardware.HardwareSpec = hardware.DEFAULT,
+    spec: hardware.HardwareSpec = hardware.TPU_V5E,
     candidates: Iterable[str] = WEIGHT_DTYPE_CANDIDATES,
 ) -> str:
     """Fastest GEMM weight storage precision under the accuracy guard.

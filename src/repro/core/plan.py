@@ -545,7 +545,7 @@ class ExecutionPlan:
         path: str,
         *,
         cfg: Optional[ModelConfig] = None,
-        spec: Optional[hardware.HardwareSpec] = hardware.DEFAULT,
+        spec: Optional[hardware.HardwareSpec] = hardware.TPU_V5E,
         strict: bool = True,
     ) -> "ExecutionPlan":
         """Load a plan, rejecting stale artifacts.
@@ -649,7 +649,7 @@ def _resolve_measure(measure: MeasureLike):
 
 def tune(
     cfg: ModelConfig,
-    spec: hardware.HardwareSpec = hardware.DEFAULT,
+    spec: hardware.HardwareSpec = hardware.TPU_V5E,
     *,
     measure: MeasureLike = "analytical",
     backend: str = "xla",
@@ -746,7 +746,7 @@ def tune(
 
 def default_plan_path(
     cfg: ModelConfig,
-    spec: hardware.HardwareSpec = hardware.DEFAULT,
+    spec: hardware.HardwareSpec = hardware.TPU_V5E,
     root: str = "plans",
 ) -> str:
     """The versioned artifact location: ``plans/<arch>-<hw>.json``."""

@@ -1,10 +1,13 @@
-"""Hardware descriptions used by the roofline model and the heuristic dataflow.
+"""Hardware descriptions: roofline constants, kernel VMEM limits, and the
+table that maps a JAX device to its spec.
 
-The TARGET platform is TPU v5e; this container executes on CPU (kernels are
-validated with ``interpret=True``), so every performance decision in the
-framework is driven by these constants rather than wall-clock measurements.
-A real-hardware timing hook exists in :mod:`repro.core.dispatch` for when the
-framework runs on actual TPUs.
+The serving path runs its Pallas kernels on a TPU v5e. The analytical
+decision flows in :mod:`repro.core.dispatch` take a :class:`HardwareSpec`
+as their tuning target, and the kernels size their tiles against the
+spec's scoped-VMEM limit. :func:`for_device` picks the spec of the chip a
+run is on by ``device_kind``; a device that is not in the table is an
+error, never a silent v5e. CPU runs (tests, interpret mode) name
+:data:`TPU_V5E` explicitly as the target they emulate.
 """
 from __future__ import annotations
 
@@ -28,6 +31,9 @@ class HardwareSpec:
       ici_links: number of ICI links per chip taking part in a 2D torus.
       hbm_bytes: HBM capacity per chip.
       vmem_bytes: VMEM (on-chip vector memory) capacity per core.
+      vmem_limit_bytes: the scoped-VMEM limit every Pallas kernel asks
+        Mosaic for (``CompilerParams(vmem_limit_bytes=...)``); Mosaic's
+        own default is far below the physical VMEM.
       mxu_dim: systolic array dimension (128 for all current TPUs).
       lane: vector lane count (last-dim tiling atom).
       sublane_f32 / sublane_bf16: second-minor tiling atom per dtype.
@@ -42,6 +48,7 @@ class HardwareSpec:
     ici_links: int
     hbm_bytes: int
     vmem_bytes: int
+    vmem_limit_bytes: int
     mxu_dim: int = 128
     lane: int = 128
     sublane_f32: int = 8
@@ -50,9 +57,16 @@ class HardwareSpec:
     def sublane(self, dtype_bytes: int) -> int:
         return {4: self.sublane_f32, 2: self.sublane_bf16, 1: 32}.get(dtype_bytes, 8)
 
+    @property
+    def tile_budget(self) -> int:
+        """Bytes a kernel's tile picker may claim for its double-buffered
+        input tiles and accumulators: half the scoped limit, leaving the
+        rest for output buffers, scratch and Mosaic's internal use."""
+        return self.vmem_limit_bytes // 2
 
-# Roofline constants mandated by the assignment: 197 TFLOP/s bf16 per chip,
-# 819 GB/s HBM, ~50 GB/s/link ICI.
+
+# Roofline constants: 197 TFLOP/s bf16 per chip, 819 GB/s HBM, ~50 GB/s/link
+# ICI (Google Cloud documentation, "TPU v5e").
 TPU_V5E = HardwareSpec(
     name="tpu-v5e",
     peak_flops_bf16=197e12,
@@ -63,9 +77,31 @@ TPU_V5E = HardwareSpec(
     ici_links=4,  # 2D torus: 4 links (x+, x-, y+, y-)
     hbm_bytes=16 * 2**30,
     vmem_bytes=128 * 2**20,
+    vmem_limit_bytes=64 * 2**20,
 )
 
-DEFAULT = TPU_V5E
+#: ``jax.Device.device_kind`` -> spec, for every chip the kernels support.
+BY_DEVICE_KIND = {
+    "TPU v5 lite": TPU_V5E,
+}
+
+
+class UnknownDeviceError(RuntimeError):
+    """The run's device has no entry in :data:`BY_DEVICE_KIND`."""
+
+
+def for_device(device) -> HardwareSpec:
+    """The spec of ``device`` (a ``jax.Device``), looked up by its
+    ``device_kind``. Raises :class:`UnknownDeviceError` for any kind not
+    in the table — including the CPU, which only runs kernels in
+    interpret mode against an explicitly named target."""
+    try:
+        return BY_DEVICE_KIND[device.device_kind]
+    except KeyError:
+        raise UnknownDeviceError(
+            f"no HardwareSpec for device_kind {device.device_kind!r} "
+            f"(platform {device.platform!r}); known kinds: "
+            f"{sorted(BY_DEVICE_KIND)}") from None
 
 
 def matmul_flops(m: int, n: int, k: int) -> int:

@@ -21,8 +21,10 @@ Two softmax schemes, mirroring :mod:`repro.kernels.decode_attention`:
     scheme (Fig. 4(b)); the recompute target and paper baseline.
 
 Layout: q ``(B, C, HQ, D)`` is regrouped to ``(B, HK, C·G, D)`` so the
-grouped query heads of one KV head ride together — each page step is two
-MXU matmuls, ``(C·G, D) x (D, PS)`` and ``(C·G, PS) x (PS, D)``. Chunk-
+grouped query heads of one KV head ride together. The grid steps over
+``(b, page)``: each step DMAs one whole ``(PS, HK, D)`` page and runs two
+MXU matmuls per KV head, ``(C·G, D) x (D, PS)`` and ``(C·G, PS) x (PS, D)``
+(the page layout is shared with the paged decode kernels). Chunk-
 local causality is masked in-kernel: query row ``r`` sits at absolute
 position ``lengths[b] + r // G`` and sees keys at positions ``<=`` its
 own (the chunk's KV must already be scattered into the pool, exactly the
@@ -42,7 +44,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import merge
-from repro.kernels import pltpu_compat  # noqa: F401  (pltpu.CompilerParams alias)
+from repro.kernels.decode_attention import (page_kv, page_specs,
+                                            scale_operands, stat_rows)
 
 
 def _chunk_mask(cg: int, ps: int, groups: int, length, page_idx):
@@ -57,9 +60,9 @@ def _chunk_mask(cg: int, ps: int, groups: int, length, page_idx):
 def _paged_chunk_kernel(
     bt_ref,       # (B, NB) int32 scalar-prefetch (consumed by index maps)
     len_ref,      # (B,) int32 scalar-prefetch — lengths *before* the chunk
-    q_ref,        # (1, 1, C*G, D)
-    k_ref,        # (1, PS, 1, D) — physical page bt[b, i]
-    v_ref,        # (1, PS, 1, D)
+    q_ref,        # (1, HK, C*G, D)
+    k_ref,        # (1, PS, HK, D) — physical page bt[b, i]
+    v_ref,        # (1, PS, HK, D)
     *rest,        # [ks_ref, vs_ref,] out_ref, stat_ref, acc, den, msc
     phi: float,
     scale: float,
@@ -68,52 +71,50 @@ def _paged_chunk_kernel(
     groups: int,
     quantized: bool = False,
 ):
-    if quantized:
-        ks_ref, vs_ref = rest[0], rest[1]   # (1, 1) f32 step of page bt[b,i]
-        rest = rest[2:]
-    out_ref, stat_ref, acc_ref, den_ref, msc_ref = rest
+    steps = rest[:2] if quantized else ()
+    out_ref, stat_ref, acc_ref, den_ref, msc_ref = rest[len(steps):]
+    hk = q_ref.shape[1]
 
     b_idx = pl.program_id(0)
-    i_idx = pl.program_id(2)
-    n_i = pl.num_programs(2)
+    i_idx = pl.program_id(1)
+    n_i = pl.num_programs(1)
 
     @pl.when(i_idx == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         den_ref[...] = jnp.zeros_like(den_ref)
-        msc_ref[...] = jnp.full_like(msc_ref, -jnp.inf)
+        for h in range(hk):
+            msc_ref[h] = -jnp.inf
 
     length = len_ref[b_idx]
 
     # pages wholly past the chunk's last query position carry no valid key
     @pl.when(i_idx * page_size < length + chunk)
     def _accum():
-        q = q_ref[0, 0].astype(jnp.float32) * scale      # (CG, D)
-        k = k_ref[0, :, 0].astype(jnp.float32)           # (PS, D)
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        if quantized:
-            k = k * ks_ref[0, 0]
-            v = v * vs_ref[0, 0]
+        for h in range(hk):
+            q = q_ref[0, h].astype(jnp.float32) * scale  # (CG, D)
+            k, v = page_kv(k_ref, v_ref, steps, h)       # (PS, D)
 
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                                # (CG, PS)
-        valid = _chunk_mask(s.shape[0], page_size, groups, length, i_idx)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )                                            # (CG, PS)
+            valid = _chunk_mask(s.shape[0], page_size, groups, length, i_idx)
 
-        acc, den, msc = merge.unified_accumulate(
-            acc_ref[...], den_ref[...], msc_ref[0, 0], s - phi, v, valid
-        )
-        acc_ref[...] = acc
-        den_ref[...] = den
-        msc_ref[0, 0] = msc
+            acc, den, msc = merge.unified_accumulate(
+                acc_ref[h], den_ref[h], msc_ref[h], s - phi, v, valid
+            )
+            acc_ref[h] = acc
+            den_ref[h] = den
+            msc_ref[h] = msc
 
     @pl.when(i_idx == n_i - 1)
     def _fin():
-        # guard_zero: fully-masked rows -> 0 (callers drop them)
-        out = merge.finalize(acc_ref[...], den_ref[...], guard_zero=True)
-        out_ref[0, 0] = out.astype(out_ref.dtype)
-        stat_ref[0, 0] = msc_ref[0, 0]
+        for h in range(hk):
+            # guard_zero: fully-masked rows -> 0 (callers drop them)
+            out = merge.finalize(acc_ref[h], den_ref[h], guard_zero=True)
+            out_ref[0, h] = out.astype(out_ref.dtype)
+        stat_rows(stat_ref, msc_ref, hk)
 
 
 def _paged_chunk_kernel_sync(
@@ -126,14 +127,13 @@ def _paged_chunk_kernel_sync(
     groups: int,
     quantized: bool = False,
 ):
-    if quantized:
-        ks_ref, vs_ref = rest[0], rest[1]
-        rest = rest[2:]
-    out_ref, acc_ref, den_ref, m_ref = rest
+    steps = rest[:2] if quantized else ()
+    out_ref, acc_ref, den_ref, m_ref = rest[len(steps):]
+    hk = q_ref.shape[1]
 
     b_idx = pl.program_id(0)
-    i_idx = pl.program_id(2)
-    n_i = pl.num_programs(2)
+    i_idx = pl.program_id(1)
+    n_i = pl.num_programs(1)
 
     @pl.when(i_idx == 0)
     def _init():
@@ -145,31 +145,30 @@ def _paged_chunk_kernel_sync(
 
     @pl.when(i_idx * page_size < length + chunk)
     def _accum():
-        q = q_ref[0, 0].astype(jnp.float32) * scale
-        k = k_ref[0, :, 0].astype(jnp.float32)
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        if quantized:
-            k = k * ks_ref[0, 0]
-            v = v * vs_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        valid = _chunk_mask(s.shape[0], page_size, groups, length, i_idx)
-        s = jnp.where(valid, s, -jnp.inf)
+        for h in range(hk):
+            q = q_ref[0, h].astype(jnp.float32) * scale
+            k, v = page_kv(k_ref, v_ref, steps, h)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32
+            )
+            valid = _chunk_mask(s.shape[0], page_size, groups, length, i_idx)
+            s = jnp.where(valid, s, -jnp.inf)
 
-        # ---- the synchronized partial-softmax update T1 removes ----
-        acc, den, m_new = merge.sync_accumulate(
-            acc_ref[...], den_ref[...], m_ref[:, :1], s, v, valid=valid
-        )
-        acc_ref[...] = acc
-        den_ref[...] = den
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+            # ---- the synchronized partial-softmax update T1 removes ----
+            acc, den, m_new = merge.sync_accumulate(
+                acc_ref[h], den_ref[h], m_ref[h][:, :1], s, v, valid=valid
+            )
+            acc_ref[h] = acc
+            den_ref[h] = den
+            m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
 
     @pl.when(i_idx == n_i - 1)
     def _fin():
-        # guard_zero: fully-masked rows -> 0 (callers drop them)
-        out = merge.finalize(acc_ref[...], den_ref[...], guard_zero=True)
-        out_ref[0, 0] = out.astype(out_ref.dtype)
+        for h in range(hk):
+            # guard_zero: fully-masked rows -> 0 (callers drop them)
+            out = merge.finalize(acc_ref[h], den_ref[h], guard_zero=True)
+            out_ref[0, h] = out.astype(out_ref.dtype)
 
 
 def _regroup_q(q: jax.Array, hk: int):
@@ -192,41 +191,27 @@ def _ungroup_out(out: jax.Array, c: int, g: int):
 
 def _chunk_grid_spec(b, hk, nb, cg, d, ps, unified: bool,
                      quantized: bool = False):
-    page_spec = pl.BlockSpec(
-        (1, ps, 1, d), lambda b_, h_, i_, bt, ln: (bt[b_, i_], 0, h_, 0))
-    common_in = [
-        pl.BlockSpec((1, 1, cg, d),
-                     lambda b_, h_, i_, bt, ln: (b_, h_, 0, 0)),
-        page_spec,
-        page_spec,
+    head_block = pl.BlockSpec((1, hk, cg, d),
+                              lambda b_, i_, bt, ln: (b_, 0, 0, 0))
+    in_specs = [head_block] + page_specs(
+        ps, hk, d, lambda b_, i_, bt, ln: bt[b_, i_], quantized=quantized)
+    scratch = [
+        pltpu.VMEM((hk, cg, d), jnp.float32),
+        pltpu.VMEM((hk, cg, 128), jnp.float32),
     ]
-    if quantized:
-        step_spec = pl.BlockSpec(
-            (1, 1), lambda b_, h_, i_, bt, ln: (bt[b_, i_], h_))
-        common_in += [step_spec, step_spec]
-    out_spec = pl.BlockSpec((1, 1, cg, d),
-                            lambda b_, h_, i_, bt, ln: (b_, h_, 0, 0))
     if unified:
         out_specs = [
-            out_spec,
-            pl.BlockSpec((1, 1), lambda b_, h_, i_, bt, ln: (b_, h_)),
+            head_block,
+            pl.BlockSpec((1, hk, 128), lambda b_, i_, bt, ln: (b_, 0, 0)),
         ]
-        scratch = [
-            pltpu.VMEM((cg, d), jnp.float32),
-            pltpu.VMEM((cg, 128), jnp.float32),
-            pltpu.SMEM((1, 1), jnp.float32),
-        ]
+        scratch.append(pltpu.SMEM((hk,), jnp.float32))
     else:
-        out_specs = out_spec
-        scratch = [
-            pltpu.VMEM((cg, d), jnp.float32),
-            pltpu.VMEM((cg, 128), jnp.float32),
-            pltpu.VMEM((cg, 128), jnp.float32),
-        ]
+        out_specs = head_block
+        scratch.append(pltpu.VMEM((hk, cg, 128), jnp.float32))
     return pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, hk, nb),
-        in_specs=common_in,
+        grid=(b, nb),
+        in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=scratch,
     )
@@ -270,8 +255,7 @@ def paged_chunk_attention_unified_max(
                                  quantized=quantized)
     operands = [qg, k_pool, v_pool]
     if quantized:
-        operands += [k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32)]
+        operands += scale_operands(k_scale, v_scale)
     kernel = functools.partial(
         _paged_chunk_kernel, phi=phi, scale=scale, page_size=ps,
         chunk=c, groups=g, quantized=quantized)
@@ -280,15 +264,15 @@ def paged_chunk_attention_unified_max(
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, hk, c * g, d), q.dtype),
-            jax.ShapeDtypeStruct((b, hk), jnp.float32),
+            jax.ShapeDtypeStruct((b, hk, 128), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
       *operands)
-    return _ungroup_out(out, c, g), stat
+    return _ungroup_out(out, c, g), stat[..., 0]
 
 
 def paged_chunk_attention_sync(
@@ -317,8 +301,7 @@ def paged_chunk_attention_sync(
                                  quantized=quantized)
     operands = [qg, k_pool, v_pool]
     if quantized:
-        operands += [k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32)]
+        operands += scale_operands(k_scale, v_scale)
     kernel = functools.partial(
         _paged_chunk_kernel_sync, scale=scale, page_size=ps,
         chunk=c, groups=g, quantized=quantized)
@@ -327,7 +310,7 @@ def paged_chunk_attention_sync(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hk, c * g, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),

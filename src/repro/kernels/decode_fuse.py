@@ -39,8 +39,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pltpu_compat  # noqa: F401  (pltpu.CompilerParams alias)
-
+from repro import hardware
 from repro.kernels.flat_gemm import pick_bk, pick_bn, round_up
 
 
@@ -124,7 +123,9 @@ def _ingest_kernel(x_ref, scale_ref, wq_ref, wk_ref, wv_ref,
         v = accv.astype(outv_ref.dtype) + bv_ref[...]
         if use_rope:
             half = head_dim // 2
-            ih = jax.lax.broadcasted_iota(jnp.float32, (1, half), 1)
+            # Mosaic's iota is integer-only: build int32, then cast
+            ih = jax.lax.broadcasted_iota(jnp.int32, (1, half), 1).astype(
+                jnp.float32)
             freq = theta ** (-ih / half)
             ang = pos_ref[...] * freq            # (M, half)
             cos, sin = jnp.cos(ang), jnp.sin(ang)
@@ -156,6 +157,7 @@ def decode_ingest_fused(
     wk_scale: jax.Array | None = None,   # (HK*Dh,) f32 -> wk is codes
     wv_scale: jax.Array | None = None,
     block_k: int = 0,
+    spec: hardware.HardwareSpec = hardware.TPU_V5E,
     interpret: bool = False,
 ):
     """Fused rmsnorm → QKV → bias → rope. Returns flat q (M, HQ*Dh) and
@@ -190,12 +192,11 @@ def decode_ingest_fused(
         bv = jnp.pad(bv, (0, nkp - nk))
 
     bk = block_k or pick_bk(m_pad, nqp + 2 * nkp, d,
-                            dtype_bytes=dtype_bytes)
+                            dtype_bytes=dtype_bytes, spec=spec)
     # the working set holds three double-buffered weight streams, the
     # resident x + normed-x scratch, and three f32 accumulators — halve
     # B_K until it fits the same budget the single-GEMM picker assumed
-    from repro import hardware
-    budget = hardware.DEFAULT.vmem_bytes // 4
+    budget = spec.tile_budget
     kp = round_up(d, bk)
     while bk > 128 and (
             2 * bk * (nqp + 2 * nkp) * dtype_bytes
@@ -258,6 +259,7 @@ def decode_ingest_fused(
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=spec.vmem_limit_bytes,
         ),
         interpret=interpret,
     )(*operands)
@@ -304,6 +306,7 @@ def oproj_residual_fused(
     w_scale: jax.Array | None = None,   # (D,) f32 -> wo is quantized codes
     block_n: int = 0,
     block_k: int = 0,
+    spec: hardware.HardwareSpec = hardware.TPU_V5E,
     interpret: bool = False,
 ) -> jax.Array:
     """resid + o @ wo with the residual add fused into the GEMM epilogue."""
@@ -318,8 +321,8 @@ def oproj_residual_fused(
         o = jnp.pad(o, ((0, m_pad - m), (0, 0)))
         resid = jnp.pad(resid, ((0, m_pad - m), (0, 0)))
 
-    bn = block_n or pick_bn(m_pad, n, k, dtype_bytes=dtype_bytes)
-    bk = block_k or pick_bk(m_pad, bn, k, dtype_bytes=dtype_bytes)
+    bn = block_n or pick_bn(m_pad, n, k, dtype_bytes=dtype_bytes, spec=spec)
+    bk = block_k or pick_bk(m_pad, bn, k, dtype_bytes=dtype_bytes, spec=spec)
     if n % bn:
         pad_n = bn - n % bn
         wo = jnp.pad(wo, ((0, 0), (0, pad_n)))
@@ -353,6 +356,7 @@ def oproj_residual_fused(
         scratch_shapes=[pltpu.VMEM((m_pad, bn), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=spec.vmem_limit_bytes,
         ),
         interpret=interpret,
     )(*operands)
@@ -421,6 +425,7 @@ def ffn_norm_fused(
     wu_scale: jax.Array | None = None,  # (F,) f32 -> w_up is codes
     block_n: int = 0,
     block_k: int = 0,
+    spec: hardware.HardwareSpec = hardware.TPU_V5E,
     interpret: bool = False,
 ) -> jax.Array:
     """Fused rmsnorm → gate/up GEMMs → act(g)*u. Returns (M, F) in
@@ -438,12 +443,11 @@ def ffn_norm_fused(
     if m_pad != m:
         x = jnp.pad(x, ((0, m_pad - m), (0, 0)))
 
-    bn = block_n or pick_bn(m_pad, f, d, dtype_bytes=dtype_bytes)
-    bk = block_k or pick_bk(m_pad, bn, d, dtype_bytes=dtype_bytes)
+    bn = block_n or pick_bn(m_pad, f, d, dtype_bytes=dtype_bytes, spec=spec)
+    bk = block_k or pick_bk(m_pad, bn, d, dtype_bytes=dtype_bytes, spec=spec)
     # two double-buffered weight streams + resident x and normed-x +
     # two f32 accumulators — shrink blocks until the set fits
-    from repro import hardware
-    budget = hardware.DEFAULT.vmem_bytes // 4
+    budget = spec.tile_budget
     kp = round_up(d, bk)
 
     def _working_set(bn_, bk_, kp_):
@@ -500,6 +504,7 @@ def ffn_norm_fused(
             # the N dim must run sequentially too: every N block reads
             # the normed-x scratch written at grid step (0, 0)
             dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=spec.vmem_limit_bytes,
         ),
         interpret=interpret,
     )(*operands)

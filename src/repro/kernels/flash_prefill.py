@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pltpu_compat  # noqa: F401  (pltpu.CompilerParams alias)
-
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 256
 
@@ -60,7 +58,7 @@ def _prefill_kernel_async(
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         den_ref[...] = jnp.zeros_like(den_ref)
-        msc_ref[...] = jnp.full_like(msc_ref, -jnp.inf)
+        msc_ref[0, 0] = -jnp.inf
 
     q = q_ref[0, 0].astype(jnp.float32) * scale           # (BQ, D)
     k = k_ref[0, 0].astype(jnp.float32)                   # (BK, D)
@@ -84,7 +82,8 @@ def _prefill_kernel_async(
     @pl.when(ki == n_k - 1)
     def _fin():
         out_ref[0, 0] = (acc_ref[...] / den_ref[:, :1]).astype(out_ref.dtype)
-        stat_ref[0, 0] = msc_ref[0, 0]
+        stat_ref[0, 0, 0] = jnp.full(stat_ref.shape[3:], msc_ref[0, 0],
+                                     stat_ref.dtype)
 
 
 def _prefill_kernel_sync(
@@ -149,6 +148,8 @@ def flash_prefill(
 
     Returns ``out`` (sync mode) or ``(out, stat)`` (unified-max mode) where
     ``stat: (B, HQ)`` is the max centered logit for the overflow fallback.
+    Each (head, query block) writes its own lane-dense stat row, reduced
+    over query blocks here.
     """
     b, sq, hq, d = q.shape
     _, sk, hk, _ = k.shape
@@ -190,11 +191,13 @@ def flash_prefill(
             in_specs=[q_spec, kv_spec, kv_spec],
             out_specs=[
                 out_spec,
-                pl.BlockSpec((1, 1), lambda b_, h_, q_, k_: (b_, h_)),
+                pl.BlockSpec((1, 1, 1, 1, 128),
+                             lambda b_, h_, q_, k_: (b_, h_, q_, 0, 0)),
             ],
             out_shape=[
                 jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
-                jax.ShapeDtypeStruct((b, hq), jnp.float32),
+                jax.ShapeDtypeStruct((b, hq, sq // block_q, 1, 128),
+                                     jnp.float32),
             ],
             scratch_shapes=[
                 pltpu.VMEM((block_q, d), jnp.float32),
@@ -204,7 +207,7 @@ def flash_prefill(
             compiler_params=params,
             interpret=interpret,
         )(qt, kt, vt)
-        return out.transpose(0, 2, 1, 3), stat
+        return out.transpose(0, 2, 1, 3), stat[..., 0, 0].max(axis=2)
 
     kernel = functools.partial(_prefill_kernel_sync, **common)
     out = pl.pallas_call(

@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pltpu_compat  # noqa: F401  (pltpu.CompilerParams alias)
-
 from repro import hardware
 
 
@@ -35,16 +33,16 @@ def round_up(x: int, m: int) -> int:
 
 
 def pick_bn(m: int, n: int, k: int, *, dtype_bytes: int = 2,
-            spec: hardware.HardwareSpec = hardware.DEFAULT) -> int:
+            spec: hardware.HardwareSpec = hardware.TPU_V5E) -> int:
     """Eq. 5 on TPU: choose B_N balancing grid parallelism vs reuse.
 
     compute/memory ratio of a tile pass ≈ 2·M·K / (K + M·K/B_N + M); larger
     B_N amortizes the A-tile reload, smaller B_N gives more parallel grid
     steps to pipeline. We want at least ``min_grid`` parallel N-steps to keep
-    the pipeline busy, subject to the VMEM budget (double-buffered).
+    the pipeline busy, subject to the spec's tile budget (double-buffered).
     """
     min_grid = 8     # pipeline depth worth of independent N tiles
-    budget = spec.vmem_bytes // 4  # leave room for out tile + other buffers
+    budget = spec.tile_budget
     best = 128
     for bn in (128, 256, 512, 1024, 2048):
         if n % bn:
@@ -60,9 +58,9 @@ def pick_bn(m: int, n: int, k: int, *, dtype_bytes: int = 2,
 
 
 def pick_bk(m: int, bn: int, k: int, *, dtype_bytes: int = 2,
-            spec: hardware.HardwareSpec = hardware.DEFAULT) -> int:
-    """Largest K tile whose double-buffered tiles fit the VMEM budget."""
-    budget = spec.vmem_bytes // 4
+            spec: hardware.HardwareSpec = hardware.TPU_V5E) -> int:
+    """Largest K tile whose double-buffered tiles fit the tile budget."""
+    budget = spec.tile_budget
     best = 128
     for bk in (128, 256, 512, 1024, 2048, 4096):
         if k % bk:
@@ -122,9 +120,12 @@ def flat_gemm(
     block_n: int = 0,
     block_k: int = 0,
     out_dtype=None,
+    spec: hardware.HardwareSpec = hardware.TPU_V5E,
     interpret: bool = False,
 ) -> jax.Array:
-    """Minimal-pad flat GEMM. M is padded to the sublane atom (8), only."""
+    """Minimal-pad flat GEMM. M is padded to the sublane atom (8), only.
+    Tiles are sized against ``spec``'s tile budget, and the kernel asks
+    Mosaic for ``spec.vmem_limit_bytes`` of scoped VMEM."""
     m, k = x.shape
     k2, n = w.shape
     assert k == k2, (x.shape, w.shape)
@@ -135,8 +136,8 @@ def flat_gemm(
     if m_pad != m:
         x = jnp.pad(x, ((0, m_pad - m), (0, 0)))
 
-    bn = block_n or pick_bn(m_pad, n, k, dtype_bytes=dtype_bytes)
-    bk = block_k or pick_bk(m_pad, bn, k, dtype_bytes=dtype_bytes)
+    bn = block_n or pick_bn(m_pad, n, k, dtype_bytes=dtype_bytes, spec=spec)
+    bk = block_k or pick_bk(m_pad, bn, k, dtype_bytes=dtype_bytes, spec=spec)
     # pad N/K up to tile multiples if the caller passed odd sizes
     if n % bn:
         w = jnp.pad(w, ((0, 0), (0, bn - n % bn)))
@@ -168,6 +169,7 @@ def flat_gemm(
         scratch_shapes=[pltpu.VMEM((m_pad, bn), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=spec.vmem_limit_bytes,
         ),
         interpret=interpret,
     )(*operands)

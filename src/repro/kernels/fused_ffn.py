@@ -29,8 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pltpu_compat  # noqa: F401  (pltpu.CompilerParams alias)
-
+from repro import hardware
 from repro.kernels.flat_gemm import pick_bk, pick_bn, round_up
 
 
@@ -86,6 +85,7 @@ def fused_ffn_up(
     block_n: int = 0,
     block_k: int = 0,
     out_dtype=None,
+    spec: hardware.HardwareSpec = hardware.TPU_V5E,
     interpret: bool = False,
 ) -> jax.Array:
     """h = act(x @ w_gate) * (x @ w_up), epilogue fused in VMEM."""
@@ -102,13 +102,12 @@ def fused_ffn_up(
     if m_pad != m:
         x = jnp.pad(x, ((0, m_pad - m), (0, 0)))
 
-    bn = block_n or pick_bn(m_pad, n, k, dtype_bytes=dtype_bytes)
-    bk = block_k or pick_bk(m_pad, bn, k, dtype_bytes=dtype_bytes)
+    bn = block_n or pick_bn(m_pad, n, k, dtype_bytes=dtype_bytes, spec=spec)
+    bk = block_k or pick_bk(m_pad, bn, k, dtype_bytes=dtype_bytes, spec=spec)
     # halve B_K if the doubled (two weight streams + two f32 accumulators)
-    # working set would overflow the VMEM budget the single-GEMM picker
+    # working set would overflow the tile budget the single-GEMM picker
     # assumed
-    from repro import hardware
-    budget = hardware.DEFAULT.vmem_bytes // 4
+    budget = spec.tile_budget
     while bk > 128 and (
             2 * (m_pad * bk + 2 * bk * bn) * dtype_bytes
             + 2 * m_pad * bn * 4) > budget:
@@ -152,6 +151,7 @@ def fused_ffn_up(
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=spec.vmem_limit_bytes,
         ),
         interpret=interpret,
     )(*operands)

@@ -14,7 +14,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pltpu_compat  # noqa: F401  (pltpu.CompilerParams alias)
+from repro import hardware
 
 DEFAULT_BLOCK_N = 256
 DEFAULT_BLOCK_K = 512
@@ -66,6 +66,7 @@ def gemv(
     block_n: int = DEFAULT_BLOCK_N,
     block_k: int = DEFAULT_BLOCK_K,
     out_dtype=None,
+    spec: hardware.HardwareSpec = hardware.TPU_V5E,
     interpret: bool = False,
 ) -> jax.Array:
     m, k = x.shape
@@ -103,6 +104,7 @@ def gemv(
         scratch_shapes=[pltpu.VMEM((m, bn), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=spec.vmem_limit_bytes,
         ),
         interpret=interpret,
     )(*operands)

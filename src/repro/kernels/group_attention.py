@@ -3,10 +3,10 @@
 PR 4 deduplicated shared-prefix *storage* (refcounted COW pages); this
 module deduplicates the decode-step *compute* over those pages. Requests
 whose block tables begin with the same run of refcount>1 pages form a
-group; the shared run is read once per ``(group, kv_head)`` instead of
-once per request:
+group; the shared run is read once per group instead of once per
+request:
 
-  * **Stage 1** (:func:`_group_prefix_kernel`): grid ``(NG, HK, LP)`` over
+  * **Stage 1** (:func:`_group_prefix_kernel`): grid ``(NG, LP)`` over
     the *group* block table (scalar-prefetched). Every member's grouped
     query heads ride in one ``(M·G, D)`` tile, so one pass over the prefix
     pages produces every member's partial — emitted raw as unified-max
@@ -34,7 +34,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import merge
-from repro.kernels import pltpu_compat  # noqa: F401  (pltpu.CompilerParams alias)
+from repro.kernels.decode_attention import (page_kv, page_specs,
+                                            scale_operands, stat_rows)
 
 
 class DecodeGroups(NamedTuple):
@@ -61,9 +62,9 @@ def _group_prefix_kernel(
     gt_ref,       # (NG, LP) int32 scalar-prefetch (consumed by index maps)
     plen_ref,     # (NG,) int32 scalar-prefetch — shared tokens per group
     nm_ref,       # (NG,) int32 scalar-prefetch — live members per group
-    q_ref,        # (1, 1, M*G, D) — all members' grouped query heads
-    k_ref,        # (1, PS, 1, D) — physical page gt[g, i]
-    v_ref,        # (1, PS, 1, D)
+    q_ref,        # (1, HK, M*G, D) — all members' grouped query heads
+    k_ref,        # (1, PS, HK, D) — physical page gt[g, i]
+    v_ref,        # (1, PS, HK, D)
     *rest,        # [ks_ref, vs_ref,] num, den, stat, acc, dacc, msc
     phi: float,
     scale: float,
@@ -71,20 +72,21 @@ def _group_prefix_kernel(
     heads_per_kv: int,
     quantized: bool = False,
 ):
-    if quantized:
-        ks_ref, vs_ref = rest[0], rest[1]   # (1, 1) f32 step of page gt[g,i]
-        rest = rest[2:]
-    num_ref, den_ref, stat_ref, acc_ref, dacc_ref, msc_ref = rest
+    steps = rest[:2] if quantized else ()
+    num_ref, den_ref, stat_ref, acc_ref, dacc_ref, msc_ref = \
+        rest[len(steps):]
+    hk = q_ref.shape[1]
 
     g_idx = pl.program_id(0)
-    i_idx = pl.program_id(2)
-    n_i = pl.num_programs(2)
+    i_idx = pl.program_id(1)
+    n_i = pl.num_programs(1)
 
     @pl.when(i_idx == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         dacc_ref[...] = jnp.zeros_like(dacc_ref)
-        msc_ref[...] = jnp.full_like(msc_ref, -jnp.inf)
+        for h in range(hk):
+            msc_ref[h] = -jnp.inf
 
     plen = plen_ref[g_idx]
     nm = nm_ref[g_idx]
@@ -92,71 +94,69 @@ def _group_prefix_kernel(
     # pages past the shared run (incl. every page of padding groups): skip
     @pl.when(i_idx * page_size < plen)
     def _accum():
-        q = q_ref[0, 0].astype(jnp.float32) * scale      # (MG, D)
-        k = k_ref[0, :, 0].astype(jnp.float32)           # (PS, D)
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        if quantized:
-            k = k * ks_ref[0, 0]
-            v = v * vs_ref[0, 0]
+        for h in range(hk):
+            q = q_ref[0, h].astype(jnp.float32) * scale  # (MG, D)
+            k, v = page_kv(k_ref, v_ref, steps, h)       # (PS, D)
 
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                                # (MG, PS)
-        offs = i_idx * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        # padding member slots ride along with clamped (garbage) q rows —
-        # keep them out of the group's shared stat so they can never flip
-        # the overflow fallback
-        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // heads_per_kv
-        valid = jnp.logical_and(offs < plen,    # partial last prefix page
-                                row < nm)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )                                            # (MG, PS)
+            offs = i_idx * page_size + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            # padding member slots ride along with clamped (garbage) q
+            # rows — keep them out of the group's shared stat so they can
+            # never flip the overflow fallback
+            row = jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 0) // heads_per_kv
+            valid = jnp.logical_and(offs < plen,    # partial last page
+                                    row < nm)
 
-        acc, den, msc = merge.unified_accumulate(
-            acc_ref[...], dacc_ref[...], msc_ref[0, 0], s - phi, v, valid
-        )
-        acc_ref[...] = acc
-        dacc_ref[...] = den
-        msc_ref[0, 0] = msc
+            acc, den, msc = merge.unified_accumulate(
+                acc_ref[h], dacc_ref[h], msc_ref[h], s - phi, v, valid
+            )
+            acc_ref[h] = acc
+            dacc_ref[h] = den
+            msc_ref[h] = msc
 
     @pl.when(i_idx == n_i - 1)
     def _fin():
-        num_ref[0, 0] = acc_ref[...]
-        den_ref[0, 0] = dacc_ref[...]
-        stat_ref[0, 0] = msc_ref[0, 0]
+        num_ref[0] = acc_ref[...]
+        den_ref[0] = dacc_ref[...]
+        stat_rows(stat_ref, msc_ref, hk)
 
 
 def _tail_merge_kernel(
     bt_ref,       # (B, NB) int32 scalar-prefetch (consumed by index maps)
     len_ref,      # (B,) int32 scalar-prefetch
     plen_ref,     # (B,) int32 scalar-prefetch — per-row shared tokens
-    q_ref,        # (1, 1, G, D)
-    num_in_ref,   # (1, 1, G, D) f32 — stage-1 partial (zeros for solo rows)
-    den_in_ref,   # (1, 1, G, 128) f32
-    k_ref,        # (1, PS, 1, D)
-    v_ref,        # (1, PS, 1, D)
+    q_ref,        # (1, HK, G, D)
+    num_in_ref,   # (1, HK, G, D) f32 — stage-1 partial (zeros for solo rows)
+    den_in_ref,   # (1, HK, G, 128) f32
+    k_ref,        # (1, PS, HK, D)
+    v_ref,        # (1, PS, HK, D)
     *rest,        # [ks_ref, vs_ref,] out, stat, acc, den, msc
     phi: float,
     scale: float,
     page_size: int,
     quantized: bool = False,
 ):
-    if quantized:
-        ks_ref, vs_ref = rest[0], rest[1]
-        rest = rest[2:]
-    out_ref, stat_ref, acc_ref, den_ref, msc_ref = rest
+    steps = rest[:2] if quantized else ()
+    out_ref, stat_ref, acc_ref, den_ref, msc_ref = rest[len(steps):]
+    hk = q_ref.shape[1]
 
     b_idx = pl.program_id(0)
-    i_idx = pl.program_id(2)
-    n_i = pl.num_programs(2)
+    i_idx = pl.program_id(1)
+    n_i = pl.num_programs(1)
 
     # the merge: seed the accumulators with the prefix partial — the
     # unified-max scheme needs no rescale to continue accumulating
     @pl.when(i_idx == 0)
     def _init():
-        acc_ref[...] = num_in_ref[0, 0]
-        den_ref[...] = den_in_ref[0, 0]
-        msc_ref[...] = jnp.full_like(msc_ref, -jnp.inf)
+        acc_ref[...] = num_in_ref[0]
+        den_ref[...] = den_in_ref[0]
+        for h in range(hk):
+            msc_ref[h] = -jnp.inf
 
     length = len_ref[b_idx]
     plen = plen_ref[b_idx]
@@ -166,34 +166,32 @@ def _tail_merge_kernel(
     @pl.when(jnp.logical_and((i_idx + 1) * page_size > plen,
                              i_idx * page_size < length))
     def _accum():
-        q = q_ref[0, 0].astype(jnp.float32) * scale      # (G, D)
-        k = k_ref[0, :, 0].astype(jnp.float32)           # (PS, D)
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        if quantized:
-            k = k * ks_ref[0, 0]
-            v = v * vs_ref[0, 0]
+        for h in range(hk):
+            q = q_ref[0, h].astype(jnp.float32) * scale  # (G, D)
+            k, v = page_kv(k_ref, v_ref, steps, h)       # (PS, D)
 
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                                # (G, PS)
-        offs = i_idx * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        valid = jnp.logical_and(offs >= plen, offs < length)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )                                            # (G, PS)
+            offs = i_idx * page_size + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            valid = jnp.logical_and(offs >= plen, offs < length)
 
-        acc, den, msc = merge.unified_accumulate(
-            acc_ref[...], den_ref[...], msc_ref[0, 0], s - phi, v, valid
-        )
-        acc_ref[...] = acc
-        den_ref[...] = den
-        msc_ref[0, 0] = msc
+            acc, den, msc = merge.unified_accumulate(
+                acc_ref[h], den_ref[h], msc_ref[h], s - phi, v, valid
+            )
+            acc_ref[h] = acc
+            den_ref[h] = den
+            msc_ref[h] = msc
 
     @pl.when(i_idx == n_i - 1)
     def _fin():
-        # guard_zero: empty batch slots (length 0, no carry) -> 0 rows
-        out = merge.finalize(acc_ref[...], den_ref[...], guard_zero=True)
-        out_ref[0, 0] = out.astype(out_ref.dtype)
-        stat_ref[0, 0] = msc_ref[0, 0]
+        for h in range(hk):
+            # guard_zero: empty batch slots (length 0, no carry) -> 0 rows
+            out = merge.finalize(acc_ref[h], den_ref[h], guard_zero=True)
+            out_ref[0, h] = out.astype(out_ref.dtype)
+        stat_rows(stat_ref, msc_ref, hk)
 
 
 def grouped_paged_decode_attention_unified_max(
@@ -228,13 +226,11 @@ def grouped_paged_decode_attention_unified_max(
     mg = m * g
     scale = scale if scale is not None else d ** -0.5
     quantized = k_scale is not None
-    if quantized:
-        k_scale = k_scale.astype(jnp.float32)
-        v_scale = v_scale.astype(jnp.float32)
+    scales = scale_operands(k_scale, v_scale) if quantized else []
 
     qg = q.reshape(b, hk, g, d)
 
-    # ---- stage 1: shared-prefix partials, one pass per (group, kv_head)
+    # ---- stage 1: shared-prefix partials, one pass per group
     gtables = jnp.minimum(groups.tables, num_pages - 1)
     rows = jnp.clip(groups.member_rows, 0, b - 1).reshape(-1)
     qs = (jnp.take(qg, rows, axis=0)
@@ -242,36 +238,26 @@ def grouped_paged_decode_attention_unified_max(
              .transpose(0, 2, 1, 3, 4)
              .reshape(ng, hk, mg, d))
 
-    s1_page = pl.BlockSpec(
-        (1, ps, 1, d), lambda g_, h_, i_, gt, pn, nm: (gt[g_, i_], 0, h_, 0))
-    s1_in = [
-        pl.BlockSpec((1, 1, mg, d),
-                     lambda g_, h_, i_, gt, pn, nm: (g_, h_, 0, 0)),
-        s1_page,
-        s1_page,
-    ]
-    s1_operands = [qs, k_pool, v_pool]
-    if quantized:
-        s1_step = pl.BlockSpec(
-            (1, 1), lambda g_, h_, i_, gt, pn, nm: (gt[g_, i_], h_))
-        s1_in += [s1_step, s1_step]
-        s1_operands += [k_scale, v_scale]
+    s1_heads = pl.BlockSpec((1, hk, mg, d),
+                            lambda g_, i_, gt, pn, nm: (g_, 0, 0, 0))
+    s1_in = [s1_heads] + page_specs(
+        ps, hk, d, lambda g_, i_, gt, pn, nm: gt[g_, i_], quantized=quantized)
+    s1_operands = [qs, k_pool, v_pool] + scales
 
     s1_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(ng, hk, lp),
+        grid=(ng, lp),
         in_specs=s1_in,
         out_specs=[
-            pl.BlockSpec((1, 1, mg, d),
-                         lambda g_, h_, i_, gt, pn, nm: (g_, h_, 0, 0)),
-            pl.BlockSpec((1, 1, mg, 128),
-                         lambda g_, h_, i_, gt, pn, nm: (g_, h_, 0, 0)),
-            pl.BlockSpec((1, 1), lambda g_, h_, i_, gt, pn, nm: (g_, h_)),
+            s1_heads,
+            pl.BlockSpec((1, hk, mg, 128),
+                         lambda g_, i_, gt, pn, nm: (g_, 0, 0, 0)),
+            pl.BlockSpec((1, hk, 128), lambda g_, i_, gt, pn, nm: (g_, 0, 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((mg, d), jnp.float32),
-            pltpu.VMEM((mg, 128), jnp.float32),
-            pltpu.SMEM((1, 1), jnp.float32),
+            pltpu.VMEM((hk, mg, d), jnp.float32),
+            pltpu.VMEM((hk, mg, 128), jnp.float32),
+            pltpu.SMEM((hk,), jnp.float32),
         ],
     )
     s1_kernel = functools.partial(
@@ -283,10 +269,10 @@ def grouped_paged_decode_attention_unified_max(
         out_shape=[
             jax.ShapeDtypeStruct((ng, hk, mg, d), jnp.float32),
             jax.ShapeDtypeStruct((ng, hk, mg, 128), jnp.float32),
-            jax.ShapeDtypeStruct((ng, hk), jnp.float32),
+            jax.ShapeDtypeStruct((ng, hk, 128), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(gtables.astype(jnp.int32), groups.g_prefix_len.astype(jnp.int32),
@@ -298,44 +284,35 @@ def grouped_paged_decode_attention_unified_max(
     has_pref = groups.prefix_len > 0
     num_b = num.reshape(ng, hk, m, g, d)[gid_c, :, mem_c]       # (B,HK,G,D)
     den_b = den.reshape(ng, hk, m, g, 128)[gid_c, :, mem_c]     # (B,HK,G,128)
-    stat_b = stat1[gid_c]                                       # (B,HK)
+    stat_b = stat1[gid_c, :, 0]                                 # (B,HK)
     num_b = jnp.where(has_pref[:, None, None, None], num_b, 0.0)
     den_b = jnp.where(has_pref[:, None, None, None], den_b, 0.0)
     stat_b = jnp.where(has_pref[:, None], stat_b, -jnp.inf)
 
     # ---- stage 2: private tail, accumulating on top of the carry
     block_tables = jnp.minimum(block_tables, num_pages - 1)
-    s2_page = pl.BlockSpec(
-        (1, ps, 1, d), lambda b_, h_, i_, bt, ln, pn: (bt[b_, i_], 0, h_, 0))
+    s2_heads = pl.BlockSpec((1, hk, g, d),
+                            lambda b_, i_, bt, ln, pn: (b_, 0, 0, 0))
     s2_in = [
-        pl.BlockSpec((1, 1, g, d),
-                     lambda b_, h_, i_, bt, ln, pn: (b_, h_, 0, 0)),
-        pl.BlockSpec((1, 1, g, d),
-                     lambda b_, h_, i_, bt, ln, pn: (b_, h_, 0, 0)),
-        pl.BlockSpec((1, 1, g, 128),
-                     lambda b_, h_, i_, bt, ln, pn: (b_, h_, 0, 0)),
-        s2_page,
-        s2_page,
-    ]
-    s2_operands = [qg, num_b, den_b, k_pool, v_pool]
-    if quantized:
-        s2_step = pl.BlockSpec(
-            (1, 1), lambda b_, h_, i_, bt, ln, pn: (bt[b_, i_], h_))
-        s2_in += [s2_step, s2_step]
-        s2_operands += [k_scale, v_scale]
+        s2_heads,
+        s2_heads,
+        pl.BlockSpec((1, hk, g, 128),
+                     lambda b_, i_, bt, ln, pn: (b_, 0, 0, 0)),
+    ] + page_specs(ps, hk, d, lambda b_, i_, bt, ln, pn: bt[b_, i_],
+                   quantized=quantized)
+    s2_operands = [qg, num_b, den_b, k_pool, v_pool] + scales
     s2_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, hk, nb),
+        grid=(b, nb),
         in_specs=s2_in,
         out_specs=[
-            pl.BlockSpec((1, 1, g, d),
-                         lambda b_, h_, i_, bt, ln, pn: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, 1), lambda b_, h_, i_, bt, ln, pn: (b_, h_)),
+            s2_heads,
+            pl.BlockSpec((1, hk, 128), lambda b_, i_, bt, ln, pn: (b_, 0, 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((g, d), jnp.float32),
-            pltpu.VMEM((g, 128), jnp.float32),
-            pltpu.SMEM((1, 1), jnp.float32),
+            pltpu.VMEM((hk, g, d), jnp.float32),
+            pltpu.VMEM((hk, g, 128), jnp.float32),
+            pltpu.SMEM((hk,), jnp.float32),
         ],
     )
     s2_kernel = functools.partial(
@@ -346,13 +323,13 @@ def grouped_paged_decode_attention_unified_max(
         grid_spec=s2_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, hk, g, d), q.dtype),
-            jax.ShapeDtypeStruct((b, hk), jnp.float32),
+            jax.ShapeDtypeStruct((b, hk, 128), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
       groups.prefix_len.astype(jnp.int32), *s2_operands)
 
-    return out.reshape(b, hq, d), jnp.maximum(stat_b, stat2)
+    return out.reshape(b, hq, d), jnp.maximum(stat_b, stat2[..., 0])

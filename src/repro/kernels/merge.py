@@ -79,9 +79,12 @@ def sync_accumulate(acc, den, m_prev, s, v, *, valid=None):
     (the chunk kernels) and omitted by those that rely on the ``-inf``
     masking alone (the decode kernels) — the two differ bitwise only on
     fully-masked rows. Returns ``(acc, den, m_new)`` with m_new (R, 1).
+    A row with no valid position yet keeps ``m_new == -inf``; its rescale
+    is pinned to 1 (``exp(-inf - -inf)`` would be NaN, and NaN·0 would
+    poison every later merge of the still-empty partial).
     """
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    rescale = jnp.exp(m_prev - m_new)
+    rescale = jnp.exp(jnp.where(m_new == -jnp.inf, 0.0, m_prev - m_new))
     if valid is None:
         e = jnp.exp(s - m_new)
     else:

@@ -8,11 +8,15 @@ doors wrap the fused kernels with the T1 overflow fallback.
 Every wrapper takes exactly one ``plan=`` operand — an
 :class:`~repro.core.plan.ExecutionPlan` (``None`` = the untuned
 ``DEFAULT_PLAN``) deciding backend (``"pallas"`` kernels vs. the XLA
-reference math in ``ref.py`` — the CPU container cannot lower Mosaic, so
-the default plan is XLA and kernels are validated with
-``interpret=True``), softmax scheme, decode ``block_k``, the chunked
-prefill threshold, and whether the ``lax.cond`` overflow-recompute branch
-is emitted. Plans choose *which* implementation runs, never the math.
+reference math in ``ref.py``), softmax scheme, decode ``block_k``, the
+chunked prefill threshold, and whether the ``lax.cond`` overflow-recompute
+branch is emitted. Plans choose *which* implementation runs, never the
+math.
+
+On a TPU the Pallas backend lowers through Mosaic, sized for the chip's
+:class:`~repro.hardware.HardwareSpec`. On the CPU the same kernels run in
+interpret mode against the v5e spec, which is how the tests check them;
+:func:`kernel_target` is the one place that decides which.
 """
 from __future__ import annotations
 
@@ -22,6 +26,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro import hardware
 from repro.config import SoftmaxPhiConfig
 from repro.core.dispatch import Impl
 from repro.core.plan import DEFAULT_PLAN, ExecutionPlan
@@ -43,7 +48,16 @@ from repro.kernels.group_attention import (
     grouped_paged_decode_attention_unified_max,
 )
 
-_INTERPRET = jax.default_backend() == "cpu"
+
+def kernel_target() -> tuple[bool, hardware.HardwareSpec]:
+    """``(interpret, spec)`` for the device the kernels run on, read when
+    a wrapper traces. Interpret mode is for the CPU alone, which emulates
+    the v5e; any other device must be in the hardware table, so an unknown
+    accelerator is an error rather than a silently interpreted run."""
+    device = jax.devices()[0]
+    if device.platform == "cpu":
+        return True, hardware.TPU_V5E
+    return False, hardware.for_device(device)
 
 
 def _unified(phi_cfg: SoftmaxPhiConfig, scheme: str) -> bool:
@@ -81,6 +95,7 @@ def matmul(
     """Plan-dispatched GEMM. x: (..., K), w: (K, N) array or quantized
     ``{"codes", "scale"}`` leaf."""
     mp = (plan or DEFAULT_PLAN).matmul
+    interpret, spec = kernel_target()
     w, w_scale = _wparts(w)
     lead = x.shape[:-1]
     k = x.shape[-1]
@@ -96,9 +111,10 @@ def matmul(
     if mp.backend != "pallas" or impl is Impl.XLA_DOT:
         out = ref.flat_gemm_ref(x2, w, w_scale=w_scale)
     elif impl is Impl.GEMV:
-        out = gemv(x2, w, w_scale=w_scale, interpret=_INTERPRET)
+        out = gemv(x2, w, w_scale=w_scale, spec=spec, interpret=interpret)
     else:
-        out = flat_gemm(x2, w, w_scale=w_scale, interpret=_INTERPRET)
+        out = flat_gemm(x2, w, w_scale=w_scale, spec=spec,
+                        interpret=interpret)
     return out.reshape(*lead, n)
 
 
@@ -114,6 +130,7 @@ def fused_ffn(
     when the plan's ``fused_ffn`` entry says ``fused`` on the Pallas
     backend (kernels/fused_ffn.py), oracle math otherwise."""
     fp = (plan or DEFAULT_PLAN).fused_ffn
+    interpret, spec = kernel_target()
     w_gate, wg_scale = _wparts(w_gate)
     w_up, wu_scale = _wparts(w_up)
     lead = x.shape[:-1]
@@ -124,7 +141,7 @@ def fused_ffn(
         from repro.kernels.fused_ffn import fused_ffn_up
         out = fused_ffn_up(x2, w_gate, w_up, activation=activation,
                            wg_scale=wg_scale, wu_scale=wu_scale,
-                           interpret=_INTERPRET)
+                           spec=spec, interpret=interpret)
     else:
         out = ref.fused_ffn_up_ref(x2, w_gate, w_up, activation=activation,
                                    wg_scale=wg_scale, wu_scale=wu_scale)
@@ -155,6 +172,7 @@ def decode_ingest(
     split-chain composition in ``ref.py`` otherwise). Returns
     q (B,1,HQ,Dh), k/v (B,1,HK,Dh)."""
     fp = (plan or DEFAULT_PLAN).decode_fusion
+    interpret, spec = kernel_target()
     wq, wq_scale = _wparts(wq)
     wk, wk_scale = _wparts(wk)
     wv, wv_scale = _wparts(wv)
@@ -167,7 +185,7 @@ def decode_ingest(
             head_dim=head_dim, rope_theta=rope_theta, eps=eps,
             use_rope=use_rope, bq=bq, bk_bias=bk, bv=bv,
             wq_scale=wq_scale, wk_scale=wk_scale, wv_scale=wv_scale,
-            interpret=_INTERPRET,
+            spec=spec, interpret=interpret,
         )
         return (q.reshape(b, s, num_heads, head_dim),
                 k.reshape(b, s, num_kv_heads, head_dim),
@@ -192,13 +210,14 @@ def oproj_residual(
     the residual add riding its epilogue on the Pallas backend; the
     bit-exact split composition otherwise)."""
     fp = (plan or DEFAULT_PLAN).decode_fusion
+    interpret, spec = kernel_target()
     wo, wo_scale = _wparts(wo)
     if fp.backend == "pallas":
         from repro.kernels.decode_fuse import oproj_residual_fused
         b, s, qd = o.shape
         out = oproj_residual_fused(
             o.reshape(b * s, qd), wo, resid.reshape(b * s, -1),
-            w_scale=wo_scale, interpret=_INTERPRET,
+            w_scale=wo_scale, spec=spec, interpret=interpret,
         )
         return out.reshape(resid.shape)
     return ref.oproj_residual_ref(o, wo, resid, w_scale=wo_scale)
@@ -221,6 +240,7 @@ def ffn_norm(
     — feed to :func:`oproj_residual` with ``w_down`` for the full seam."""
     p = plan or DEFAULT_PLAN
     fp = p.decode_fusion
+    interpret, spec = kernel_target()
     w_gate, wg_scale = _wparts(w_gate)
     w_up, wu_scale = _wparts(w_up)
     if fp.backend == "pallas":
@@ -229,7 +249,8 @@ def ffn_norm(
         out = ffn_norm_fused(
             x.reshape(b * s, d), norm_scale, w_gate, w_up,
             activation=activation, eps=eps,
-            wg_scale=wg_scale, wu_scale=wu_scale, interpret=_INTERPRET,
+            wg_scale=wg_scale, wu_scale=wu_scale, spec=spec,
+            interpret=interpret,
         )
         return out.reshape(b, s, -1)
     return ref.ffn_norm_ref(x, norm_scale, w_gate, w_up,
@@ -265,6 +286,7 @@ def attention_prefill(
     branch probability ≈ 0 — paper §3).
     """
     ap = (plan or DEFAULT_PLAN).attention_prefill
+    interpret = kernel_target()[0]
     unified = _unified(phi_cfg, ap.scheme)
     if ap.backend != "pallas":
         if q.shape[1] * k.shape[1] >= ap.chunk_threshold ** 2:
@@ -278,11 +300,11 @@ def attention_prefill(
     if not unified:
         return flash_prefill(
             q, k, v, causal=causal, unified_max=False,
-            sliding_window=sliding_window, interpret=_INTERPRET,
+            sliding_window=sliding_window, interpret=interpret,
         )
     out, stat = flash_prefill(
         q, k, v, causal=causal, unified_max=True, phi=phi_cfg.phi,
-        sliding_window=sliding_window, interpret=_INTERPRET,
+        sliding_window=sliding_window, interpret=interpret,
     )
     if not ap.fallback:
         return out
@@ -292,7 +314,7 @@ def attention_prefill(
         # paper §3 "Recomputation": rerun with the synchronized scheme
         return flash_prefill(
             q, k, v, causal=causal, unified_max=False,
-            sliding_window=sliding_window, interpret=_INTERPRET,
+            sliding_window=sliding_window, interpret=interpret,
         )
 
     return jax.lax.cond(overflow, recompute, lambda _: out, operand=None)
@@ -318,6 +340,7 @@ def attention_decode(
     the unified-max softmax.
     """
     dp = (plan or DEFAULT_PLAN).attention_decode
+    interpret = kernel_target()[0]
     unified = _unified(phi_cfg, dp.scheme)
     if dp.backend != "pallas":
         if not unified:
@@ -340,11 +363,11 @@ def attention_decode(
     vt = v_cache.transpose(0, 2, 1, 3)
     if not unified:
         return decode_attention_sync(
-            q, kt, vt, lengths, block_k=dp.block_k, interpret=_INTERPRET
+            q, kt, vt, lengths, block_k=dp.block_k, interpret=interpret
         )
     out, stat = decode_attention_unified_max(
         q, kt, vt, lengths, phi=phi_cfg.phi, block_k=dp.block_k,
-        interpret=_INTERPRET,
+        interpret=interpret,
     )
     if not dp.fallback:
         return out
@@ -352,7 +375,7 @@ def attention_decode(
 
     def recompute(_):
         return decode_attention_sync(
-            q, kt, vt, lengths, block_k=dp.block_k, interpret=_INTERPRET
+            q, kt, vt, lengths, block_k=dp.block_k, interpret=interpret
         )
 
     return jax.lax.cond(overflow, recompute, lambda _: out, operand=None)
@@ -400,6 +423,7 @@ def attention_decode_paged(
     the values the Pallas kernels reconstruct per page in VMEM.
     """
     pp = (plan or DEFAULT_PLAN).paged
+    interpret = kernel_target()[0]
     unified = _unified(phi_cfg, pp.scheme)
     if pp.backend != "pallas":
         if k_scale is not None:
@@ -439,18 +463,18 @@ def attention_decode_paged(
         # grouped sync has no kernel — the ungrouped sync kernel is exact
         return paged_decode_attention_sync(
             q, k_pool, v_pool, block_tables, lengths,
-            k_scale=k_scale, v_scale=v_scale, interpret=_INTERPRET
+            k_scale=k_scale, v_scale=v_scale, interpret=interpret
         )
     if groups is not None:
         out, stat = grouped_paged_decode_attention_unified_max(
             q, k_pool, v_pool, block_tables, lengths, groups,
             phi=phi_cfg.phi, k_scale=k_scale, v_scale=v_scale,
-            interpret=_INTERPRET,
+            interpret=interpret,
         )
     else:
         out, stat = paged_decode_attention_unified_max(
             q, k_pool, v_pool, block_tables, lengths, phi=phi_cfg.phi,
-            k_scale=k_scale, v_scale=v_scale, interpret=_INTERPRET,
+            k_scale=k_scale, v_scale=v_scale, interpret=interpret,
         )
     if not pp.fallback:
         return out
@@ -459,7 +483,7 @@ def attention_decode_paged(
     def recompute(_):
         return paged_decode_attention_sync(
             q, k_pool, v_pool, block_tables, lengths,
-            k_scale=k_scale, v_scale=v_scale, interpret=_INTERPRET
+            k_scale=k_scale, v_scale=v_scale, interpret=interpret
         )
 
     return jax.lax.cond(overflow, recompute, lambda _: out, operand=None)
@@ -529,15 +553,16 @@ def attention_chunk_paged(
     construction).
     """
     pp = (plan or DEFAULT_PLAN).paged
+    interpret = kernel_target()[0]
     if pp.backend == "pallas" and pp.gather_chunk == "fused":
         unified = _unified(phi_cfg, pp.scheme)
         if not unified:
             return paged_chunk_attention_sync(
                 q, k_pool, v_pool, block_tables, lengths,
-                k_scale=k_scale, v_scale=v_scale, interpret=_INTERPRET)
+                k_scale=k_scale, v_scale=v_scale, interpret=interpret)
         out, stat = paged_chunk_attention_unified_max(
             q, k_pool, v_pool, block_tables, lengths, phi=phi_cfg.phi,
-            k_scale=k_scale, v_scale=v_scale, interpret=_INTERPRET)
+            k_scale=k_scale, v_scale=v_scale, interpret=interpret)
         if not pp.fallback:
             return out
         overflow = jnp.any(stat > phi_cfg.band[1])
@@ -545,7 +570,7 @@ def attention_chunk_paged(
         def recompute(_):
             return paged_chunk_attention_sync(
                 q, k_pool, v_pool, block_tables, lengths,
-                k_scale=k_scale, v_scale=v_scale, interpret=_INTERPRET)
+                k_scale=k_scale, v_scale=v_scale, interpret=interpret)
 
         return jax.lax.cond(overflow, recompute, lambda _: out, operand=None)
 

@@ -166,25 +166,31 @@ def main() -> int:
 
     from repro import configs
     from repro.core import plan as plan_mod
+    from repro.kernels import ops
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models.api import get_model
     from repro.models.kvlayout import pages_for
     from repro.serving.engine import Engine
     from repro.serving.request import SamplingParams
 
+    enable_compile_cache()
     cfg = configs.get(args.arch)
     if args.smoke:
         cfg = configs.smoke(cfg)
     api = get_model(cfg)
     params = api.init_params(jax.random.PRNGKey(args.seed))
 
+    # plans are tuned for, and checked against, the chip the kernels run
+    # on (the v5e that interpret mode emulates on the CPU)
+    _, spec = ops.kernel_target()
     plan = None
     if args.tune:
-        plan = plan_mod.tune(cfg, page_size=args.page_size)
-        path = args.plan or plan_mod.default_plan_path(cfg)
+        plan = plan_mod.tune(cfg, spec, page_size=args.page_size)
+        path = args.plan or plan_mod.default_plan_path(cfg, spec)
         plan.save(path)
         print(f"tuned plan -> {path}\n  {plan.describe()}")
     elif args.plan:
-        plan = plan_mod.ExecutionPlan.load(args.plan, cfg=cfg)
+        plan = plan_mod.ExecutionPlan.load(args.plan, cfg=cfg, spec=spec)
         print(f"loaded plan {args.plan}\n  {plan.describe()}")
 
     if args.gather_chunk is not None or args.decode_group is not None:

@@ -1,168 +1,32 @@
-"""Hypothesis compatibility shim: use the real package when installed,
-otherwise a minimal deterministic fallback.
+"""The suite's single import point for hypothesis.
 
-The tier-1 suite property-tests the T1 math, the dispatch table, the
-distributed combine, and the block pool. The container does not always ship
-``hypothesis``, and the suite must collect and run either way, so test
-modules import ``given``/``settings``/``st`` from here instead of from
-``hypothesis`` directly.
+Test modules import ``given``/``settings``/``st``/``example`` from here
+instead of from ``hypothesis`` directly, so the determinism rule below
+applies to every property test.
 
-The fallback implements exactly the strategy surface the suite uses
-(``integers``, ``floats``, ``sampled_from``, ``lists``, ``tuples``,
-``booleans``) with a seeded ``random.Random`` per test: examples are
-deterministic across runs, ``max_examples`` is honored, and the first
-failing example is re-raised with the drawn arguments attached. It does no
-shrinking — it is a property *runner*, not a property *search engine* —
-which is the right trade for a smoke tier that must stay fast.
-
-**Determinism contract.** The fallback is always deterministic (seeded per
-test qualname). The real package randomizes its search by default, which
-would make the default lane flaky-by-design, so when ``CI`` is set in the
-environment every profile the suite registers is forced to
+**Determinism contract.** Hypothesis randomizes its search by default,
+which would make the default lane flaky-by-design, so when ``CI`` is set
+in the environment every profile the suite registers is forced to
 ``derandomize=True`` — each run replays the same example sequence. Escape
 hatch for counterexample *hunting*: run locally without ``CI``, or pass
 hypothesis' builtin ``pytest --hypothesis-seed=<n>`` to pin a specific
-randomized search.
+randomized search. Counterexamples worth keeping are pinned with
+``@example`` on the property itself.
 """
 from __future__ import annotations
 
 import os
 
-try:  # pragma: no cover - exercised only when hypothesis is installed
-    from hypothesis import given, settings  # noqa: F401
-    import hypothesis.strategies as st  # noqa: F401
+from hypothesis import example, given, settings  # noqa: F401
+import hypothesis.strategies as st  # noqa: F401
 
-    HAVE_HYPOTHESIS = True
+if os.environ.get("CI"):  # pragma: no cover - CI-lane only
+    _register = settings.register_profile
 
-    if os.environ.get("CI"):  # pragma: no cover - CI-lane only
-        _register = settings.register_profile
+    def _register_derandomized(name, parent=None, **kw):
+        kw.setdefault("derandomize", True)
+        return _register(name, parent=parent, **kw)
 
-        def _register_derandomized(name, parent=None, **kw):
-            kw.setdefault("derandomize", True)
-            return _register(name, parent=parent, **kw)
-
-        settings.register_profile = _register_derandomized
-        settings.register_profile("ci", deadline=None)
-        settings.load_profile("ci")
-except ImportError:
-    import functools
-    import inspect
-    import itertools
-    import random
-
-    HAVE_HYPOTHESIS = False
-
-    class _Strategy:
-        """A draw rule: ``draw(rng) -> value``."""
-
-        def __init__(self, draw):
-            self._draw = draw
-
-        def draw(self, rng: random.Random):
-            return self._draw(rng)
-
-        def map(self, fn):
-            return _Strategy(lambda rng: fn(self.draw(rng)))
-
-        def filter(self, pred, _tries: int = 100):
-            def draw(rng):
-                for _ in range(_tries):
-                    v = self.draw(rng)
-                    if pred(v):
-                        return v
-                raise ValueError("filter predicate never satisfied")
-
-            return _Strategy(draw)
-
-    class _Strategies:
-        @staticmethod
-        def integers(min_value=0, max_value=1 << 30):
-            return _Strategy(lambda rng: rng.randint(min_value, max_value))
-
-        @staticmethod
-        def floats(min_value=0.0, max_value=1.0, **_kw):
-            return _Strategy(lambda rng: rng.uniform(min_value, max_value))
-
-        @staticmethod
-        def booleans():
-            return _Strategy(lambda rng: rng.random() < 0.5)
-
-        @staticmethod
-        def sampled_from(elements):
-            elements = list(elements)
-            return _Strategy(lambda rng: elements[rng.randrange(len(elements))])
-
-        @staticmethod
-        def lists(elements, min_size=0, max_size=10):
-            def draw(rng):
-                n = rng.randint(min_size, max_size)
-                return [elements.draw(rng) for _ in range(n)]
-
-            return _Strategy(draw)
-
-        @staticmethod
-        def tuples(*strategies):
-            return _Strategy(lambda rng: tuple(s.draw(rng) for s in strategies))
-
-    st = _Strategies()
-
-    class settings:  # noqa: N801 - mirror hypothesis' class name
-        _profiles: dict = {"default": {"max_examples": 20}}
-        _active: dict = _profiles["default"]
-
-        def __init__(self, **kw):
-            self._kw = kw
-
-        def __call__(self, fn):
-            fn._compat_settings = self._kw
-            return fn
-
-        @classmethod
-        def register_profile(cls, name: str, **kw):
-            cls._profiles[name] = kw
-
-        @classmethod
-        def load_profile(cls, name: str):
-            cls._active = cls._profiles[name]
-
-    def given(*strategies, **kw_strategies):
-        def deco(fn):
-            @functools.wraps(fn)
-            def wrapper(*args, **kwargs):
-                overrides = getattr(fn, "_compat_settings", {})
-                n = overrides.get(
-                    "max_examples", settings._active.get("max_examples", 20))
-                # the fallback is a smoke runner, not a search engine: cap
-                # the example count so shape-varying draws don't turn into
-                # dozens of fresh XLA compiles per property
-                n = min(n, 10)
-                # stable per-test seed so failures reproduce across runs
-                rng = random.Random(fn.__qualname__)
-                for i in range(n):
-                    drawn = [s.draw(rng) for s in strategies]
-                    drawn_kw = {k: s.draw(rng)
-                                for k, s in kw_strategies.items()}
-                    try:
-                        fn(*args, *drawn, **drawn_kw, **kwargs)
-                    except Exception as e:  # noqa: BLE001
-                        raise AssertionError(
-                            f"property failed on example {i}: "
-                            f"args={drawn!r} kwargs={drawn_kw!r}"
-                        ) from e
-
-            # Hide the drawn parameters from pytest (it would otherwise
-            # try to resolve them as fixtures). Hypothesis binds positional
-            # strategies to the RIGHTMOST params; anything left over is a
-            # real fixture and stays visible.
-            params = list(inspect.signature(fn).parameters.values())
-            keep = params[:len(params) - len(strategies)]
-            keep = [p for p in keep if p.name not in kw_strategies]
-            wrapper.__signature__ = inspect.Signature(keep)
-            del wrapper.__wrapped__  # pytest must not unwrap to fn
-            # counter keeps pytest from deduping parametrized wrappers
-            wrapper._compat_id = next(_COUNTER)
-            return wrapper
-
-        return deco
-
-    _COUNTER = itertools.count()
+    settings.register_profile = _register_derandomized
+    settings.register_profile("ci", deadline=None)
+    settings.load_profile("ci")

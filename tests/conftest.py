@@ -1,24 +1,18 @@
 """Shared fixtures. NOTE: no XLA_FLAGS here — smoke tests must see exactly
 one CPU device (the 512-device override belongs to launch/dryrun.py only;
 multi-device tests spawn subprocesses)."""
-import os
-import tempfile
-
 import jax
 import numpy as np
 import pytest
+
+from repro.launch.compile_cache import enable_compile_cache
 
 jax.config.update("jax_enable_x64", False)
 
 # Persistent XLA compilation cache: tier-1 runtime is compile-dominated
 # (smoke models are tiny), so repeat runs drop most of their wall time.
-_CACHE_DIR = os.environ.get(
-    "REPRO_JAX_CACHE", os.path.join(tempfile.gettempdir(), "repro-jax-cache"))
-try:
-    jax.config.update("jax_compilation_cache_dir", _CACHE_DIR)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
-except Exception:  # pragma: no cover - older jax without the knobs
-    pass
+enable_compile_cache()
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
 
 
 @pytest.fixture(scope="session")

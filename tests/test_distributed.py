@@ -18,17 +18,8 @@ from jax.sharding import PartitionSpec as P
 from repro import configs
 from repro.config import MULTI_POD, SINGLE_POD, MeshConfig
 from repro.distributed import collectives as C
-from repro.distributed import shardmap_compat
 from repro.distributed.sharding import make_rules
 from repro.models.api import get_model
-
-# The old-jax (0.4.x) XLA SPMD partitioner dies in a CHECK
-# (IsManualSubgroup) on partial-manual shard_map — a process ABORT that
-# would kill the whole pytest run, so the tests whose collectives need
-# partial-manual mode are version-gated rather than allowed to fail.
-needs_partial_manual = pytest.mark.skipif(
-    not shardmap_compat.PARTIAL_MANUAL_OK,
-    reason=shardmap_compat.PARTIAL_MANUAL_REASON)
 
 settings.register_profile("fast", max_examples=20, deadline=None)
 settings.load_profile("fast")
@@ -198,7 +189,6 @@ def _run_sub(code: str) -> str:
 
 
 @pytest.mark.slow
-@needs_partial_manual
 def test_crosspod_allreduce_int8_multidevice():
     out = _run_sub("""
         import jax, jax.numpy as jnp, numpy as np
@@ -215,7 +205,6 @@ def test_crosspod_allreduce_int8_multidevice():
 
 
 @pytest.mark.slow
-@needs_partial_manual
 def test_pipeline_forward_and_grad_multidevice():
     out = _run_sub("""
         import jax, jax.numpy as jnp, numpy as np
@@ -337,7 +326,6 @@ def test_seq_sharded_decode_matches_single():
 
 
 @pytest.mark.slow
-@needs_partial_manual
 def test_split_kv_decode_attention_collective_claim():
     """The paper's T1 claim at pod scale: the async (unified-max) combine
     needs exactly ONE all-reduce per decode-attention call; the
@@ -377,7 +365,6 @@ def test_split_kv_decode_attention_collective_claim():
 
 
 @pytest.mark.slow
-@needs_partial_manual
 def test_manual_moe_dispatch_matches_gspmd():
     """_moe_block_manual (dispatch locality by construction) must equal
     the plain GSPMD path in loss AND gradients."""

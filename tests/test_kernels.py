@@ -44,16 +44,31 @@ def test_flat_gemm_matches_oracle(m, k, n, dtype):
 
 def test_flat_gemm_block_pickers_respect_vmem():
     from repro import hardware
-    spec = hardware.DEFAULT
+    spec = hardware.TPU_V5E
     for m in (8, 16, 64):
         for n in (512, 4096, 16384):
             for k in (512, 4096):
-                bn = pick_bn(m, n, k)
-                bk = pick_bk(m, bn, k)
+                bn = pick_bn(m, n, k, spec=spec)
+                bk = pick_bk(m, bn, k, spec=spec)
                 assert n % bn == 0 or bn == n
                 assert k % bk == 0 or bk == k
                 vmem = 2 * (m * bk + bk * bn) * 2 + m * bn * 4
-                assert vmem <= spec.vmem_bytes // 4 or (bn == 128 and bk == 128)
+                assert vmem <= spec.tile_budget or (bn == 128 and bk == 128)
+
+
+def test_hardware_spec_by_device_kind():
+    """The chip path looks its spec up by device_kind: v5e resolves, any
+    other kind (the CPU included) is an error, never a silent v5e."""
+    from types import SimpleNamespace
+
+    from repro import hardware
+    v5e = SimpleNamespace(device_kind="TPU v5 lite", platform="tpu")
+    assert hardware.for_device(v5e) is hardware.TPU_V5E
+    with pytest.raises(hardware.UnknownDeviceError):
+        hardware.for_device(SimpleNamespace(device_kind="TPU v9",
+                                            platform="tpu"))
+    with pytest.raises(hardware.UnknownDeviceError):
+        hardware.for_device(jax.devices()[0])     # the CPU
 
 
 def test_flat_gemm_min_padding_is_8():

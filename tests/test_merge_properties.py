@@ -19,7 +19,7 @@ the algebra those kernels rely on:
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from _hypothesis_compat import example, given, settings, st
 
 from repro.config import SoftmaxPhiConfig
 from repro.kernels import merge
@@ -73,6 +73,7 @@ def test_unified_split_equivalence(seed, split):
 
 
 @given(st.integers(0, 10_000), st.integers(1, 15), st.integers(16, 31))
+@example(seed=9, t1=1, t2=31)   # a row fully masked in [31, 32): NaN before
 def test_sync_split_equivalence(seed, t1, t2):
     """Online-max/LSE: two independently max-stabilized partials merged
     via merge_lse equal the unsplit softmax-attention (any 3 segments:

@@ -34,7 +34,7 @@ def test_json_roundtrip_is_identity(tmp_path):
     assert ExecutionPlan.load(path, cfg=CFG) == TUNED
     # the default artifact location is versioned per (arch, hardware)
     assert plan_mod.default_plan_path(CFG).endswith(
-        f"{CFG.name}-{hardware.DEFAULT.name}.json")
+        f"{CFG.name}-{hardware.TPU_V5E.name}.json")
 
 
 def test_load_rejects_wrong_hardware(tmp_path):

@@ -115,10 +115,12 @@ def test_engine_stop_token_and_finish_reason():
     burns ``max_new_tokens`` budget; the freed slot is reused."""
     rng = np.random.default_rng(0)
     prompt = rng.integers(1, 50, 8).astype(np.int32)
-    # find the greedy continuation, then use its second token as the stop
+    # find the greedy continuation, then stop on its first token that
+    # differs from the first one (a repeat of toks[0] would stop at once)
     cfg, probe = _engine("qwen2-0.5b", num_slots=1, max_seq=128)
-    toks = probe.run([(prompt, SamplingParams(max_new_tokens=4))])[0]
-    stop = toks[1]
+    toks = probe.run([(prompt, SamplingParams(max_new_tokens=10))])[0]
+    j = next(i for i, t in enumerate(toks) if t != toks[0])
+    stop = toks[j]
 
     _, eng = _engine("qwen2-0.5b", num_slots=1, max_seq=128)
     out = eng.run([
@@ -127,8 +129,8 @@ def test_engine_stop_token_and_finish_reason():
                                 include_stop=True)),
         (prompt, SamplingParams(max_new_tokens=10)),
     ])
-    assert out[0] == toks[:1]                    # stop excluded
-    assert out[1] == toks[:2]                    # stop included
+    assert out[0] == toks[:j]                    # stop excluded
+    assert out[1] == toks[:j + 1]                # stop included
     assert len(out[2]) == 10                     # no stop -> full budget
     assert eng.finish_reason(0) is FinishReason.STOP
     assert eng.finish_reason(1) is FinishReason.STOP
