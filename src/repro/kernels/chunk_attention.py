@@ -269,6 +269,7 @@ def paged_chunk_attention_unified_max(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
+        name="paged_chunk_attention_unified_max",
         interpret=interpret,
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
       *operands)
@@ -312,6 +313,7 @@ def paged_chunk_attention_sync(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
+        name="paged_chunk_attention_sync",
         interpret=interpret,
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
       *operands)

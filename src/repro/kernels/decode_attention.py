@@ -156,6 +156,7 @@ def decode_attention_unified_max(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
+        name="decode_attention_unified_max",
         interpret=interpret,
     )(qg, k_cache, v_cache, lens)
     return out.reshape(b, hq, d), stat[..., 0, 0]
@@ -334,9 +335,10 @@ def _paged_decode_kernel(
 
 
 def _paged_decode_call(kernel, q, k_pool, v_pool, block_tables, lengths,
-                       k_scale, v_scale, *, unified: bool, interpret: bool,
-                       **kernel_kw):
-    """Shared pallas_call plumbing of the two paged decode schemes."""
+                       k_scale, v_scale, *, name: str, unified: bool,
+                       interpret: bool, **kernel_kw):
+    """Shared pallas_call plumbing of the two paged decode schemes;
+    ``name`` is the kernel's name in the compiled program."""
     b, hq, d = q.shape
     num_pages, ps, hk, _ = k_pool.shape
     nb = block_tables.shape[1]
@@ -380,6 +382,7 @@ def _paged_decode_call(kernel, q, k_pool, v_pool, block_tables, lengths,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
+        name=name,
         interpret=interpret,
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
       *operands)
@@ -413,7 +416,8 @@ def paged_decode_attention_unified_max(
     d = q.shape[-1]
     return _paged_decode_call(
         _paged_decode_kernel, q, k_pool, v_pool, block_tables, lengths,
-        k_scale, v_scale, unified=True, interpret=interpret, phi=phi,
+        k_scale, v_scale, name="paged_decode_attention_unified_max",
+        unified=True, interpret=interpret, phi=phi,
         scale=scale if scale is not None else d ** -0.5)
 
 
@@ -484,7 +488,8 @@ def paged_decode_attention_sync(
     d = q.shape[-1]
     return _paged_decode_call(
         _paged_decode_kernel_sync, q, k_pool, v_pool, block_tables, lengths,
-        k_scale, v_scale, unified=False, interpret=interpret,
+        k_scale, v_scale, name="paged_decode_attention_sync",
+        unified=False, interpret=interpret,
         scale=scale if scale is not None else d ** -0.5)
 
 
@@ -537,6 +542,7 @@ def decode_attention_sync(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
+        name="decode_attention_sync",
         interpret=interpret,
     )(qg, k_cache, v_cache, lens)
     return out.reshape(b, hq, d)

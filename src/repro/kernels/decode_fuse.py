@@ -261,6 +261,7 @@ def decode_ingest_fused(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=spec.vmem_limit_bytes,
         ),
+        name="decode_ingest_fused",
         interpret=interpret,
     )(*operands)
     return outq[:m, :nq], outk[:m, :nk], outv[:m, :nk]
@@ -358,6 +359,7 @@ def oproj_residual_fused(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=spec.vmem_limit_bytes,
         ),
+        name="oproj_residual_fused",
         interpret=interpret,
     )(*operands)
     return out[:m, :n]
@@ -506,6 +508,7 @@ def ffn_norm_fused(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=spec.vmem_limit_bytes,
         ),
+        name="ffn_norm_fused",
         interpret=interpret,
     )(*operands)
     return out[:m, :f]

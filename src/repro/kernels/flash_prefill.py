@@ -205,6 +205,7 @@ def flash_prefill(
                 pltpu.SMEM((1, 1), jnp.float32),
             ],
             compiler_params=params,
+            name="flash_prefill_unified_max",
             interpret=interpret,
         )(qt, kt, vt)
         return out.transpose(0, 2, 1, 3), stat[..., 0, 0].max(axis=2)
@@ -222,6 +223,7 @@ def flash_prefill(
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
         compiler_params=params,
+        name="flash_prefill_sync",
         interpret=interpret,
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3)

@@ -171,6 +171,7 @@ def flat_gemm(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=spec.vmem_limit_bytes,
         ),
+        name="flat_gemm",
         interpret=interpret,
     )(*operands)
     return out[:m, :n]

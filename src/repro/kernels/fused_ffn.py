@@ -153,6 +153,7 @@ def fused_ffn_up(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=spec.vmem_limit_bytes,
         ),
+        name="fused_ffn_up",
         interpret=interpret,
     )(*operands)
     return out[:m, :n]

@@ -106,6 +106,7 @@ def gemv(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=spec.vmem_limit_bytes,
         ),
+        name="gemv",
         interpret=interpret,
     )(*operands)
     return out[:, :n]

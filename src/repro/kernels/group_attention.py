@@ -274,6 +274,7 @@ def grouped_paged_decode_attention_unified_max(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
+        name="grouped_decode_prefix",
         interpret=interpret,
     )(gtables.astype(jnp.int32), groups.g_prefix_len.astype(jnp.int32),
       groups.num_members.astype(jnp.int32), *s1_operands)
@@ -328,6 +329,7 @@ def grouped_paged_decode_attention_unified_max(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
+        name="grouped_decode_tail",
         interpret=interpret,
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
       groups.prefix_len.astype(jnp.int32), *s2_operands)

@@ -112,6 +112,8 @@ from repro.serving.request import (FinishReason, Phase, RequestState,
 from repro.serving.sampling import sample
 from repro.serving.scheduler import Scheduler, get_scheduler
 
+_span = jax.profiler.TraceAnnotation
+
 PROMPT_BUCKET = 64
 DEFAULT_PREFILL_CHUNK = 64
 DEFAULT_PAGE_SIZE = 64
@@ -121,12 +123,12 @@ PromptLike = Union[np.ndarray, Sequence[int]]
 
 @dataclasses.dataclass
 class EngineStats:
-    """Counters for the CLI summary line and the scheduler benchmarks.
-    (Tick count lives on ``Engine.ticks`` — the loop's one clock.)"""
+    """Counters for the CLI summary line and the benchmark, read as
+    differences between two snapshots. (Tick count lives on
+    ``Engine.ticks`` — the loop's one clock.)"""
 
-    admitted: int = 0
-    finished: int = 0
-    aborted: int = 0
+    host_syncs: int = 0              # device→host reads: one per sampled
+    #                                  token, one per leaf of a tier gather
     preemptions: int = 0
     peak_pages_used: int = 0
     # prefix sharing (all zero unless Engine(prefix_sharing=True))
@@ -356,50 +358,56 @@ class Engine:
 
         # the single jitted pair: the layout's block-table operand (None
         # for dense) is just another argument, so dense and paged engines
-        # trace the same lambdas
-        self._decode = jax.jit(
-            lambda p, t, c, bt, le, po: self.api.decode_step(
-                self.ctx, p, t, c, le, block_tables=bt, positions=po),
-            donate_argnums=(2,),
-        )
-        self._chunk = jax.jit(
-            lambda p, t, cl, c, bt, le: self.api.prefill_chunk(
-                self.ctx, p, t, cl, c, le, block_tables=bt),
-            donate_argnums=(3,),
-        ) if self.prefill_chunk else None
+        # trace the same functions. Each program is a named function, so
+        # the compiled module (and the device trace) reads
+        # ``jit_decode_step``, ``jit_prefill_chunk``, ...
+        def decode_step(p, t, c, bt, le, po):
+            return self.api.decode_step(self.ctx, p, t, c, le,
+                                        block_tables=bt, positions=po)
+
+        def prefill_chunk(p, t, cl, c, bt, le):
+            return self.api.prefill_chunk(self.ctx, p, t, cl, c, le,
+                                          block_tables=bt)
+
         # COW fork: copy one page's (layers, page_size, kv_heads, head_dim)
         # slab to a privately owned destination page (donated in-place
         # update; src/dst trace as scalars so every fork reuses one
         # compile)
-        self._copy_page = jax.jit(
-            lambda c, src, dst: jax.tree.map(
-                lambda a: a.at[:, dst].set(a[:, src]), c),
-            donate_argnums=(0,),
-        ) if cache_kind == "paged" else None
+        def copy_page(c, src, dst):
+            return jax.tree.map(lambda a: a.at[:, dst].set(a[:, src]), c)
+
         # tiered promotion: scatter a batch of host slabs (stacked to
         # (layers, n, page_size, kv_heads, head_dim) per leaf) into fresh
         # tier-0 pages in one donated update; padding rows carry the OOB
         # sentinel destination and are dropped, so slab batches share a
         # pow2 family of compiles
-        self._promote_upload = jax.jit(
-            lambda c, s, d: jax.tree.map(
-                lambda a, b: a.at[:, d].set(
-                    b.astype(a.dtype), mode="drop"), c, s),
-            donate_argnums=(0,),
-        ) if self.tiers is not None else None
+        def promote_upload(c, s, d):
+            return jax.tree.map(
+                lambda a, b: a.at[:, d].set(b.astype(a.dtype), mode="drop"),
+                c, s)
+
         # prefix-shared grouped decode: when the tuned plan asks for it
         # (and refcounted sharing is on so groups can exist), decode ticks
-        # with a qualifying group dispatch through a second jitted lambda
-        # that threads the DecodeGroups operand down to the attention op
+        # with a qualifying group dispatch through a second program that
+        # threads the DecodeGroups operand down to the attention op
+        def grouped_decode_step(p, t, c, bt, le, gr, po):
+            return self.api.decode_step(self.ctx, p, t, c, le,
+                                        block_tables=bt, decode_groups=gr,
+                                        positions=po)
+
+        self._decode = jax.jit(decode_step, donate_argnums=(2,))
+        self._chunk = (jax.jit(prefill_chunk, donate_argnums=(3,))
+                       if self.prefill_chunk else None)
+        self._copy_page = (jax.jit(copy_page, donate_argnums=(0,))
+                           if cache_kind == "paged" else None)
+        self._promote_upload = (jax.jit(promote_upload, donate_argnums=(0,))
+                                if self.tiers is not None else None)
         self._group_decode = (
             cache_kind == "paged" and prefix_sharing
             and self.plan.paged.decode_group == "grouped")
-        self._decode_grouped = jax.jit(
-            lambda p, t, c, bt, le, gr, po: self.api.decode_step(
-                self.ctx, p, t, c, le, block_tables=bt, decode_groups=gr,
-                positions=po),
-            donate_argnums=(2,),
-        ) if self._group_decode else None
+        self._decode_grouped = (
+            jax.jit(grouped_decode_step, donate_argnums=(2,))
+            if self._group_decode else None)
         # one page's K+V slab across all layers — the unit of both the
         # COW copy and the grouped-decode bytes-saved accounting
         self._kv_bytes_per_page = (
@@ -490,7 +498,6 @@ class Engine:
         state.events.append(TokenEvent(
             rid, None, state.generated, finished=True,
             finish_reason=FinishReason.ABORT))
-        self.stats.aborted += 1
         return True
 
     def finish_reason(self, rid: int) -> Optional[FinishReason]:
@@ -553,7 +560,14 @@ class Engine:
     def step(self) -> list[TokenEvent]:
         """Admit + prefill per the scheduler's order, then one decode tick
         (growing/preempting paged sequences first). Returns this tick's
-        token events."""
+        token events.
+
+        Each phase runs inside one profiler span (``engine.admit``,
+        ``engine.prefill``, ``engine.grow``, ``engine.decode``,
+        ``engine.sample``; at most one per phase per step, never one per
+        slot), so a trace taken around the engine puts the device's idle
+        time down to the host work that caused it. With no profiler
+        running a span costs about a microsecond."""
         events = self._admit()
         if not self.by_slot:
             if self.waiting and not events:
@@ -575,7 +589,8 @@ class Engine:
 
     def _admit(self) -> list[TokenEvent]:
         """Offer slots (and prefill pages) to waiting requests in the
-        scheduler's order; prefill the admitted wave in one batch.
+        scheduler's order; prefill the admitted wave in one batch, then
+        sample every admitted row's first token in one pass.
 
         With prefix sharing, admission hands the slot manager the exact
         prefill tokens so the prefix index can map page-aligned shared
@@ -587,6 +602,26 @@ class Engine:
         """
         if not self.waiting:
             return []
+        with _span("engine.admit"):
+            admitted, levels = self._assign()
+        if not admitted:
+            return []
+        with _span("engine.prefill"):
+            if self.prefill_chunk:
+                rows: list[tuple] = []
+                for lv in sorted(set(levels.values())):
+                    rows += self._prefill_chunked(
+                        [(i, s) for i, s in admitted if levels[i] == lv])
+            else:
+                rows = self._prefill_batched(admitted)
+            return self._sample_rows(rows, wrote_kv=False)
+
+    def _assign(self) -> tuple[list[tuple[int, RequestState]],
+                               dict[int, int]]:
+        """Admission bookkeeping: hand slots (and prefix matches) to
+        waiting requests in the scheduler's order, then perform the page
+        promotions and COW forks the wave was promised. Returns the
+        admitted ``(slot, state)`` pairs and each slot's prefill level."""
         admitted: list[tuple[int, RequestState]] = []
         levels: dict[int, int] = {}
         for state in self.scheduler.admission_order(self.waiting):
@@ -601,9 +636,10 @@ class Engine:
                 continue
             state.phase = Phase.PREFILLING
             state.slot = idx
+            if state.admit_time is None:
+                state.admit_time = time.perf_counter()
             self.by_slot[idx] = state
             admitted.append((idx, state))
-            self.stats.admitted += 1
             levels[idx] = 0
             if self.prefix is not None:
                 slot = self.slots.slots[idx]
@@ -624,20 +660,14 @@ class Engine:
                     self.stats.session_hits += 1
                     slot.session_mapped = 0
         if not admitted:
-            return []
+            return admitted, levels
         self.waiting = [s for s in self.waiting if s.slot is None]
         self._note_page_pressure()
         if self.tiers is not None:
             self._apply_pending_promotions(admitted)
         if self.prefix is not None:
             self._apply_pending_forks(admitted)
-        if self.prefill_chunk:
-            events: list[TokenEvent] = []
-            for lv in sorted(set(levels.values())):
-                events += self._prefill_chunked(
-                    [(i, s) for i, s in admitted if levels[i] == lv])
-            return events
-        return self._prefill_batched(admitted)
+        return admitted, levels
 
     def _apply_pending_forks(
             self, admitted: list[tuple[int, RequestState]]) -> None:
@@ -743,8 +773,9 @@ class Engine:
         return bt[:, :bound]
 
     def _prefill_chunked(
-            self, items: list[tuple[int, RequestState]]) -> list[TokenEvent]:
-        """Stream all admitted prompts through the chunk-append path.
+            self, items: list[tuple[int, RequestState]]) -> list[tuple]:
+        """Stream all admitted prompts through the chunk-append path;
+        returns each row's last-token logits for :meth:`_sample_rows`.
 
         Each step processes one ``(num_slots, chunk)`` call: admitted rows
         consume their next chunk, every other slot is a spectator
@@ -769,7 +800,7 @@ class Engine:
         pp = self.plan.paged
         fused = (self.pool is not None and pp.gather_chunk == "fused"
                  and max(plens.values()) >= pp.fused_threshold)
-        final_logits: dict[int, jax.Array] = {}
+        final: dict[int, jax.Array] = {}
         n_steps = max(-(-(plens[idx] - progress[idx]) // c)
                       for idx, _ in items)
         for _ in range(n_steps):
@@ -797,18 +828,13 @@ class Engine:
                 if chunk_lens[idx]:
                     progress[idx] += int(chunk_lens[idx])
                     if progress[idx] == plens[idx]:
-                        final_logits[idx] = logits[idx:idx + 1]
+                        final[idx] = logits
         for idx, _state in items:
             # full prompt pages now hold real KV: flip this slot's pending
             # index entries so later arrivals (and later levels of this
             # wave) may map them
             self.slots.commit_prefix(idx, seqs[idx])
-        events = []
-        for idx, state in items:
-            tok = int(self._sample(final_logits[idx], state)[0])
-            state.phase = Phase.RUNNING
-            events.append(self._emit(idx, state, tok, wrote_kv=False))
-        return events
+        return [(idx, state, final[idx], idx) for idx, state in items]
 
     # -- batched single-shot prefill (recurrent/ring families) ------------------
 
@@ -817,17 +843,17 @@ class Engine:
             spec = self.api.cache_spec(
                 DenseLayout(self.num_slots, self.max_seq))
 
-            def fn(params, tokens, lengths):
+            def prefill(params, tokens, lengths):
                 cache = jax.tree.map(
                     lambda s: jnp.zeros(s.shape, s.dtype), spec)
                 return self.api.prefill(
                     self.ctx, params, tokens, lengths, cache)
 
-            self._prefill_cache[padded] = jax.jit(fn)
+            self._prefill_cache[padded] = jax.jit(prefill)
         return self._prefill_cache[padded]
 
     def _prefill_batched(
-            self, items: list[tuple[int, RequestState]]) -> list[TokenEvent]:
+            self, items: list[tuple[int, RequestState]]) -> list[tuple]:
         """One padded prefill call for the whole admission wave; each row's
         cache entry is inserted at its slot index afterwards. Prompts pad
         to a power-of-two bucket (min ``PROMPT_BUCKET``) so distinct tail
@@ -847,8 +873,7 @@ class Engine:
             lens[row] = len(seqs[idx])
         logits, cache_new = self._prefill_fn(padded)(
             self.params, jnp.asarray(toks), jnp.asarray(lens))
-        events = []
-        for row, (idx, state) in enumerate(items):
+        for row, (idx, _state) in enumerate(items):
             row_cache = jax.tree.map(
                 lambda a: jax.lax.dynamic_slice_in_dim(a, row, 1, axis=1),
                 cache_new)
@@ -857,10 +882,8 @@ class Engine:
                     big, small.astype(big.dtype), idx, axis=1),
                 self.cache, row_cache,
             )
-            tok = int(self._sample(logits[row:row + 1], state)[0])
-            state.phase = Phase.RUNNING
-            events.append(self._emit(idx, state, tok, wrote_kv=False))
-        return events
+        return [(idx, state, logits, row)
+                for row, (idx, state) in enumerate(items)]
 
     # -- decode ----------------------------------------------------------------
 
@@ -906,28 +929,30 @@ class Engine:
         self._note_page_pressure()
 
     def _decode_tick(self) -> list[TokenEvent]:
-        self._grow_or_preempt()
+        with _span("engine.grow"):
+            self._grow_or_preempt()
         if not self.by_slot:
             return []
-        lengths = self.slots.lengths_device()
-        positions = self.slots.positions_device()
-        tokens = np.zeros((self.num_slots,), np.int32)
-        for idx, state in self.by_slot.items():
-            tokens[idx] = state.tokens[-1]
-        gplan = self.slots.group_plan(
-            self.plan.paged.group_threshold) if self._group_decode else None
-        if gplan is not None:
-            logits, self.cache = self._decode_grouped(
-                self.params, jnp.asarray(tokens), self.cache,
-                self.slots.block_tables(), lengths, gplan.operands(),
-                positions)
-            self.stats.grouped_requests += gplan.n_grouped
-            self.stats.prefix_kv_bytes_saved += (
-                gplan.pages_deduped * self._kv_bytes_per_page)
-        else:
-            logits, self.cache = self._decode(
-                self.params, jnp.asarray(tokens), self.cache,
-                self.slots.block_tables(), lengths, positions)
+        with _span("engine.decode"):
+            lengths = self.slots.lengths_device()
+            positions = self.slots.positions_device()
+            tokens = np.zeros((self.num_slots,), np.int32)
+            for idx, state in self.by_slot.items():
+                tokens[idx] = state.tokens[-1]
+            gplan = (self.slots.group_plan(self.plan.paged.group_threshold)
+                     if self._group_decode else None)
+            if gplan is not None:
+                logits, self.cache = self._decode_grouped(
+                    self.params, jnp.asarray(tokens), self.cache,
+                    self.slots.block_tables(), lengths, gplan.operands(),
+                    positions)
+                self.stats.grouped_requests += gplan.n_grouped
+                self.stats.prefix_kv_bytes_saved += (
+                    gplan.pages_deduped * self._kv_bytes_per_page)
+            else:
+                logits, self.cache = self._decode(
+                    self.params, jnp.asarray(tokens), self.cache,
+                    self.slots.block_tables(), lengths, positions)
         if self.pool is not None:
             # decode streams every resident page once per tick, at the
             # stored width — the term kv_dtype shrinks
@@ -938,12 +963,8 @@ class Engine:
         # every decode tick streams the full per-layer GEMM weight stack
         # once, at stored width — the term weight_dtype shrinks
         self.stats.weight_bytes_decode_read += self._weight_bytes_per_tick
-        events = []
-        for idx in list(self.by_slot):
-            state = self.by_slot[idx]
-            tok = int(self._sample(logits[idx:idx + 1], state)[0])
-            events.append(self._emit(idx, state, tok))
-        return events
+        return self._sample_rows(
+            [(idx, state, logits, idx) for idx, state in self.by_slot.items()])
 
     # -- tiered store dataflow (the only tier-crossing copies) -----------------
 
@@ -962,6 +983,7 @@ class Engine:
         idxd = jnp.asarray(idx)
         host = [np.asarray(leaf[:, idxd])
                 for leaf in jax.tree.leaves(self.cache)]
+        self.stats.host_syncs += len(host)
         return {p: tuple(np.ascontiguousarray(h[:, i]) for h in host)
                 for i, p in enumerate(pages)}
 
@@ -1025,6 +1047,20 @@ class Engine:
             top_k=p.top_k, top_p=p.top_p, vocab_size=self.cfg.vocab_size,
         )
 
+    def _sample_rows(self, rows: list[tuple], *,
+                     wrote_kv: bool = True) -> list[TokenEvent]:
+        """Sample one token for each ``(slot, state, logits, row)``, read
+        it back to the host and account it (:meth:`_emit`): the step's
+        one ``engine.sample`` span per phase, one host read per token."""
+        events = []
+        with _span("engine.sample"):
+            for idx, state, logits, row in rows:
+                tok = int(self._sample(logits[row:row + 1], state)[0])
+                self.stats.host_syncs += 1
+                state.phase = Phase.RUNNING
+                events.append(self._emit(idx, state, tok, wrote_kv=wrote_kv))
+        return events
+
     def _emit(self, idx: int, state: RequestState, tok: int,
               *, wrote_kv: bool = True) -> TokenEvent:
         """Account one sampled token: stop/budget checks, event record,
@@ -1069,7 +1105,6 @@ class Engine:
             self.slots.release(idx)
         self.by_slot.pop(idx, None)
         state.finish(reason)
-        self.stats.finished += 1
         if reason is FinishReason.STOP and not state.params.include_stop:
             ev = TokenEvent(state.rid, None, state.generated,
                             finished=True, finish_reason=reason)

@@ -98,6 +98,8 @@ class RequestState:
     #                                  by the engine before victim ranking;
     #                                  stays 0 without a TieredPool
     submit_time: float = 0.0
+    admit_time: Optional[float] = None   # first admission (a re-admission
+    #                                      after preemption keeps it)
     first_token_time: Optional[float] = None
     first_token_tick: Optional[int] = None
 
