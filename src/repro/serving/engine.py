@@ -127,8 +127,10 @@ class EngineStats:
     differences between two snapshots. (Tick count lives on
     ``Engine.ticks`` — the loop's one clock.)"""
 
-    host_syncs: int = 0              # device→host reads: one per sampled
-    #                                  token, one per leaf of a tier gather
+    host_syncs: int = 0              # blocking device→host reads: one
+    #                                  per sampling phase (a wave's first
+    #                                  tokens, a tick's tokens), one per
+    #                                  leaf of a tier gather
     preemptions: int = 0
     peak_pages_used: int = 0
     # prefix sharing (all zero unless Engine(prefix_sharing=True))
@@ -348,6 +350,11 @@ class Engine:
 
         self.seed = seed
         self._base_key = jax.random.PRNGKey(seed)
+        # resident sampled requests' PRNG keys, by slot: written at
+        # admission, split by the sampler, parked back into
+        # ``RequestState.key`` at preemption and retirement
+        self._keys = jnp.zeros((num_slots,) + self._base_key.shape,
+                               self._base_key.dtype)
         self.requests: dict[int, RequestState] = {}
         self.waiting: list[RequestState] = []
         self.by_slot: dict[int, RequestState] = {}
@@ -395,7 +402,32 @@ class Engine:
                                         block_tables=bt, decode_groups=gr,
                                         positions=po)
 
+        # sampling: one program per logits array of a phase. It takes each
+        # slot's row of the logits (``rows``: a fixed (num_slots,) operand,
+        # so decode ticks and prefill launches share one compile) and
+        # draws through the module's ``sample``, looked up when traced.
+        # The variant is static and follows the rows' own parameters: an
+        # all-greedy batch is the argmax alone and touches no key; else a
+        # sampled slot splits its key once per draw, as its private chain
+        # in ``self._keys`` (unsampled slots' keys pass through).
+        vocab_size = cfg.vocab_size
+
+        def sample_slots(logits, rows, keys=None, temperature=None,
+                         top_k=None, top_p=None, *, draw=False,
+                         filtered=False):
+            logits = logits[rows]
+            if not draw:
+                return sample(logits, None, vocab_size=vocab_size), keys
+            pair = jax.vmap(jax.random.split)(keys)
+            toks = sample(logits, pair[:, 1], temperature=temperature,
+                          top_k=top_k, top_p=top_p, vocab_size=vocab_size,
+                          draw=True, filtered=filtered)
+            return toks, jnp.where((temperature > 0)[:, None],
+                                   pair[:, 0], keys)
+
         self._decode = jax.jit(decode_step, donate_argnums=(2,))
+        self._sampler = jax.jit(sample_slots,
+                                static_argnames=("draw", "filtered"))
         self._chunk = (jax.jit(prefill_chunk, donate_argnums=(3,))
                        if self.prefill_chunk else None)
         self._copy_page = (jax.jit(copy_page, donate_argnums=(0,))
@@ -636,6 +668,8 @@ class Engine:
                 continue
             state.phase = Phase.PREFILLING
             state.slot = idx
+            if state.params.temperature > 0:
+                self._keys = self._keys.at[idx].set(state.key)
             if state.admit_time is None:
                 state.admit_time = time.perf_counter()
             self.by_slot[idx] = state
@@ -1023,6 +1057,7 @@ class Engine:
     def _preempt(self, state: RequestState) -> None:
         idx = state.slot
         self.by_slot.pop(idx, None)
+        self._park_key(idx, state)
         if self.tiers is not None:
             # demote, don't discard: the victim's full KV pages move to
             # the session cache (demoted host-ward only under pressure),
@@ -1040,25 +1075,58 @@ class Engine:
         self.stats.preemptions += 1
         self.waiting.append(state)
 
-    def _sample(self, logits: jax.Array, state: RequestState) -> jax.Array:
-        p = state.params
-        return sample(
-            logits, state.next_key(), temperature=p.temperature,
-            top_k=p.top_k, top_p=p.top_p, vocab_size=self.cfg.vocab_size,
-        )
+    def _park_key(self, idx: int, state: RequestState) -> None:
+        """Hand a sampled request's key back from its slot's row of
+        ``self._keys``, so a re-admission continues its chain."""
+        if state.params.temperature > 0:
+            state.key = self._keys[idx]
+
+    def _draw(self, logits: jax.Array,
+              members: list[tuple[int, RequestState, int]]) -> jax.Array:
+        """Launch the sampler over one logits array for the
+        ``(slot, state, row)`` members; returns the ``(num_slots,)``
+        tokens, still on the device (a slot's token is read only if the
+        slot is a member)."""
+        b = self.num_slots
+        rows = np.arange(b, dtype=np.int32)
+        temperature = np.zeros((b,), np.float32)
+        top_k = np.zeros((b,), np.int32)
+        top_p = np.ones((b,), np.float32)
+        for idx, state, row in members:
+            rows[idx] = row
+            p = state.params
+            if p.temperature > 0:
+                temperature[idx], top_k[idx], top_p[idx] = (
+                    p.temperature, p.top_k, p.top_p)
+        rows = self._upload_i32("sample_rows", rows)
+        if not temperature.any():
+            return self._sampler(logits, rows)[0]
+        toks, self._keys = self._sampler(
+            logits, rows, self._keys, temperature, top_k, top_p, draw=True,
+            filtered=bool((top_k > 0).any() or (top_p < 1.0).any()))
+        return toks
 
     def _sample_rows(self, rows: list[tuple], *,
                      wrote_kv: bool = True) -> list[TokenEvent]:
         """Sample one token for each ``(slot, state, logits, row)``, read
-        it back to the host and account it (:meth:`_emit`): the step's
-        one ``engine.sample`` span per phase, one host read per token."""
+        them back to the host and account each (:meth:`_emit`): the
+        step's one ``engine.sample`` span per phase, one blocking host
+        read per phase. Each distinct logits array (a wave's rows may come
+        from several prefill launches) goes through the sampler once."""
         events = []
         with _span("engine.sample"):
+            arrays: dict[int, tuple[jax.Array, list]] = {}
             for idx, state, logits, row in rows:
-                tok = int(self._sample(logits[row:row + 1], state)[0])
-                self.stats.host_syncs += 1
+                arrays.setdefault(id(logits), (logits, []))[1].append(
+                    (idx, state, row))
+            toks = dict(zip(arrays, jax.device_get(
+                [self._draw(*a) for a in arrays.values()])))
+            self.stats.host_syncs += 1
+            for idx, state, logits, _row in rows:
                 state.phase = Phase.RUNNING
-                events.append(self._emit(idx, state, tok, wrote_kv=wrote_kv))
+                events.append(self._emit(idx, state,
+                                         int(toks[id(logits)][idx]),
+                                         wrote_kv=wrote_kv))
         return events
 
     def _emit(self, idx: int, state: RequestState, tok: int,
@@ -1104,6 +1172,7 @@ class Engine:
         else:
             self.slots.release(idx)
         self.by_slot.pop(idx, None)
+        self._park_key(idx, state)
         state.finish(reason)
         if reason is FinishReason.STOP and not state.params.include_stop:
             ev = TokenEvent(state.rid, None, state.generated,

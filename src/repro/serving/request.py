@@ -80,7 +80,10 @@ class RequestState:
     prompt: np.ndarray               # (P,) int32
     params: SamplingParams
     arrival: int                     # admission-order sequence number
-    key: jax.Array                   # private PRNG key, split per sample
+    key: jax.Array                   # private PRNG key: each draw splits
+    #                                  it (key, sub = split(key)) and
+    #                                  samples with sub; while resident the
+    #                                  engine holds the live copy
     phase: Phase = Phase.WAITING
     tokens: list = dataclasses.field(default_factory=list)
     events: list = dataclasses.field(default_factory=list)  # TokenEvents
@@ -153,10 +156,6 @@ class RequestState:
             return self.prompt
         return np.concatenate(
             [self.prompt, np.asarray(self.tokens, np.int32)])
-
-    def next_key(self) -> jax.Array:
-        self.key, sub = jax.random.split(self.key)
-        return sub
 
     def finish(self, reason: FinishReason) -> None:
         self.phase = Phase.FINISHED

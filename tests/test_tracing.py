@@ -161,13 +161,24 @@ def test_at_most_one_span_per_phase_per_step(traced_steps):
 # ---------------------------------------------------------------------------
 
 
-def test_host_syncs_count_sampled_tokens(smoke_model):
-    """One device→host read per sampled token, in prefill and decode."""
+def test_host_syncs_count_sampling_reads(smoke_model):
+    """One blocking device→host read per sampling phase (a wave's first
+    tokens, a tick's tokens), however many rows it samples: at most two
+    a step, and fewer than the tokens sampled."""
     cfg, params = smoke_model
     eng = _paged(cfg, params)
-    out = eng.run([(p, SamplingParams(max_new_tokens=4))
-                   for p in _shared_prompts(cfg, seed=1)])
-    assert eng.stats.host_syncs == sum(len(t) for t in out.values()) == 12
+    phases = []
+    sample_rows = eng._sample_rows
+    eng._sample_rows = lambda rows, **kw: (phases.append(len(rows))
+                                           or sample_rows(rows, **kw))
+    rids = [eng.submit(p, SamplingParams(max_new_tokens=4))
+            for p in _shared_prompts(cfg, seed=1)]
+    while not all(eng.requests[r].finished for r in rids):
+        before = eng.stats.host_syncs
+        eng.step()
+        assert eng.stats.host_syncs - before <= 2
+    assert sum(phases) == sum(eng.requests[r].generated for r in rids) == 12
+    assert eng.stats.host_syncs == len(phases) < 12
 
 
 def test_host_syncs_count_tier_gathers(smoke_model):
