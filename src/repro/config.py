@@ -68,6 +68,7 @@ class ModelConfig:
     head_dim: int = 0           # 0 -> d_model // num_heads
     qkv_bias: bool = False
     norm: str = "rmsnorm"       # rmsnorm | layernorm
+    rms_norm_eps: float = 1e-6  # the published epsilon (rmsnorm only)
     activation: str = "swiglu"  # swiglu | geglu | gelu
     rope_theta: float = 10000.0
     max_seq_len: int = 1 << 20

@@ -11,5 +11,6 @@ CONFIG = ModelConfig(
     d_ff=8192,
     vocab_size=32064,
     head_dim=96,
+    rms_norm_eps=1e-5,
     source="arXiv:2404.14219; hf:microsoft/Phi-3-mini-4k-instruct",
 )

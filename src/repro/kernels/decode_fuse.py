@@ -148,7 +148,7 @@ def decode_ingest_fused(
     num_kv_heads: int,
     head_dim: int,
     rope_theta: float = 1e4,
-    eps: float = 1e-6,
+    eps: float,
     use_rope: bool = True,
     bq: jax.Array | None = None,
     bk_bias: jax.Array | None = None,
@@ -422,7 +422,7 @@ def ffn_norm_fused(
     w_up: jax.Array,          # (D, F)
     *,
     activation: str = "swiglu",
-    eps: float = 1e-6,
+    eps: float,
     wg_scale: jax.Array | None = None,  # (F,) f32 -> w_gate is codes
     wu_scale: jax.Array | None = None,  # (F,) f32 -> w_up is codes
     block_n: int = 0,

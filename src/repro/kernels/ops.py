@@ -160,7 +160,7 @@ def decode_ingest(
     num_kv_heads: int,
     head_dim: int,
     rope_theta: float = 1e4,
-    eps: float = 1e-6,
+    eps: float,
     use_rope: bool = True,
     bq: jax.Array | None = None,
     bk: jax.Array | None = None,
@@ -230,7 +230,7 @@ def ffn_norm(
     w_up: jax.Array,        # (D, F)
     *,
     activation: str = "swiglu",
-    eps: float = 1e-6,
+    eps: float,
     plan: Optional[ExecutionPlan] = None,
 ) -> jax.Array:
     """Fused mlp-ingest stage: rmsnorm → gate/up GEMMs → act(g)*u in one

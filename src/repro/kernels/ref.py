@@ -625,7 +625,7 @@ def fused_ffn_up_ref(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
 
 
 def rmsnorm_ref(x: jax.Array, scale: jax.Array,
-                eps: float = 1e-6) -> jax.Array:
+                eps: float) -> jax.Array:
     """Expression-for-expression copy of ``models.layers.rmsnorm`` (the
     kernels layer cannot import models); the fused-ingest oracle composes
     it so the XLA ``fused``/``looped`` granularities stay bit-identical
@@ -663,7 +663,7 @@ def decode_ingest_ref(
     num_kv_heads: int,
     head_dim: int,
     rope_theta: float = 1e4,
-    eps: float = 1e-6,
+    eps: float,
     use_rope: bool = True,
     bq: jax.Array | None = None,
     bk: jax.Array | None = None,
@@ -719,7 +719,7 @@ def ffn_norm_ref(
     w_up: jax.Array,        # (D, F)
     *,
     activation: str = "swiglu",
-    eps: float = 1e-6,
+    eps: float,
     fused: bool = True,
     wg_scale: jax.Array | None = None,
     wu_scale: jax.Array | None = None,

@@ -57,7 +57,7 @@ class LayerCtx:
 # ---------------------------------------------------------------------------
 
 
-def rmsnorm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
+def rmsnorm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
     xf = x.astype(jnp.float32)
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
     out = xf * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)
@@ -77,7 +77,7 @@ def layernorm(x: jax.Array, scale: jax.Array, bias: jax.Array,
 def norm(cfg: ModelConfig, p: Params, x: jax.Array) -> jax.Array:
     if cfg.norm == "layernorm":
         return layernorm(x, p["scale"], p["bias"])
-    return rmsnorm(x, p["scale"])
+    return rmsnorm(x, p["scale"], cfg.rms_norm_eps)
 
 
 def norm_params(cfg: ModelConfig, d: int) -> Params:
@@ -239,7 +239,7 @@ def decode_ingest(
             x, norm_p["scale"], p["wq"], p["wk"], p["wv"], position,
             num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
             head_dim=cfg.head_dim, rope_theta=cfg.rope_theta,
-            use_rope=use_rope,
+            eps=cfg.rms_norm_eps, use_rope=use_rope,
             bq=p.get("bq"), bk=p.get("bk"), bv=p.get("bv"),
             plan=ctx.plan,
         )
@@ -306,7 +306,8 @@ def decode_mlp(ctx: LayerCtx, norm_p: Params, p: Params,
             and cfg.norm == "rmsnorm"
             and cfg.activation in ("swiglu", "geglu")):
         h = ops.ffn_norm(x, norm_p["scale"], p["w_gate"], p["w_up"],
-                         activation=cfg.activation, plan=ctx.plan)
+                         activation=cfg.activation,
+                         eps=cfg.rms_norm_eps, plan=ctx.plan)
         h = ctx.shard(h, "act_ffn")
         return ops.oproj_residual(h, p["w_down"], x, plan=ctx.plan)
     h = norm(cfg, norm_p, x)

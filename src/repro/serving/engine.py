@@ -132,6 +132,12 @@ class EngineStats:
     #                                  tokens, a tick's tokens), one per
     #                                  leaf of a tier gather
     preemptions: int = 0
+    prefill_tokens: int = 0          # positions prefill computed (every
+    #                                  chunk position; the whole prompt
+    #                                  in single-shot prefill)
+    recomputed_tokens: int = 0       # of those, positions a preempted
+    #                                  request prefilled again at
+    #                                  re-admission
     peak_pages_used: int = 0
     # prefix sharing (all zero unless Engine(prefix_sharing=True))
     shared_prefix_pages: int = 0     # page mappings served by refcount
@@ -829,6 +835,8 @@ class Engine:
         progress = {idx: self._chunk_start(idx, len(seqs[idx]))
                     for idx, _ in items}
         plens = {idx: max(len(seqs[idx]), 1) for idx, _ in items}
+        self._count_prefill(
+            (state, plens[idx] - progress[idx]) for idx, state in items)
         # gather-vs-fused inflection by prompt length (plan-tuned): short
         # waves keep the one-compile full-width gather
         pp = self.plan.paged
@@ -870,6 +878,15 @@ class Engine:
             self.slots.commit_prefix(idx, seqs[idx])
         return [(idx, state, final[idx], idx) for idx, state in items]
 
+    def _count_prefill(self, computed) -> None:
+        """Add each admitted row's prefill positions, ``(state, n)``
+        pairs, to ``prefill_tokens``, and a re-admitted (preempted)
+        request's to ``recomputed_tokens`` as well."""
+        for state, n in computed:
+            self.stats.prefill_tokens += n
+            if state.preemptions:
+                self.stats.recomputed_tokens += n
+
     # -- batched single-shot prefill (recurrent/ring families) ------------------
 
     def _prefill_fn(self, padded: int):
@@ -894,6 +911,8 @@ class Engine:
         lengths share a logarithmic family of compiles instead of
         re-jitting at every 64-token multiple."""
         seqs = {idx: state.prefill_tokens() for idx, state in items}
+        self._count_prefill(
+            (state, len(seqs[idx])) for idx, state in items)
         pmax = max(len(s) for s in seqs.values())
         # never pad past what plain 64-multiple rounding could reach
         # (pmax <= max_seq is enforced at submit)

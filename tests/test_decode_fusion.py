@@ -125,8 +125,9 @@ def test_ref_norm_and_rope_are_bitwise_copies():
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.standard_normal((2, 1, 96)), jnp.bfloat16)
     scale = jnp.asarray(rng.standard_normal(96), jnp.bfloat16)
-    assert np.array_equal(np.asarray(L.rmsnorm(x, scale)),
-                          np.asarray(ref.rmsnorm_ref(x, scale)))
+    for eps in (1e-6, 1e-5):
+        assert np.array_equal(np.asarray(L.rmsnorm(x, scale, eps)),
+                              np.asarray(ref.rmsnorm_ref(x, scale, eps)))
     h = x.reshape(2, 1, 3, 32)
     pos = jnp.array([[5], [170]], jnp.int32)
     assert np.array_equal(np.asarray(L.rope(h, pos, 1e4)),
@@ -228,10 +229,10 @@ def test_ingest_kernel_matches_oracle(hq, hk, dh, d, bias, use_rope):
     pos = jnp.array([4, 9, 170], jnp.int32)
     qo, ko, vo = ref.decode_ingest_ref(
         x, scale, wq, wk, wv, pos, num_heads=hq, num_kv_heads=hk,
-        head_dim=dh, use_rope=use_rope, bq=bq, bk=bk, bv=bv)
+        head_dim=dh, eps=1e-6, use_rope=use_rope, bq=bq, bk=bk, bv=bv)
     qf, kf, vf = decode_ingest_fused(
         x.reshape(m, d), scale, wq, wk, wv, pos, num_heads=hq,
-        num_kv_heads=hk, head_dim=dh, use_rope=use_rope,
+        num_kv_heads=hk, head_dim=dh, eps=1e-6, use_rope=use_rope,
         bq=bq, bk_bias=bk, bv=bv, interpret=True)
     eps = float(jnp.finfo(jnp.bfloat16).eps)
     for a, b in ((qo.reshape(m, -1), qf), (ko.reshape(m, -1), kf),
@@ -270,9 +271,10 @@ def test_ffn_norm_kernel_matches_oracle(m, d, f, act):
     scale = jnp.asarray(rng.standard_normal(d), jnp.bfloat16)
     wg = jnp.asarray(rng.standard_normal((d, f)) / 8, jnp.bfloat16)
     wu = jnp.asarray(rng.standard_normal((d, f)) / 8, jnp.bfloat16)
-    want = ref.ffn_norm_ref(x, scale, wg, wu, activation=act, fused=True)
+    want = ref.ffn_norm_ref(x, scale, wg, wu, activation=act, eps=1e-6,
+                            fused=True)
     got = ffn_norm_fused(x.reshape(m, d), scale, wg, wu, activation=act,
-                         interpret=True).reshape(want.shape)
+                         eps=1e-6, interpret=True).reshape(want.shape)
     a = np.asarray(want, np.float32)
     b = np.asarray(got, np.float32)
     eps = float(jnp.finfo(jnp.bfloat16).eps)
@@ -313,7 +315,7 @@ def test_ops_dispatch_routes_by_plan(smoke_model):
     wk = jnp.asarray(rng.standard_normal((d, hk * dh)), jnp.bfloat16)
     wv = jnp.asarray(rng.standard_normal((d, hk * dh)), jnp.bfloat16)
     pos = jnp.array([4, 9], jnp.int32)
-    kw = dict(num_heads=hq, num_kv_heads=hk, head_dim=dh)
+    kw = dict(num_heads=hq, num_kv_heads=hk, head_dim=dh, eps=1e-6)
     eps = float(jnp.finfo(jnp.bfloat16).eps)
     ref_out = ops.decode_ingest(
         x, scale, wq, wk, wv, pos,

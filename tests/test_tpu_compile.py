@@ -203,7 +203,8 @@ def test_decode_ingest_compiles(shape, wdtype):
     def fn(x, norm, wq, wk, wv, pos, bq, bk, bv, sq, sk, sv):
         return decode_ingest_fused(
             x, norm, wq, wk, wv, pos, num_heads=HQ, num_kv_heads=HK,
-            head_dim=DH, rope_theta=CFG.rope_theta, bq=bq, bk_bias=bk,
+            head_dim=DH, rope_theta=CFG.rope_theta, eps=CFG.rms_norm_eps,
+            bq=bq, bk_bias=bk,
             bv=bv, wq_scale=sq, wk_scale=sk, wv_scale=sv)
 
     bf16 = jnp.bfloat16
@@ -231,7 +232,8 @@ def test_ffn_norm_compiles(shape, wdtype):
     wu, su = _weight(shape, D, F, wdtype)
 
     def fn(x, norm, wg, wu, sg, su):
-        return ffn_norm_fused(x, norm, wg, wu, wg_scale=sg, wu_scale=su)
+        return ffn_norm_fused(x, norm, wg, wu, eps=CFG.rms_norm_eps,
+                              wg_scale=sg, wu_scale=su)
 
     _compiles_to_mosaic(fn, shape((SLOTS, D), jnp.bfloat16),
                         shape((D,), jnp.bfloat16), wg, wu, sg, su)

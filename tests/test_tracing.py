@@ -1,10 +1,12 @@
 """The engine's own instrumentation: profiler spans around each phase of
-``Engine.step``, the ``host_syncs`` counter, the admission time stamp,
-and the names of its device programs and Pallas kernels.
+``Engine.step``, the ``host_syncs``, ``prefill_tokens`` and
+``recomputed_tokens`` counters, the admission time stamp, and the names
+of its device programs and Pallas kernels.
 
 A trace taken around the engine must put the device's idle time down to
 the host work that caused it, so each phase opens one span per step
 (never one per slot), nested as the table in ``Engine.step`` states."""
+import functools
 import glob
 import os
 import time
@@ -193,6 +195,55 @@ def test_host_syncs_count_tier_gathers(smoke_model):
             == len(jax.tree.leaves(eng.cache)))
 
 
+def test_prefill_counters_zero_without_prefill_or_preemption(smoke_model):
+    """``prefill_tokens`` counts every prompt position prefill computes;
+    ``recomputed_tokens`` stays 0 when nothing is preempted, and both
+    start at 0."""
+    cfg, params = smoke_model
+    eng = Engine(cfg, params, num_slots=2, max_seq=128, cache_kind="paged",
+                 page_size=16, prefill_chunk=16)
+    assert eng.stats.prefill_tokens == eng.stats.recomputed_tokens == 0
+    prompts = _shared_prompts(cfg, seed=2)
+    eng.run([(p, SamplingParams(max_new_tokens=3)) for p in prompts])
+    assert eng.stats.preemptions == 0
+    assert eng.stats.prefill_tokens == sum(len(p) for p in prompts)
+    assert eng.stats.recomputed_tokens == 0
+
+
+def test_recomputed_tokens_count_readmission_prefill(smoke_model):
+    """On a pool too small for both sequences' growth, a preempted
+    request re-prefills its prompt and the tokens it had generated when
+    it was preempted: ``recomputed_tokens`` counts exactly those chunk
+    positions, and ``prefill_tokens`` every chunk position."""
+    cfg, params = smoke_model
+    eng = Engine(cfg, params, num_slots=2, max_seq=64, cache_kind="paged",
+                 page_size=16, num_pages=4, prefill_chunk=16)
+    chunked = {"all": 0, "again": 0}
+    chunk = eng._chunk
+
+    def spy(p, tokens, chunk_lens, *rest):
+        cl = np.asarray(chunk_lens)
+        for idx, state in eng.by_slot.items():
+            chunked["all"] += int(cl[idx])
+            if state.preemptions:
+                chunked["again"] += int(cl[idx])
+        return chunk(p, tokens, chunk_lens, *rest)
+
+    eng._chunk = spy
+    owed = []
+    preempt = eng._preempt
+    eng._preempt = lambda st: (owed.append(len(st.prompt) + st.generated)
+                               or preempt(st))
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(1, cfg.vocab_size, n).astype(np.int32)
+               for n in (9, 10)]
+    eng.run([(p, SamplingParams(max_new_tokens=26)) for p in prompts])
+    assert eng.stats.preemptions == len(owed) > 0
+    assert eng.stats.recomputed_tokens == chunked["again"] == sum(owed)
+    assert eng.stats.prefill_tokens == chunked["all"] == (
+        sum(len(p) for p in prompts) + sum(owed))
+
+
 def test_admit_time_between_submit_and_first_token(smoke_model):
     """``submit_time <= admit_time <= first_token_time`` on one clock; a
     request queued behind a full batch is admitted only in a later step."""
@@ -286,7 +337,8 @@ GROUPS = DecodeGroups(
 
 def _ingest(x, norm, wq, wk, wv, pos):
     return decode_ingest_fused(x, norm, wq, wk, wv, pos, num_heads=HQ,
-                               num_kv_heads=HK, head_dim=DH, rope_theta=1e6)
+                               num_kv_heads=HK, head_dim=DH, rope_theta=1e6,
+                               eps=1e-6)
 
 
 KERNELS = {
@@ -308,7 +360,7 @@ KERNELS = {
         S((D, HK * DH), BF), S((D, HK * DH), BF), S((B,), I32))),
     "oproj_residual_fused": (oproj_residual_fused, (
         S((B, HQ * DH), BF), S((HQ * DH, D), BF), S((B, D), BF))),
-    "ffn_norm_fused": (ffn_norm_fused, (
+    "ffn_norm_fused": (functools.partial(ffn_norm_fused, eps=1e-6), (
         S((B, D), BF), S((D,), BF), S((D, F), BF), S((D, F), BF))),
     "gemv": (gemv, (S((B, D), BF), S((D, HQ * DH), BF))),
     "flat_gemm": (flat_gemm, (S((B, D), BF), S((D, F), BF))),

@@ -211,7 +211,7 @@ def test_decode_ingest_quant_matches_oracle(seam_case, spec):
     qq, qk, qv = Q(c["wq"]), Q(c["wk"]), Q(c["wv"])
     want = ref.decode_ingest_ref(
         c["x"], c["ns"], qq["codes"], qk["codes"], qv["codes"], c["pos"],
-        num_heads=c["HQ"], num_kv_heads=c["HK"], head_dim=c["Dh"],
+        num_heads=c["HQ"], num_kv_heads=c["HK"], head_dim=c["Dh"], eps=1e-6,
         wq_scale=qq["scale"], wk_scale=qk["scale"], wv_scale=qv["scale"])
     for name, plan in _plans():
         got = ops.decode_ingest(
@@ -219,7 +219,7 @@ def test_decode_ingest_quant_matches_oracle(seam_case, spec):
             {"codes": qk["codes"], "scale": qk["scale"]},
             {"codes": qv["codes"], "scale": qv["scale"]}, c["pos"],
             num_heads=c["HQ"], num_kv_heads=c["HK"], head_dim=c["Dh"],
-            plan=plan)
+            eps=1e-6, plan=plan)
         for a, b in zip(got, want):
             np.testing.assert_array_equal(np.asarray(a, np.float32),
                                           np.asarray(b, np.float32),
@@ -249,13 +249,14 @@ def test_ffn_norm_quant_matches_oracle(seam_case, spec):
     # the xla path composes the plan's fused_ffn knob; compare per-plan
     for name, plan in _plans():
         want = ref.ffn_norm_ref(c["x"], c["ns"], gq["codes"], uq["codes"],
-                                fused=plan.fused_ffn.fused,
+                                eps=1e-6, fused=plan.fused_ffn.fused,
                                 wg_scale=gq["scale"], wu_scale=uq["scale"])
-        got = ops.ffn_norm(c["x"], c["ns"], gq, uq, plan=plan)
+        got = ops.ffn_norm(c["x"], c["ns"], gq, uq, eps=1e-6, plan=plan)
         if name == "pallas":
             # fused kernel == fused oracle composition bitwise
             want = ref.ffn_norm_ref(
-                c["x"], c["ns"], gq["codes"], uq["codes"], fused=True,
+                c["x"], c["ns"], gq["codes"], uq["codes"], eps=1e-6,
+                fused=True,
                 wg_scale=gq["scale"], wu_scale=uq["scale"])
         np.testing.assert_array_equal(np.asarray(got, np.float32),
                                       np.asarray(want, np.float32),
