@@ -25,6 +25,7 @@ bookkeeping stays in :mod:`repro.serving.blockpool`.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Tuple, Union
 
 
@@ -91,6 +92,62 @@ class PagedLayout:
 
     def pages_for(self, positions: int) -> int:
         return pages_for(positions, self.page_size)
+
+    @staticmethod
+    def device_storage(spec, device):
+        """The shapes a paged cache (``spec``: its leaves' shape structs)
+        is stored in on ``device``: each leaf's ``(L, NP, ...)`` shape, or
+        the same with the page axis split in two, ``(L, NP/a, a, ...)``,
+        for the smallest ``a`` that the device lays out row-major by
+        default.
+
+        The paged kernels read pages row-major. For a pool whose head_dim
+        is not a multiple of 128 lanes a TPU's default layout puts a page
+        axis of 128 or more minor-most, so a step program would relay the
+        whole pool on entry and again on exit. Split, the same bytes in
+        row-major order are the default layout, and the leading dims
+        merge back into the page view as a bitcast: every program takes
+        and returns the pool in place, in a layout that needs no
+        annotation. Where no split helps, the shape stays."""
+        import jax
+        from jax.experimental.layout import Layout
+
+        def row_major(shape, dtype):
+            layout = device.client.get_default_layout(dtype, shape, device)
+            return (Layout.from_pjrt_layout(layout).major_to_minor
+                    == tuple(range(len(shape))))
+
+        def stored(s):
+            nl, num_pages, *rest = s.shape
+            for a in range(1, num_pages + 1):
+                split = (num_pages,) if a == 1 else (num_pages // a, a)
+                shape = (nl, *split, *rest)
+                if num_pages % a == 0 and row_major(shape, s.dtype):
+                    return jax.ShapeDtypeStruct(shape, s.dtype)
+            return s
+
+        return jax.tree.map(stored, spec)
+
+    @staticmethod
+    def page_view(cache: dict) -> Tuple[dict, int, int]:
+        """Each leaf of a stacked paged cache as one flat ``(L*NP, ...)``
+        page axis, whichever shape it is stored in (the logical ``(L, NP,
+        ...)`` or a :meth:`device_storage` split): the leading dims merge,
+        a bitcast. Returns the views, ``L`` and ``NP``; page ``p`` of
+        layer ``l`` is row ``l*NP + p``. K/V leaves keep their ``(PS, HK,
+        D)`` tail, scale leaves their ``(HK,)``."""
+        k = cache["k"]
+        nl, num_pages = k.shape[0], math.prod(k.shape[1:-3])
+        rows = nl * num_pages
+
+        def flat(a):
+            lead, n = 0, 1
+            while n < rows:
+                n *= a.shape[lead]
+                lead += 1
+            return a.reshape(rows, *a.shape[lead:])
+
+        return {n: flat(a) for n, a in cache.items()}, nl, num_pages
 
 
 KVLayout = Union[DenseLayout, PagedLayout]

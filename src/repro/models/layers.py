@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from repro.config import ModelConfig, SoftmaxPhiConfig
 from repro.core.plan import DEFAULT_PLAN, ExecutionPlan
 from repro.kernels import ops, quant
+from repro.models.kvlayout import PagedLayout
 
 Params = dict
 ShardFn = Callable[[jax.Array, str], jax.Array]  # (x, logical role) -> x
@@ -375,74 +376,88 @@ def _paged_scatter_chunk(pool: jax.Array, new: jax.Array,
     return pool.at[phys, pos % ps].set(new.astype(pool.dtype), mode="drop")
 
 
+def _layer_pages(cache: dict, layer):
+    """Address layer ``layer`` of a stacked paged cache in place.
+
+    Each leaf is viewed as one flat ``(L*NP, ...)`` page axis
+    (:meth:`PagedLayout.page_view`: a bitcast, no copy, from the logical
+    or the device's stored shape); page ``p`` of layer ``l`` is ``l*NP +
+    p`` there. Returns the flat cache and two translations of a page
+    table onto it: ``read`` clamps unassigned entries (the sentinel
+    ``NP``) to the layer's last page, as the kernels do, and ``write``
+    sends them past the whole pool, so a scatter drops them — a sentinel
+    of layer ``l`` never lands in layer ``l+1``."""
+    flat, nl, num_pages = PagedLayout.page_view(cache)
+    base = layer * num_pages
+
+    def read(t):
+        return base + jnp.minimum(t, num_pages - 1)
+
+    def write(t):
+        return jnp.where(t < num_pages, base + t, nl * num_pages)
+
+    return flat, read, write
+
+
+def _paged_write(flat: dict, new_k: jax.Array, new_v: jax.Array,
+                 tables: jax.Array, lengths: jax.Array,
+                 chunk_lens: jax.Array) -> dict:
+    """Scatter a chunk of new K/V into the flat page view (quantized
+    scatter when scale pools ride along): only the written positions
+    change, so the update happens in place on the donated pool."""
+    if "k_scale" not in flat:
+        return {"k": _paged_scatter_chunk(flat["k"], new_k, tables, lengths,
+                                          chunk_lens),
+                "v": _paged_scatter_chunk(flat["v"], new_v, tables, lengths,
+                                          chunk_lens)}
+    from repro.serving import kvquant  # deferred: serving imports models
+
+    spec = quant.spec_for_dtype(flat["k"].dtype)
+    out = {}
+    for name, new in (("k", new_k), ("v", new_v)):
+        out[name], out[name + "_scale"] = kvquant.scatter_chunk_quantized(
+            flat[name], flat[name + "_scale"], new, tables, lengths,
+            chunk_lens, spec)
+    return out
+
+
+def _unflatten_pages(flat: dict, cache: dict) -> dict:
+    """Flat page views back to the shapes ``cache`` is stored in."""
+    return {n: a.reshape(cache[n].shape) for n, a in flat.items()}
+
+
 def decode_attend_paged(
     ctx: LayerCtx, q: jax.Array, k: jax.Array, v: jax.Array,
-    pool_k: jax.Array, pool_v: jax.Array, block_tables: jax.Array,
-    lengths: jax.Array, *, decode_groups=None,
-    k_scale: jax.Array | None = None, v_scale: jax.Array | None = None,
-) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array | None,
-           jax.Array | None]:
+    cache: dict, layer, block_tables: jax.Array, lengths: jax.Array,
+    *, decode_groups=None,
+) -> tuple[jax.Array, dict]:
     """Stage B (paged layout): append this token's KV through the block
-    tables (quantized scatter when scale pools ride along), attend over
-    the page pool. Returns (o (B,1,HQ*Dh), pools, scale pools)."""
+    tables, attend over the page pool. ``cache`` is the whole stacked
+    paged cache (``(L, NP, PS, HK, Dh)`` pools, plus ``(L, NP, HK)`` scale
+    pools when quantized) and ``layer`` this layer's index: the write and
+    the kernels address the layer's pages in place (:func:`_layer_pages`).
+    Empty slots write nothing — their table entries are the sentinel.
+    ``decode_groups`` activates the prefix-shared grouped attention path.
+    Returns (o (B,1,HQ*Dh), the updated stacked cache)."""
     cfg = ctx.cfg
     b = q.shape[0]
-    ones = jnp.ones_like(lengths)
-    if k_scale is not None:
-        from repro.serving import kvquant  # deferred: serving imports models
-
-        spec = quant.spec_for_dtype(pool_k.dtype)
-        pool_k, k_scale = kvquant.scatter_chunk_quantized(
-            pool_k, k_scale, k, block_tables, lengths, ones, spec)
-        pool_v, v_scale = kvquant.scatter_chunk_quantized(
-            pool_v, v_scale, v, block_tables, lengths, ones, spec)
-    else:
-        pool_k = _paged_scatter_chunk(pool_k, k, block_tables, lengths, ones)
-        pool_v = _paged_scatter_chunk(pool_v, v, block_tables, lengths, ones)
-    new_len = lengths + 1
+    flat, read, write = _layer_pages(cache, layer)
+    flat = _paged_write(flat, k, v, write(block_tables), lengths,
+                        jnp.ones_like(lengths))
+    groups = decode_groups
+    if groups is not None:
+        groups = groups._replace(tables=read(groups.tables))
     o = ops.attention_decode_paged(
-        q[:, 0], pool_k, pool_v, block_tables, new_len,
+        q[:, 0], flat["k"], flat["v"], read(block_tables), lengths + 1,
         phi_cfg=ctx.phi_cfg if cfg.has_softmax_attention else
         SoftmaxPhiConfig(enabled=False),
         plan=ctx.plan,
         shard=ctx.shard,
-        groups=decode_groups,
-        k_scale=k_scale, v_scale=v_scale,
+        groups=groups,
+        k_scale=flat.get("k_scale"), v_scale=flat.get("v_scale"),
     )
     o = ctx.shard(o.reshape(b, 1, cfg.q_dim), "act_attn_out")
-    return o, pool_k, pool_v, k_scale, v_scale
-
-
-def attention_decode_block_paged(
-    ctx: LayerCtx, p: Params, x: jax.Array, position: jax.Array,
-    pool_k: jax.Array, pool_v: jax.Array, block_tables: jax.Array,
-    lengths: jax.Array, *, use_rope: bool = True, decode_groups=None,
-    k_scale: jax.Array | None = None, v_scale: jax.Array | None = None,
-) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array | None,
-           jax.Array | None]:
-    """One-token decode against a block-paged KV cache (split stage
-    composition).
-
-    x: (B, 1, D); pool_k/v: (NP, PS, HK, Dh) shared page pools;
-    block_tables: (B, NB) int32. Empty slots in a partially occupied batch
-    write nothing — their block-table entries are the out-of-bounds
-    sentinel, so the scatter drops them. ``decode_groups`` (a
-    :class:`~repro.kernels.group_attention.DecodeGroups`) activates the
-    prefix-shared grouped attention path.
-
-    With ``k_scale``/``v_scale`` (the (NP, HK) f32 step pools of a
-    quantized layout) the new token is appended through the quantized
-    scatter and attention dequantizes in place; returns the updated scale
-    pools alongside the code pools (``None``/``None`` when bf16).
-    """
-    q, k, v = attention_qkv(
-        ctx, p, x, position[:, None], use_rope=use_rope
-    )
-    o, pool_k, pool_v, k_scale, v_scale = decode_attend_paged(
-        ctx, q, k, v, pool_k, pool_v, block_tables, lengths,
-        decode_groups=decode_groups, k_scale=k_scale, v_scale=v_scale,
-    )
-    return ctx.matmul(o, p["wo"]), pool_k, pool_v, k_scale, v_scale
+    return o, _unflatten_pages(flat, cache)
 
 
 def attention_chunk_block(
@@ -473,43 +488,32 @@ def attention_chunk_block(
 
 
 def attention_chunk_block_paged(
-    ctx: LayerCtx, p: Params, x: jax.Array,
-    pool_k: jax.Array, pool_v: jax.Array, block_tables: jax.Array,
-    lengths: jax.Array, chunk_lens: jax.Array, *, use_rope: bool = True,
-    k_scale: jax.Array | None = None, v_scale: jax.Array | None = None,
-) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array | None,
-           jax.Array | None]:
+    ctx: LayerCtx, p: Params, x: jax.Array, cache: dict, layer,
+    block_tables: jax.Array, lengths: jax.Array, chunk_lens: jax.Array,
+    *, use_rope: bool = True,
+) -> tuple[jax.Array, dict]:
     """Chunked-prefill step against the block-paged pool (paged twin of
-    :func:`attention_chunk_block`). Quantized layouts (``k_scale``/
-    ``v_scale`` step pools) write the chunk through the quantized scatter
-    — quantization happens in the chunk epilogue, so the full-precision
-    slab never lands in HBM — and return the updated scale pools."""
+    :func:`attention_chunk_block`). ``cache``/``layer`` as in
+    :func:`decode_attend_paged`: the chunk is written into the layer's
+    pages in place. Quantized layouts write the chunk through the
+    quantized scatter — quantization happens in the chunk epilogue, so the
+    full-precision slab never lands in HBM. Returns (out, the updated
+    stacked cache)."""
     cfg = ctx.cfg
     b, c, _ = x.shape
     positions = lengths[:, None] + jnp.arange(c)[None, :]
     q, k, v = attention_qkv(ctx, p, x, positions, use_rope=use_rope)
-    if k_scale is not None:
-        from repro.serving import kvquant  # deferred: serving imports models
-
-        spec = quant.spec_for_dtype(pool_k.dtype)
-        pool_k, k_scale = kvquant.scatter_chunk_quantized(
-            pool_k, k_scale, k, block_tables, lengths, chunk_lens, spec)
-        pool_v, v_scale = kvquant.scatter_chunk_quantized(
-            pool_v, v_scale, v, block_tables, lengths, chunk_lens, spec)
-    else:
-        pool_k = _paged_scatter_chunk(pool_k, k, block_tables, lengths,
-                                      chunk_lens)
-        pool_v = _paged_scatter_chunk(pool_v, v, block_tables, lengths,
-                                      chunk_lens)
+    flat, read, write = _layer_pages(cache, layer)
+    flat = _paged_write(flat, k, v, write(block_tables), lengths, chunk_lens)
     o = ops.attention_chunk_paged(
-        q, pool_k, pool_v, block_tables, lengths,
+        q, flat["k"], flat["v"], read(block_tables), lengths,
         phi_cfg=ctx.phi_cfg if cfg.has_softmax_attention else
         SoftmaxPhiConfig(enabled=False),
         plan=ctx.plan,
-        k_scale=k_scale, v_scale=v_scale,
+        k_scale=flat.get("k_scale"), v_scale=flat.get("v_scale"),
     )
     o = ctx.shard(o.reshape(b, c, cfg.q_dim), "act_attn_out")
-    return ctx.matmul(o, p["wo"]), pool_k, pool_v, k_scale, v_scale
+    return ctx.matmul(o, p["wo"]), _unflatten_pages(flat, cache)
 
 
 # ---------------------------------------------------------------------------

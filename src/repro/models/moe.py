@@ -305,8 +305,8 @@ def make_block(groups: int = 0, capacity_factor: float = 1.25):
 
 
 def make_decode_block(groups: int = 0):
-    def decode_block(ctx: LayerCtx, p: Params, x, position, cache_i, lengths,
-                     block_tables=None, decode_groups=None):
+    def decode_block(ctx: LayerCtx, p: Params, x, position, cache, lengths,
+                     block_tables=None, decode_groups=None, layer=None):
         # same ingest → attend → epilogue stage boundaries as the dense
         # family (repro.models.layers); only the FFN half differs — the
         # routed expert dispatch is not a fusable seam, so the MoE block
@@ -316,25 +316,19 @@ def make_decode_block(groups: int = 0):
                                   position)
         if block_tables is None:
             o, ck, cv = L.decode_attend(
-                ctx, q, k, v, cache_i["k"], cache_i["v"], lengths
+                ctx, q, k, v, cache["k"], cache["v"], lengths
             )
-            new_cache = {"k": ck, "v": cv}
+            cache = {"k": ck, "v": cv}
         else:
-            o, ck, cv, ks, vs = L.decode_attend_paged(
-                ctx, q, k, v, cache_i["k"], cache_i["v"],
-                block_tables, lengths, decode_groups=decode_groups,
-                k_scale=cache_i.get("k_scale"),
-                v_scale=cache_i.get("v_scale"),
+            o, cache = L.decode_attend_paged(
+                ctx, q, k, v, cache, layer, block_tables, lengths,
+                decode_groups=decode_groups,
             )
-            new_cache = {"k": ck, "v": cv}
-            if ks is not None:
-                new_cache["k_scale"] = ks
-                new_cache["v_scale"] = vs
         x = L.decode_epilogue(ctx, p["attn"], o, x)
         h = L.norm(cfg, p["mlp_norm"], x)
         y, _ = moe_block(ctx, p["moe"], h, groups=groups or ctx.moe_groups,
                          zero_drop=True)
-        return ctx.shard(x + y, "act_resid"), new_cache
+        return ctx.shard(x + y, "act_resid"), cache
 
     return decode_block
 
@@ -351,31 +345,25 @@ def _moe_chunk_mlp(ctx: LayerCtx, p: Params, h, groups: int):
 
 
 def make_chunk_block(groups: int = 0):
-    def chunk_block(ctx: LayerCtx, p: Params, x, cache_i, lengths,
-                    chunk_lens, block_tables=None):
+    def chunk_block(ctx: LayerCtx, p: Params, x, cache, lengths,
+                    chunk_lens, block_tables=None, layer=None):
         cfg = ctx.cfg
         h = L.norm(cfg, p["attn_norm"], x)
         if block_tables is None:
             a, ck, cv = L.attention_chunk_block(
-                ctx, p["attn"], h, cache_i["k"], cache_i["v"], lengths,
+                ctx, p["attn"], h, cache["k"], cache["v"], lengths,
                 chunk_lens,
             )
-            new_cache = {"k": ck, "v": cv}
+            cache = {"k": ck, "v": cv}
         else:
-            a, ck, cv, ks, vs = L.attention_chunk_block_paged(
-                ctx, p["attn"], h, cache_i["k"], cache_i["v"], block_tables,
-                lengths, chunk_lens,
-                k_scale=cache_i.get("k_scale"),
-                v_scale=cache_i.get("v_scale"),
+            a, cache = L.attention_chunk_block_paged(
+                ctx, p["attn"], h, cache, layer, block_tables, lengths,
+                chunk_lens,
             )
-            new_cache = {"k": ck, "v": cv}
-            if ks is not None:
-                new_cache["k_scale"] = ks
-                new_cache["v_scale"] = vs
         x = x + a
         h = L.norm(cfg, p["mlp_norm"], x)
         x = ctx.shard(x + _moe_chunk_mlp(ctx, p, h, groups), "act_resid")
-        return x, new_cache
+        return x, cache
 
     return chunk_block
 

@@ -6,6 +6,9 @@ Uniform block interfaces:
     layers.
   * cached decode-style: ``block_fn(p_i, x, cache_i) -> (x, new_cache_i)``
     where ``cache_i`` is the per-layer slice of a stacked cache pytree.
+  * in-place decode-style (the paged pool):
+    ``block_fn(p_i, x, cache, layer) -> (x, cache)`` — the whole stacked
+    cache rides in the carry and each layer writes its own part of it.
 
 ``unroll=False`` uses ``lax.scan`` over the stacked-L params (compact HLO —
 the only while-loop in the whole program, with a known trip count);
@@ -101,3 +104,26 @@ def run_stack_cached(layers_params, x, cache, block_fn: Callable,
         return x, c_new
 
     return jax.lax.scan(layer_scan_body, x, (layers_params, cache))
+
+
+def run_stack_in_place(layers_params, x, cache, block_fn: Callable,
+                       *, unroll: bool = False):
+    """Returns (x, cache) — for a stacked cache that each layer updates in
+    place: the cache is carried whole (never sliced into per-layer ``xs``
+    and reassembled from ``ys``), and ``block_fn`` gets the layer index to
+    address its own part. Only what the layers write changes, so a donated
+    cache is updated in its own buffer. ``unroll=True`` passes the index
+    as a Python int."""
+    if unroll:
+        for layer, p_i in enumerate(unstack(layers_params)):
+            x, cache = block_fn(p_i, x, cache, layer)
+        return x, cache
+
+    def layer_scan_body(carry, xs):
+        p_i, layer = xs
+        return block_fn(p_i, *carry, layer), None
+
+    layers = jnp.arange(num_layers_of(layers_params), dtype=jnp.int32)
+    (x, cache), _ = jax.lax.scan(layer_scan_body, (x, cache),
+                                 (layers_params, layers))
+    return x, cache

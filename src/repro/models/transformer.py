@@ -69,17 +69,18 @@ def block(ctx: LayerCtx, p: Params, x: jax.Array,
 
 
 def decode_block(ctx: LayerCtx, p: Params, x: jax.Array, position: jax.Array,
-                 cache_i: dict, lengths: jax.Array,
+                 cache: dict, lengths: jax.Array,
                  block_tables: Optional[jax.Array] = None,
-                 decode_groups=None):
+                 decode_groups=None, layer=None):
     """One-token decode block over either KV layout.
 
-    ``block_tables is None`` means the per-layer cache slice is a dense
-    (B, S, HK, Dh) slot cache; with tables it is the shared (NP, PS, HK,
-    Dh) page pool, addressed through the (B, NB) logical→physical map.
-    The discriminator is resolved at trace time — each engine layout
-    compiles exactly one path. ``decode_groups`` (paged only) switches to
-    the prefix-shared grouped attention path.
+    ``block_tables is None`` means ``cache`` is this layer's dense (B, S,
+    HK, Dh) slot cache; with tables it is the whole stacked (L, NP, PS,
+    HK, Dh) page pool, addressed through the (B, NB) logical→physical map
+    at layer ``layer`` and updated in place. The discriminator is resolved
+    at trace time — each engine layout compiles exactly one path.
+    ``decode_groups`` (paged only) switches to the prefix-shared grouped
+    attention path.
 
     The layer runs as three explicit stage boundaries (ingest → attend →
     epilogue, see :mod:`repro.models.layers`); the plan's
@@ -89,50 +90,41 @@ def decode_block(ctx: LayerCtx, p: Params, x: jax.Array, position: jax.Array,
     q, k, v = L.decode_ingest(ctx, p["attn_norm"], p["attn"], x, position)
     if block_tables is None:
         o, ck, cv = L.decode_attend(
-            ctx, q, k, v, cache_i["k"], cache_i["v"], lengths
+            ctx, q, k, v, cache["k"], cache["v"], lengths
         )
-        new_cache = {"k": ck, "v": cv}
+        cache = {"k": ck, "v": cv}
     else:
-        o, ck, cv, ks, vs = L.decode_attend_paged(
-            ctx, q, k, v, cache_i["k"], cache_i["v"],
-            block_tables, lengths, decode_groups=decode_groups,
-            k_scale=cache_i.get("k_scale"), v_scale=cache_i.get("v_scale"),
+        o, cache = L.decode_attend_paged(
+            ctx, q, k, v, cache, layer, block_tables, lengths,
+            decode_groups=decode_groups,
         )
-        new_cache = {"k": ck, "v": cv}
-        if ks is not None:   # quantized layout: scale pools ride along
-            new_cache["k_scale"] = ks
-            new_cache["v_scale"] = vs
     x = L.decode_epilogue(ctx, p["attn"], o, x)
     x = L.decode_mlp(ctx, p["mlp_norm"], p["mlp"], x)
-    return ctx.shard(x, "act_resid"), new_cache
+    return ctx.shard(x, "act_resid"), cache
 
 
-def chunk_block(ctx: LayerCtx, p: Params, x: jax.Array, cache_i: dict,
+def chunk_block(ctx: LayerCtx, p: Params, x: jax.Array, cache: dict,
                 lengths: jax.Array, chunk_lens: jax.Array,
-                block_tables: Optional[jax.Array] = None):
-    """Chunked-prefill block (decode-shaped path) over either KV layout."""
+                block_tables: Optional[jax.Array] = None, layer=None):
+    """Chunked-prefill block (decode-shaped path) over either KV layout
+    (``cache``/``layer`` as in :func:`decode_block`)."""
     cfg = ctx.cfg
     h = L.norm(cfg, p["attn_norm"], x)
     if block_tables is None:
         a, ck, cv = L.attention_chunk_block(
-            ctx, p["attn"], h, cache_i["k"], cache_i["v"], lengths,
+            ctx, p["attn"], h, cache["k"], cache["v"], lengths,
             chunk_lens
         )
-        new_cache = {"k": ck, "v": cv}
+        cache = {"k": ck, "v": cv}
     else:
-        a, ck, cv, ks, vs = L.attention_chunk_block_paged(
-            ctx, p["attn"], h, cache_i["k"], cache_i["v"], block_tables,
-            lengths, chunk_lens,
-            k_scale=cache_i.get("k_scale"), v_scale=cache_i.get("v_scale"),
+        a, cache = L.attention_chunk_block_paged(
+            ctx, p["attn"], h, cache, layer, block_tables, lengths,
+            chunk_lens,
         )
-        new_cache = {"k": ck, "v": cv}
-        if ks is not None:
-            new_cache["k_scale"] = ks
-            new_cache["v_scale"] = vs
     x = x + a
     h = L.norm(cfg, p["mlp_norm"], x)
     x = x + L.mlp_block(ctx, p["mlp"], h)
-    return ctx.shard(x, "act_resid"), new_cache
+    return ctx.shard(x, "act_resid"), cache
 
 
 def prefill_block(ctx: LayerCtx, p: Params, x: jax.Array,
@@ -279,6 +271,19 @@ def prefill(
     return logits, cache
 
 
+def _run_layers(layers_params, x, cache, block_tables, block_fn, *, unroll):
+    """The cached depth loop for either KV layout. The paged pool is
+    carried whole and written in place (``stack.run_stack_in_place``);
+    a dense slot cache goes through the scan per layer slice.
+    ``block_fn(p_i, x, cache, layer)``."""
+    if block_tables is not None:
+        return stack.run_stack_in_place(layers_params, x, cache, block_fn,
+                                        unroll=unroll)
+    return stack.run_stack_cached(
+        layers_params, x, cache,
+        lambda p_i, xx, c_i: block_fn(p_i, xx, c_i, None), unroll=unroll)
+
+
 def decode_step(
     ctx: LayerCtx, params: Params, tokens: jax.Array, cache: dict,
     lengths: jax.Array, *, block_tables: Optional[jax.Array] = None,
@@ -291,7 +296,9 @@ def decode_step(
     One signature for both KV layouts: with ``block_tables=None`` the cache
     leaves are dense (L, B, S, HK, Dh) slot caches; with a (B, NB)
     logical→physical page map they are (L, NP, PS, HK, Dh) page pools (the
-    scan carries the pool, the table rides in closure). ``decode_groups``
+    scan carries the whole pool and each layer writes its new positions in
+    place, see :func:`_run_layers`; the table rides in closure).
+    ``decode_groups``
     rides along the same way and activates prefix-shared grouped attention
     on the paged layout.
 
@@ -311,11 +318,11 @@ def decode_step(
     x = L.embed(ctx, params, tokens[:, None])  # (B, 1, D)
     position = lengths if positions is None else positions
 
-    x, new_cache = stack.run_stack_cached(
-        params["layers"], x, cache,
-        lambda p_i, xx, c_i: decode_block_fn(ctx, p_i, xx, position, c_i,
-                                             lengths, block_tables,
-                                             decode_groups),
+    x, new_cache = _run_layers(
+        params["layers"], x, cache, block_tables,
+        lambda p_i, xx, c, layer: decode_block_fn(
+            ctx, p_i, xx, position, c, lengths, block_tables, decode_groups,
+            layer=layer),
         unroll=unroll,
     )
     x = L.norm(cfg, params["final_norm"], x)
@@ -343,10 +350,10 @@ def prefill_chunk(
     cfg = ctx.cfg
     x = L.embed(ctx, params, tokens)           # (B, C, D)
 
-    x, new_cache = stack.run_stack_cached(
-        params["layers"], x, cache,
-        lambda p_i, xx, c_i: chunk_block_fn(ctx, p_i, xx, c_i, lengths,
-                                            chunk_lens, block_tables),
+    x, new_cache = _run_layers(
+        params["layers"], x, cache, block_tables,
+        lambda p_i, xx, c, layer: chunk_block_fn(
+            ctx, p_i, xx, c, lengths, chunk_lens, block_tables, layer=layer),
         unroll=unroll,
     )
     x = L.norm(cfg, params["final_norm"], x)

@@ -176,6 +176,48 @@ class EngineStats:
     #                                  per-layer streams)
 
 
+def _page_rows(flat, nl: int, num_pages: int, pages):
+    """Index of pages ``pages`` of every layer in a flat ``(L*NP, ...)``
+    page view (:meth:`PagedLayout.page_view`), layer-major; entries ``>=
+    NP`` point past the pool. K/V pages are addressed as (page, row) rows
+    like the steps' own token writes: a whole-page scatter makes the
+    compiler relay a row-major pool for it, and back."""
+    idx = jnp.where(pages < num_pages,
+                    jnp.arange(nl)[:, None] * num_pages + pages,
+                    nl * num_pages).reshape(-1)
+    return ((idx,) if flat.ndim < 4 else
+            (idx[:, None], jnp.arange(flat.shape[1])))
+
+
+def gather_pages(cache: dict, pages) -> dict:
+    """Tier demotion: the named pages' slabs of every paged cache leaf,
+    ``(layers, n, ...)`` each, whatever shape the pool is stored in."""
+    flat, nl, num_pages = PagedLayout.page_view(cache)
+    return {n: a[_page_rows(a, nl, num_pages, pages)].reshape(
+                nl, pages.shape[0], *a.shape[1:])
+            for n, a in flat.items()}
+
+
+def promote_upload(cache: dict, slabs: dict, dst) -> dict:
+    """Tiered promotion: write a batch of slabs (stacked to ``(layers, n,
+    ...)`` per leaf) into pages ``dst`` in one donated update; entries
+    ``dst >= NP`` (padding rows) are dropped, so slab batches share a pow2
+    family of compiles."""
+    flat, nl, num_pages = PagedLayout.page_view(cache)
+    return {n: a.at[_page_rows(a, nl, num_pages, dst)].set(
+                slabs[n].astype(a.dtype).reshape(-1, *a.shape[1:]),
+                mode="drop").reshape(cache[n].shape)
+            for n, a in flat.items()}
+
+
+def copy_page(cache: dict, src, dst) -> dict:
+    """COW fork: copy one page's slab across all layers to a privately
+    owned destination page (a donated in-place update; ``src``/``dst``
+    trace as scalars so every fork reuses one compile)."""
+    return promote_upload(cache, gather_pages(cache, jnp.atleast_1d(src)),
+                          jnp.atleast_1d(dst))
+
+
 class Engine:
     def __init__(
         self,
@@ -352,7 +394,17 @@ class Engine:
         else:
             raise ValueError(f"unknown cache_kind {cache_kind!r}")
         self.cache_kind = cache_kind
-        self.cache = self.api.init_cache(self.layout)
+        # a paged pool is allocated in the shapes the device lays out the
+        # way its kernels read it (PagedLayout.device_storage); the model
+        # and the page copies view any stored shape as flat pages
+        # (PagedLayout.page_view)
+        if cache_kind == "paged":
+            self.cache = jax.tree.map(
+                lambda s: jnp.zeros(s.shape, s.dtype),
+                PagedLayout.device_storage(self.api.cache_spec(self.layout),
+                                           jax.devices()[0]))
+        else:
+            self.cache = self.api.init_cache(self.layout)
 
         self.seed = seed
         self._base_key = jax.random.PRNGKey(seed)
@@ -381,23 +433,6 @@ class Engine:
         def prefill_chunk(p, t, cl, c, bt, le):
             return self.api.prefill_chunk(self.ctx, p, t, cl, c, le,
                                           block_tables=bt)
-
-        # COW fork: copy one page's (layers, page_size, kv_heads, head_dim)
-        # slab to a privately owned destination page (donated in-place
-        # update; src/dst trace as scalars so every fork reuses one
-        # compile)
-        def copy_page(c, src, dst):
-            return jax.tree.map(lambda a: a.at[:, dst].set(a[:, src]), c)
-
-        # tiered promotion: scatter a batch of host slabs (stacked to
-        # (layers, n, page_size, kv_heads, head_dim) per leaf) into fresh
-        # tier-0 pages in one donated update; padding rows carry the OOB
-        # sentinel destination and are dropped, so slab batches share a
-        # pow2 family of compiles
-        def promote_upload(c, s, d):
-            return jax.tree.map(
-                lambda a, b: a.at[:, d].set(b.astype(a.dtype), mode="drop"),
-                c, s)
 
         # prefix-shared grouped decode: when the tuned plan asks for it
         # (and refcounted sharing is on so groups can exist), decode ticks
@@ -440,6 +475,8 @@ class Engine:
                            if cache_kind == "paged" else None)
         self._promote_upload = (jax.jit(promote_upload, donate_argnums=(0,))
                                 if self.tiers is not None else None)
+        self._gather_pages_fn = (jax.jit(gather_pages)
+                                 if self.tiers is not None else None)
         self._group_decode = (
             cache_kind == "paged" and prefix_sharing
             and self.plan.paged.decode_group == "grouped")
@@ -1034,8 +1071,9 @@ class Engine:
         idx = np.full((nb,), pages[0], np.int32)   # pad rows discarded
         idx[:n] = pages
         idxd = jnp.asarray(idx)
-        host = [np.asarray(leaf[:, idxd])
-                for leaf in jax.tree.leaves(self.cache)]
+        host = [np.asarray(a)
+                for a in jax.tree.leaves(
+                    self._gather_pages_fn(self.cache, idxd))]
         self.stats.host_syncs += len(host)
         return {p: tuple(np.ascontiguousarray(h[:, i]) for h in host)
                 for i, p in enumerate(pages)}

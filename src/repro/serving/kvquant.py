@@ -53,8 +53,9 @@ def scatter_chunk_quantized(codes, steps, new, block_tables, lengths,
                             chunk_lens, spec: quant.QuantSpec):
     """Append a (possibly ragged) token chunk into quantized page pools.
 
-    codes:  (NP, PS, HK, D) code pool (one layer's K or V)
-    steps:  (NP, HK) f32 step pool
+    codes:  (NP, PS, HK, D) code pool (one layer's K or V, or every
+            layer's as one flat page axis, with tables translated to it)
+    steps:  (NP, HK) f32 step pool, paged alike
     new:    (B, C, HK, D) full-precision values; row b contributes its
             first chunk_lens[b] tokens at positions lengths[b]..
     block_tables: (B, NB) logical->physical page map
@@ -102,13 +103,16 @@ def scatter_chunk_quantized(codes, steps, new, block_tables, lengths,
     tok_step = steps[jnp.clip(phys, 0, np_ - 1)]               # (B,C,HK)
 
     # 5) re-express each touched page's existing codes under its new step
-    # (ratio 1 -> bitwise no-op; old_step 0 -> stale codes launder to 0)
-    old_codes = codes[span_safe]                       # (B,S,PS,HK,D)
+    # (ratio 1 -> bitwise no-op; old_step 0 -> stale codes launder to 0).
+    # Pages are addressed row by row, (page, offset), like the token
+    # write below, so both keep the pool in its own layout
+    rows = jnp.arange(ps)[None, None, :]
+    old_codes = codes[span_safe[..., None], rows]      # (B,S,PS,HK,D)
     requant = quant.rescale_codes(
         old_codes, old_step[:, :, None, :], new_step[:, :, None, :], spec)
 
     # 6) write rescaled pages back, then 7) the new tokens on top
-    codes = codes.at[span_phys].set(requant, mode="drop")
+    codes = codes.at[span_phys[..., None], rows].set(requant, mode="drop")
     codes = codes.at[phys, pos % ps].set(
         quant.encode(new, tok_step, spec), mode="drop")
     return codes, steps

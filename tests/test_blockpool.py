@@ -4,14 +4,19 @@ engine equivalence through the unified KVLayout path — greedy outputs must
 be token-identical, including runs where overcommit forces preemption and
 re-admission recycles pages."""
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from _hypothesis_compat import given, settings, st
 
 from repro import configs
+from repro.core.plan import make_plan
+from repro.kernels.group_attention import DecodeGroups
+from repro.models import stack
 from repro.models.api import get_model
 from repro.models.kvlayout import DenseLayout, PagedLayout, pages_for
+from repro.models.layers import LayerCtx
 from repro.serving.blockpool import BlockPool, PagedSlotManager
 from repro.serving.engine import Engine
 from repro.serving.request import SamplingParams
@@ -371,3 +376,93 @@ def test_paged_engine_rejects_recurrent_families():
     params = api.init_params(jax.random.PRNGKey(0))
     with pytest.raises(ValueError):
         Engine(cfg, params, cache_kind="paged")
+
+
+# ---------------------------------------------------------------------------
+# The stacked pool is carried through the depth loop and written in place
+# ---------------------------------------------------------------------------
+
+
+def _slab_stack(layers_params, x, cache, block_fn, *, unroll=False):
+    """Per-layer slab reference for ``stack.run_stack_in_place``: the depth
+    loop slices each layer's (NP, ...) slab out and stacks the new slabs
+    back, and every layer sees a one-layer pool — nothing it writes can
+    reach another layer's pages."""
+    def per_slab(p_i, x, c_i):
+        x, c = block_fn(p_i, x, jax.tree.map(lambda a: a[None], c_i), 0)
+        return x, jax.tree.map(lambda a: a[0], c)
+
+    return stack.run_stack_cached(layers_params, x, cache, per_slab,
+                                  unroll=unroll)
+
+
+def _random_cache(api, layout, rng):
+    """A pool that already holds data, so a misplaced or missing write
+    shows in the contents."""
+    def fill(s):
+        if s.dtype == jnp.float32:       # scale pools: positive steps
+            return jnp.asarray(rng.uniform(0.01, 0.1, s.shape), s.dtype)
+        if s.dtype == jnp.int8:
+            return jnp.asarray(rng.integers(-127, 128, s.shape), s.dtype)
+        return jnp.asarray(rng.normal(size=s.shape), s.dtype)
+
+    return jax.tree.map(fill, api.cache_spec(layout))
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@pytest.mark.parametrize("granularity", ["looped", "fused"])
+@pytest.mark.parametrize("step", ["decode", "grouped", "chunk"])
+def test_carried_pool_matches_slab_reference(monkeypatch, step, granularity,
+                                             kv):
+    """The paged decode, grouped-decode and chunk steps write into the
+    whole stacked pool in place; pool contents and logits must equal the
+    per-layer slab reference bit for bit. Row 1 is an empty slot whose
+    table is all sentinel: it writes nothing, and layer 0's sentinel must
+    not land on layer 1's page 0 (held by rows 0 and 2). int8 pools bring
+    their scale pools along; the Pallas kernels run in interpret mode."""
+    cfg = configs.smoke(configs.get("qwen2-0.5b"))
+    api = get_model(cfg)
+    params = api.init_params(jax.random.PRNGKey(0))
+    plan = make_plan("pallas", gather_chunk="fused", decode_group="grouped",
+                     kv_dtype=kv, decode_fusion=granularity)
+    ctx = LayerCtx(cfg=cfg, plan=plan)
+    num_pages, ps = 8, 16
+    layout = PagedLayout(num_pages, ps, kv)
+    rng = np.random.default_rng(11)
+    cache = _random_cache(api, layout, rng)
+    bt = jnp.asarray([[0, 5, num_pages, num_pages],
+                      [num_pages] * 4,
+                      [0, 6, 1, num_pages]], jnp.int32)
+    groups = DecodeGroups(*(jnp.asarray(a, jnp.int32) for a in (
+        [[0]], [1], [ps], [2], [[0, 2]], [0, 1, 0], [0, 0, 1],
+        [ps, 0, ps])))
+    if step == "chunk":
+        lengths = jnp.asarray([ps, 0, 2 * ps + 8], jnp.int32)
+        chunk_lens = jnp.asarray([ps, 0, 5], jnp.int32)
+        tokens = jnp.asarray(rng.integers(1, cfg.vocab_size, (3, ps)),
+                             jnp.int32)
+
+        def run(c):
+            return api.prefill_chunk(ctx, params, tokens, chunk_lens, c,
+                                     lengths, block_tables=bt,
+                                     unroll=granularity == "fused")
+    else:
+        lengths = jnp.asarray([ps + 4, 0, 2 * ps + 8], jnp.int32)
+        tokens = jnp.asarray([3, 0, 7], jnp.int32)
+        gr = groups if step == "grouped" else None
+
+        def run(c):
+            return api.decode_step(ctx, params, tokens, c, lengths,
+                                   block_tables=bt, decode_groups=gr)
+
+    logits, got = jax.jit(run)(cache)
+    monkeypatch.setattr(stack, "run_stack_in_place", _slab_stack)
+    # a function of its own, so the reference is traced anew
+    want_logits, want = jax.jit(lambda c: run(c))(cache)
+    assert np.array_equal(np.asarray(logits), np.asarray(want_logits))
+    for name in want:
+        assert np.array_equal(np.asarray(got[name]), np.asarray(want[name]))
+    # the step wrote something, and only into the pages rows 0 and 2 own
+    changed = np.asarray(got["k"] != cache["k"]).any(axis=(2, 3, 4))
+    assert changed.any()
+    assert not changed[:, [2, 3, 4, 7]].any()

@@ -7,11 +7,14 @@ bar — greedy outputs bit-identical for resumed-from-demoted vs
 re-prefilled vs never-preempted sequences (dense vs paged too), through
 host and disk tiers and through the eviction fallback."""
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro import configs
 from repro.models.api import get_model
+from repro.models.kvlayout import PagedLayout
+from repro.models.layers import LayerCtx
 from repro.serving.blockpool import BlockPool, PagedSlotManager
 from repro.serving.engine import Engine
 from repro.serving.prefix import PrefixIndex
@@ -487,6 +490,60 @@ def test_quantized_pages_demote_promote_bitwise(smoke_model, tmp_path):
             assert g.dtype == w.dtype
             np.testing.assert_array_equal(g, w)
     eng.slots.check()
+
+
+def _split_storage(spec, device):
+    """Stands in for a device on which ``PagedLayout.device_storage``
+    splits every pool's page axis in two: ``(L, NP/2, 2, ...)``."""
+    return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        (s.shape[0], s.shape[1] // 2, 2, *s.shape[2:]), s.dtype), spec)
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_split_pool_storage_serves_identically(smoke_model, monkeypatch,
+                                               kv):
+    """A pool stored with its page axis split, as a TPU's storage is for
+    a head_dim under 128 lanes, serves exactly as the logical pool: the
+    same greedy tokens through prefix sharing, COW forks, demotion and
+    promotion, the same bytes in every page, and the model's own steps,
+    called on the engine's cache, give the same logits and pool."""
+    cfg, params = smoke_model
+    reqs = _reqs(cfg, seed=47)
+    reqs += [(reqs[0][0][:32].copy(), reqs[0][1])]   # page-aligned: forks
+    base = _engine(cfg, params, kv_dtype=kv, host_pages=64)
+    monkeypatch.setattr(PagedLayout, "device_storage",
+                        staticmethod(_split_storage))
+    eng = _engine(cfg, params, kv_dtype=kv, host_pages=64)
+    for name, a in eng.cache.items():
+        assert a.shape == _split_storage(base.cache[name], None).shape
+    for e in (base, eng):
+        e.outs = [_toks(e.run(_rerun(reqs)))]
+        e.evict_finished(flush=True)
+        e.outs.append(_toks(e.run(_rerun(reqs))))    # promotes back
+    assert eng.outs == base.outs
+    assert eng.stats.cow_forks > 0 and eng.stats.promoted_pages > 0
+    assert (eng.stats.cow_forks, eng.stats.promoted_pages) == (
+        base.stats.cow_forks, base.stats.promoted_pages)
+
+    def logical(cache):
+        return {n: np.asarray(a).reshape(base.cache[n].shape)
+                for n, a in cache.items()}
+
+    want = logical(base.cache)
+    for n, a in logical(eng.cache).items():
+        np.testing.assert_array_equal(a, want[n])
+
+    # a caller holding the engine's cache steps the model on it directly
+    api, ctx = get_model(cfg), LayerCtx(cfg=cfg, plan=eng.plan)
+    table = jnp.arange(16, dtype=jnp.int32).reshape(4, 4)
+    lengths = jnp.asarray([20, 33, 5, 47], jnp.int32)
+    toks = jnp.arange(1, 5, dtype=jnp.int32)
+    outs = [api.decode_step(ctx, params, toks, e.cache, lengths,
+                            block_tables=table) for e in (base, eng)]
+    np.testing.assert_array_equal(np.asarray(outs[1][0]),
+                                  np.asarray(outs[0][0]))
+    for n, a in logical(outs[1][1]).items():
+        np.testing.assert_array_equal(a, np.asarray(outs[0][1][n]))
 
 
 def test_eviction_fallback_reprefills_identically(smoke_model):

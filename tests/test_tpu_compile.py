@@ -10,20 +10,31 @@ checks that the program really contains the Mosaic kernel
 (``tpu_custom_call``). Nothing runs, so these say nothing about results or
 times — the interpret-mode oracle tests cover results.
 
+The last tests compile whole paged step programs as the engine builds
+them, on four-layer stacks at phi3's and qwen's attention widths, and
+read the optimized HLO: the pool must be updated in place (no copy,
+slice or transpose of a pool or a layer's slab, the cache outputs
+aliased to the donated inputs) and every paged kernel must read it as a
+``(pages, PS, HK, D)`` operand.
+
 The topology is described inside a module fixture (never at import), so
 only the worker that runs this file loads the TPU compiler, and every
 worker collects the same tests.
 """
+import dataclasses
 import functools
 import json
 import os
+import re
 
 import jax
+import numpy as np
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro import configs
+from repro import configs, hardware
+from repro.core.plan import make_plan
 from repro.kernels.chunk_attention import (
     paged_chunk_attention_sync,
     paged_chunk_attention_unified_max,
@@ -43,10 +54,15 @@ from repro.kernels.flash_prefill import flash_prefill
 from repro.kernels.flat_gemm import flat_gemm
 from repro.kernels.fused_ffn import fused_ffn_up
 from repro.kernels.gemv import gemv
+from repro.kernels import ops
 from repro.kernels.group_attention import (
     DecodeGroups,
     grouped_paged_decode_attention_unified_max,
 )
+from repro.models.api import get_model
+from repro.models.kvlayout import PagedLayout
+from repro.models.layers import LayerCtx
+from repro.serving.engine import copy_page, promote_upload
 
 CFG = configs.get("qwen2-0.5b")
 SLOTS, PAGE, MAX_SEQ, CHUNK = 8, 64, 1024, 64   # the served engine's shape
@@ -89,6 +105,15 @@ def shape(one_chip):
     """``shape(dims, dtype)`` -> a ShapeDtypeStruct placed on the chip."""
     return lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype,
                                                     sharding=one_chip)
+
+
+def _groups(shape, ng, lp, m):
+    i32 = jnp.int32
+    return DecodeGroups(
+        tables=shape((ng, lp), i32), n_pages=shape((ng,), i32),
+        g_prefix_len=shape((ng,), i32), num_members=shape((ng,), i32),
+        member_rows=shape((ng, m), i32), gid=shape((SLOTS,), i32),
+        member=shape((SLOTS,), i32), prefix_len=shape((SLOTS,), i32))
 
 
 def _compiles_to_mosaic(fn, *args):
@@ -161,13 +186,8 @@ def test_dense_decode_compiles(shape, scheme):
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
 def test_grouped_decode_compiles(shape, kv):
     (kp, vp), (ks, vs) = _pools(shape, kv)
-    ng, lp, m = 2, 2, SLOTS
+    groups = _groups(shape, 2, 2, SLOTS)
     i32 = jnp.int32
-    groups = DecodeGroups(
-        tables=shape((ng, lp), i32), n_pages=shape((ng,), i32),
-        g_prefix_len=shape((ng,), i32), num_members=shape((ng,), i32),
-        member_rows=shape((ng, m), i32), gid=shape((SLOTS,), i32),
-        member=shape((SLOTS,), i32), prefix_len=shape((SLOTS,), i32))
 
     def fn(q, k_pool, v_pool, bt, lengths, groups, k_scale, v_scale):
         return grouped_paged_decode_attention_unified_max(
@@ -292,3 +312,137 @@ def test_fused_ffn_compiles(shape, m, wdtype):
         return fused_ffn_up(x, wg, wu, wg_scale=sg, wu_scale=su)
 
     _compiles_to_mosaic(fn, shape((m, D), jnp.bfloat16), wg, wu, sg, su)
+
+
+# ---------------------------------------------------------------------------
+# Whole step programs: each writes only its new positions into the donated
+# pool, and the kernels read the stacked pool in place
+# ---------------------------------------------------------------------------
+
+# (config, pages): MHA 32 x 96 at the phi3 cell's 128 pages and GQA 14/2 x
+# 64; four layers each, so every pool is larger than the chip's VMEM, as a
+# served pool is
+STEP_CASES = {"phi3": ("phi3-mini-3.8b", 128), "qwen": ("qwen2-0.5b", 2048)}
+STEP_LAYERS = 4
+# copies, slices, transposes: any of them on a pool or a slab moves it all
+MOVES = re.compile(r"copy|slice|transpose")
+PAGED_KERNELS = ("paged_decode_attention", "paged_chunk_attention",
+                 "grouped_decode_")
+
+
+def _program_hlo(topo, shape, monkeypatch, arch, num_pages, granularity,
+                 program):
+    """Optimized HLO of one program that takes the pool donated, built as
+    ``Engine`` builds it — a step (``decode``, ``grouped``, ``chunk``) or
+    a page copy (``copy_page``, ``promote_upload``) — for a
+    ``STEP_LAYERS``-layer stack at ``arch``'s widths, on the pool in the
+    shapes the engine stores it in on a v5e."""
+    monkeypatch.setattr(ops, "kernel_target",
+                        lambda: (False, hardware.TPU_V5E))
+    cfg = dataclasses.replace(configs.get(arch), num_layers=STEP_LAYERS)
+    api = get_model(cfg)
+    plan = make_plan("pallas", gather_chunk="fused", decode_group="grouped",
+                     decode_fusion=granularity)
+    ctx = LayerCtx(cfg=cfg, plan=plan)
+    spec = api.cache_spec(PagedLayout(num_pages, PAGE))
+    cache = jax.tree.map(lambda s: shape(s.shape, s.dtype),
+                         PagedLayout.device_storage(spec, topo.devices[0]))
+    params = jax.tree.map(lambda s: shape(s.shape, s.dtype),
+                          jax.eval_shape(api.init_params,
+                                         jax.random.PRNGKey(0)))
+    i32 = jnp.int32
+    rows, table = shape((SLOTS,), i32), shape((SLOTS, NB), i32)
+    if program == "decode":
+        def fn(p, t, c, bt, le, po):
+            return api.decode_step(ctx, p, t, c, le, block_tables=bt,
+                                   positions=po)
+        args, donate = (params, rows, cache, table, rows, rows), 2
+    elif program == "grouped":
+        def fn(p, t, c, bt, le, gr, po):
+            return api.decode_step(ctx, p, t, c, le, block_tables=bt,
+                                   decode_groups=gr, positions=po)
+        args = (params, rows, cache, table, rows,
+                _groups(shape, 4, 4, SLOTS), rows)
+        donate = 2
+    elif program == "chunk":
+        def fn(p, t, cl, c, bt, le):
+            return api.prefill_chunk(ctx, p, t, cl, c, le, block_tables=bt)
+        args = (params, shape((SLOTS, CHUNK), i32), rows, cache, table,
+                rows)
+        donate = 3
+    elif program == "copy_page":
+        fn, args, donate = copy_page, (cache, shape((), i32),
+                                       shape((), i32)), 0
+    else:
+        n = 4
+        slabs = jax.tree.map(
+            lambda s: shape((s.shape[0], n, *s.shape[2:]), s.dtype), spec)
+        fn, args, donate = promote_upload, (cache, slabs,
+                                            shape((n,), i32)), 0
+    low = jax.jit(fn, donate_argnums=(donate,)).lower(*args)
+    return low.compile().as_text(), cfg, cache["k"].shape
+
+
+def _assert_in_place(text, cfg, num_pages, stored):
+    """No pool- or slab-sized move in ``text``, and both pools of the
+    output aliased to the donated pool arguments."""
+    hk, d = cfg.num_kv_heads, cfg.head_dim
+    # a pool, a flat pool view or a slab: (..., HK, D) with at least a
+    # layer's pages' rows in front, pages and rows merged or not
+    pooled = re.compile(rf"bf16\[([\d,]+),{hk},{d}\]")
+
+    def is_pool(dims):
+        lead = [int(x) for x in dims.split(",")]
+        return int(np.prod(lead)) >= num_pages * PAGE
+
+    moved = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([\w\-]+)\(",
+                     line)
+        if m and MOVES.search(m[1] + " " + m[3]) and any(
+                is_pool(dims) for dims in pooled.findall(m[2])):
+            moved.append(line.strip()[:160])
+    assert not moved, "pool-sized moves:\n" + "\n".join(moved)
+
+    # the cache leaves of the output alias the donated cache arguments
+    entry = text[text.index("\nENTRY"):]
+    args = {n: t for t, n in re.findall(
+        r"= (bf16\[[\d,]+\])\S* parameter\((\d+)\)", entry)}
+    header = text.splitlines()[0]          # HloModule ..., input_output_alias
+    aliased = re.findall(r"\{(\d+)\}: \((\d+), \{\}", header)
+    pool_shape = "bf16[" + ",".join(map(str, stored)) + "]"
+    assert len({out for out, arg in aliased
+                if args.get(arg) == pool_shape}) == 2
+
+
+@pytest.mark.parametrize("program", ["decode", "grouped", "chunk"])
+@pytest.mark.parametrize("granularity", ["looped", "fused"])
+@pytest.mark.parametrize("case", sorted(STEP_CASES))
+def test_step_updates_pool_in_place(topo, shape, monkeypatch, case,
+                                    granularity, program):
+    arch, num_pages = STEP_CASES[case]
+    text, cfg, stored = _program_hlo(topo, shape, monkeypatch, arch,
+                                     num_pages, granularity, program)
+    _assert_in_place(text, cfg, num_pages, stored)
+
+    # every paged kernel reads a 4-D (pages, PS, HK, D) pool operand, the
+    # shape the benchmark's trace classes recognise decode attention by
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line
+               and re.match(r"\s*%(" + "|".join(PAGED_KERNELS) + ")", line)]
+    assert kernels
+    operand = re.compile(rf"bf16\[\d+,{PAGE},{cfg.num_kv_heads},"
+                         rf"{cfg.head_dim}\]")
+    assert all(operand.search(line) for line in kernels)
+
+
+@pytest.mark.parametrize("program", ["copy_page", "promote_upload"])
+@pytest.mark.parametrize("case", sorted(STEP_CASES))
+def test_page_copies_update_pool_in_place(topo, shape, monkeypatch, case,
+                                          program):
+    """The COW fork and the tier promotion write their pages into the
+    donated pool as the steps do: no relay of the pool around them."""
+    arch, num_pages = STEP_CASES[case]
+    text, cfg, stored = _program_hlo(topo, shape, monkeypatch, arch,
+                                     num_pages, "looped", program)
+    _assert_in_place(text, cfg, num_pages, stored)
