@@ -11,8 +11,8 @@ One process, no children. It
      d_ff 4864, vocab 151936) with random weights from ``--seed`` and an
      ``Engine`` under the committed Pallas plan
      (``plans/qwen2-0.5b-tpu-v5e.json``): paged cache, page size 64, 8
-     slots, max_seq 1024, chunked prefill, prefix sharing, and the plan's
-     decode-layer granularity;
+     slots, max_seq 1024, chunked prefill, prefix sharing, and the decode
+     seams under one layer scan;
   3. serves 8 requests — a 128-token shared prefix plus 384 unshared
      tokens each — for 32 greedy tokens apiece, once to compile and once
      timed, and checks that every request finished;
@@ -129,7 +129,7 @@ def main() -> int:
         cfg=cfg, spec=spec)
     backends = {plan.matmul.backend, plan.attention_decode.backend,
                 plan.attention_prefill.backend, plan.fused_ffn.backend,
-                plan.paged.backend, plan.decode_fusion.backend}
+                plan.paged.backend}
     _require(backends == {"pallas"}, f"plan is not all-Pallas: {backends}")
     print(f"plan: {plan.describe()}")
 

@@ -603,8 +603,8 @@ def param_bytes(cfg: ModelConfig, weight_dtype: str = "bf16", *,
     per-output-channel scales when quantized. ``lm_head`` (and the tied
     embedding) is excluded — it is not a per-layer stream and never
     quantizes — so this is both the resident GEMM weight footprint and
-    the exact bytes one decode tick reads (every granularity streams each
-    layer's weights once per tick).
+    the exact bytes one decode tick reads (each layer's weights once per
+    tick).
     """
     wb = WEIGHT_DTYPE_BYTES[weight_dtype]
     total = 0.0
@@ -707,78 +707,6 @@ def find_swap_threshold(
         if t_swap < t_pre:
             return pages
     return max_pages + 1
-
-
-# ---------------------------------------------------------------------------
-# Decode-fusion granularity (DecodeFusionPlan.granularity)
-# ---------------------------------------------------------------------------
-
-# per-layer stage dispatches in one decode tick. The split chain is the
-# full op list (norm, 3 QKV GEMMs, bias, 2 ropes, 2 KV scatters,
-# attention, o_proj, residual, mlp norm, gate/up GEMMs, activation,
-# down GEMM, residual); fusing the ingest seam (norm+QKV+rope), the
-# attention epilogue (o_proj+residual), the mlp ingest
-# (norm+gate/up+activation) and the down-projection epilogue
-# (down+residual) collapses it to: ingest, 2 scatters, attention,
-# epilogue, ffn_norm, down-epilogue.
-_DECODE_STAGES = {"split": 16, "fused": 7, "looped": 7}
-
-# one-time cost of entering the scan'd (looped) depth dispatch: the
-# while-loop's condition/carry plumbing, priced like one chunk-step
-# dispatch bubble
-_LOOP_SETUP_S = 2e-5
-
-# host-visible dispatch cost per stage when the layer loop is python-
-# unrolled: every traced stage is its own XLA computation boundary the
-# host runtime walks, vs. the scan'd path's single looped dispatch
-_HOST_DISPATCH_S = 1e-6
-
-
-def predict_fusion_time(
-    cfg: ModelConfig, granularity: str, *,
-    m: int = 1,
-    dtype_bytes: int = 2,
-    weight_dtype: str = "bf16",
-    spec: hardware.HardwareSpec = hardware.TPU_V5E,
-) -> float:
-    """Roofline time for one decode tick at a fusion granularity.
-
-    Decode at small batch is memory-bound: every tick streams each
-    layer's weights once regardless of granularity
-    (:func:`param_bytes` at the plan's ``weight_dtype`` — quantized
-    weights shrink the common term, so the fixed boundary costs weigh
-    relatively more), and the granularities differ only in *boundary*
-    cost — stage-dispatch bubbles per layer (:data:`_DECODE_STAGES`,
-    priced at the shared :data:`_PIPELINE_FILL_S` launch constant), plus
-    the host-side term: ``fused`` python-unrolls the depth (L × stages
-    host-visible dispatches), while ``split``/``looped`` run the whole
-    depth under one ``lax.scan`` (one looped dispatch + a fixed
-    :data:`_LOOP_SETUP_S`).
-    """
-    if granularity not in _DECODE_STAGES:
-        raise ValueError(f"unknown fusion granularity {granularity!r}")
-    weight_bytes = param_bytes(
-        cfg, weight_dtype, dtype_bytes=dtype_bytes) / cfg.num_layers
-    stages = _DECODE_STAGES[granularity]
-    t_layer = weight_bytes / spec.hbm_bw + stages * _PIPELINE_FILL_S
-    if granularity == "fused":
-        return cfg.num_layers * (t_layer + stages * _HOST_DISPATCH_S)
-    return cfg.num_layers * t_layer + _LOOP_SETUP_S
-
-
-def find_decode_fusion(
-    cfg: ModelConfig, *,
-    m: int = 1,
-    weight_dtype: str = "bf16",
-    spec: hardware.HardwareSpec = hardware.TPU_V5E,
-) -> str:
-    """Cheapest decode-tick granularity for this model (ties break toward
-    the earlier, simpler mode in ``FUSION_MODES`` order: split < fused <
-    looped)."""
-    modes = ("split", "fused", "looped")
-    times = {g: predict_fusion_time(cfg, g, m=m, weight_dtype=weight_dtype,
-                                    spec=spec) for g in modes}
-    return min(modes, key=lambda g: times[g])
 
 
 # ---------------------------------------------------------------------------

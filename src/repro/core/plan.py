@@ -14,9 +14,7 @@ selection a first-class tunable surface spanning the whole graph:
     ``attention_prefill`` chunking threshold + φ policy, ``fused_ffn``
     fused/unfused, the paged-path knobs — decode backend/scheme plus
     the chunked-prefill ``gather_chunk`` mode with its tuned
-    ``fused_threshold`` / ``chunk_block`` companions — and the
-    ``decode_fusion`` stage granularity: split op chain vs. fused
-    ingest/epilogue stage kernels vs. the looped whole-depth dispatch);
+    ``fused_threshold`` / ``chunk_block`` companions);
   * one offline :func:`tune` flow (``measure="analytical"`` roofline
     models in this CPU container, ``measure="wallclock"`` on real
     hardware) that generalizes ``find_inflections`` beyond GEMM;
@@ -35,7 +33,7 @@ import dataclasses
 import hashlib
 import json
 import os
-from typing import Callable, Dict, Optional, Tuple, Union
+from typing import Dict, Optional, Tuple, Union
 
 from repro import hardware
 from repro.config import ModelConfig
@@ -49,7 +47,6 @@ GATHER_MODES = ("dense", "fused")  # chunk-path page access discipline
 GROUP_MODES = ("off", "grouped")   # decode-path shared-prefix discipline
 KV_DTYPES = ("bf16", "int8", "fp8")  # paged KV page storage precision
 WEIGHT_DTYPES = ("bf16", "int8", "fp8")  # GEMM weight storage precision
-FUSION_MODES = ("split", "fused", "looped")  # decode-layer stage granularity
 
 
 class PlanError(ValueError):
@@ -283,62 +280,6 @@ class PagedPlan:
         _check(self.kv_dtype, KV_DTYPES, "paged.kv_dtype")
 
 
-@dataclasses.dataclass(frozen=True)
-class DecodeFusionPlan:
-    """Decode-layer fusion granularity (the kernel-looping axis).
-
-    Per-token decode is a chain of many small memory-bound ops per layer;
-    past the paged/quantized KV work the dominant small-batch cost is the
-    dispatch + synchronization boundary *between* them. ``granularity``
-    names how much of the per-layer chain one dispatch claims
-    (:data:`FUSION_MODES`):
-
-      * ``"split"`` — today's op chain: every stage (norm, QKV, rope,
-        scatter, attention, o_proj, residual, FFN) is its own dispatch,
-        the whole depth under one ``lax.scan``. The reference path,
-        bit-identical by definition.
-      * ``"fused"`` — the memory-bound seams collapse into fused stage
-        kernels (``ops.decode_ingest`` = norm→QKV→bias→rope,
-        ``ops.oproj_residual`` = GEMM-into-residual, serving both the
-        o_proj and FFN down-projection epilogues, and ``ops.ffn_norm``
-        = mlp_norm→gate/up→activation), with the layer loop
-        python-unrolled — L traced layer bodies, each a short fused
-        chain.
-      * ``"looped"`` — the same fused stage dispatch with the stacked-L
-        params run under one ``lax.scan`` (:mod:`repro.models.stack`):
-        the layer body is traced once and the whole depth is a single
-        host-visible looped dispatch — the Kernel Looping shape.
-
-    The fused stage *kernels* are Pallas-only; on the XLA backend the
-    ``fused``/``looped`` granularities dispatch the jnp oracles
-    (``ref.decode_ingest_ref`` / ``ref.oproj_residual_ref`` /
-    ``ref.ffn_norm_ref``), which compose exactly the split chain's
-    expressions in the same order.
-    ``split`` and ``looped`` therefore produce bit-identical logits on
-    XLA (same scan, same jaxpr per stage — tier-1 enforced). ``fused``
-    is the one documented reassociated seam: python-unrolling the L
-    layer bodies lets XLA place bf16 rounding at different fusion
-    boundaries than the scan body, so it is held to the scheme-swap
-    dtype-eps value-closeness bound instead (greedy tokens still agree
-    wherever the argmax is decisive). The Pallas kernels additionally
-    reassociate the K-streamed GEMM accumulation (f32 tile
-    accumulators), so kernel-vs-oracle equality is bounded by the same
-    dtype-eps closeness tests, like every other Pallas GEMM in the
-    repo. Tuned by
-    :func:`repro.core.dispatch.find_decode_fusion` from the
-    :func:`repro.core.dispatch.predict_fusion_time` roofline (per-layer
-    stage-dispatch count × pipeline fill vs. the scan's one-time loop
-    setup).
-    """
-
-    backend: str = "xla"
-    granularity: str = "split"
-
-    def __post_init__(self):
-        _check(self.backend, BACKENDS, "decode_fusion.backend")
-        _check(self.granularity, FUSION_MODES, "decode_fusion.granularity")
-
-
 # ---------------------------------------------------------------------------
 # Provenance
 # ---------------------------------------------------------------------------
@@ -385,8 +326,6 @@ class ExecutionPlan:
         default_factory=AttentionPrefillPlan)
     fused_ffn: FusedFFNPlan = dataclasses.field(default_factory=FusedFFNPlan)
     paged: PagedPlan = dataclasses.field(default_factory=PagedPlan)
-    decode_fusion: DecodeFusionPlan = dataclasses.field(
-        default_factory=DecodeFusionPlan)
     provenance: Optional[PlanProvenance] = None
 
     # -- bulk knob overrides -------------------------------------------------
@@ -422,9 +361,6 @@ class ExecutionPlan:
             fused_ffn=sub(self.fused_ffn, backend=backend, fused=fused),
             paged=sub(self.paged, backend=backend, scheme=scheme,
                       fallback=fallback),
-            # granularity survives a backend override: on XLA the fused
-            # stages dispatch their bit-identical jnp oracles
-            decode_fusion=sub(self.decode_fusion, backend=backend),
         )
 
     def describe(self) -> str:
@@ -447,8 +383,7 @@ class ExecutionPlan:
                 + f", swap>={self.paged.swap_threshold}"
                 + (f", kv={self.paged.kv_dtype}"
                    if self.paged.kv_dtype != "bf16" else "")
-                + "] "
-                f"fusion[{self.decode_fusion.granularity}]")
+                + "]")
 
     # -- serialization -------------------------------------------------------
 
@@ -471,7 +406,6 @@ class ExecutionPlan:
                     self.attention_prefill),
                 "fused_ffn": dataclasses.asdict(self.fused_ffn),
                 "paged": dataclasses.asdict(self.paged),
-                "decode_fusion": dataclasses.asdict(self.decode_fusion),
             },
         }
         if self.provenance is not None:
@@ -514,9 +448,6 @@ class ExecutionPlan:
                     **ops["attention_prefill"]),
                 fused_ffn=FusedFFNPlan(**ops["fused_ffn"]),
                 paged=PagedPlan(**ops["paged"]),
-                # pre-fusion plans load with the split default
-                decode_fusion=DecodeFusionPlan(
-                    **ops.get("decode_fusion", {})),
             )
         except (KeyError, TypeError, ValueError) as e:
             if isinstance(e, PlanError):
@@ -597,11 +528,10 @@ def make_plan(
     swap_threshold: int = 1,
     kv_dtype: str = "bf16",
     weight_dtype: str = "bf16",
-    decode_fusion: str = "split",
 ) -> ExecutionPlan:
     """Build an untuned plan with uniform knobs — the hand-rolled
     counterpart of :func:`tune` for hosts that only need to pin backends
-    or drop fallbacks (benchmarks, the dry-run, tests)."""
+    or drop fallbacks (the dry-run, tests)."""
     if fused_ffn is None:
         fused_ffn = backend == "pallas"
     return ExecutionPlan(
@@ -621,8 +551,6 @@ def make_plan(
                         group_threshold=group_threshold,
                         swap_threshold=swap_threshold,
                         kv_dtype=kv_dtype),
-        decode_fusion=DecodeFusionPlan(backend=backend,
-                                       granularity=decode_fusion),
     )
 
 
@@ -673,8 +601,8 @@ def tune(
     selects the GEMM weight storage precision (see :class:`MatmulPlan`);
     pass ``None`` to let :func:`repro.core.dispatch.find_weight_dtype`
     pick the fastest candidate whose dtype-derived guard tolerance the
-    run accepts — the resolved value rescales the weight-byte terms of
-    the swap and fusion rooflines via
+    run accepts — the resolved value rescales the weight-byte term of
+    the swap roofline via
     :func:`repro.core.dispatch.param_bytes`.
     """
     _check(backend, BACKENDS, "backend")
@@ -710,8 +638,6 @@ def tune(
     swap_threshold = dispatch.find_swap_threshold(
         cfg, chunk=chunk_block, page_size=page_size, spec=spec,
         kv_dtype=kv_dtype, weight_dtype=weight_dtype)
-    granularity = dispatch.find_decode_fusion(cfg, spec=spec,
-                                              weight_dtype=weight_dtype)
 
     plan = ExecutionPlan(
         matmul=MatmulPlan(backend=backend, default_m1=default.m1,
@@ -733,8 +659,6 @@ def tune(
                         group_threshold=group_threshold,
                         swap_threshold=swap_threshold,
                         kv_dtype=kv_dtype),
-        decode_fusion=DecodeFusionPlan(backend=backend,
-                                       granularity=granularity),
         provenance=PlanProvenance(
             backend=backend,
             hardware=hardware_hash(spec), hardware_name=spec.name,

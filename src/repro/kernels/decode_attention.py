@@ -166,7 +166,7 @@ def decode_attention_unified_max(
 # Synchronized (online-max) fallback kernel — the paper's recomputation path.
 # This is the FlashDecoding-style scheme of Fig. 4(b): every chunk updates the
 # running max and rescales the accumulator. Used (a) as the overflow fallback
-# and (b) as the "paper baseline" in benchmarks.
+# and (b) as the plan's ``sync`` scheme (the paper's baseline).
 # ---------------------------------------------------------------------------
 
 

@@ -1,4 +1,4 @@
-"""Fused decode-layer stage kernels (the DecodeFusionPlan seams).
+"""Fused decode-layer stage kernels (the decode seams of models/layers.py).
 
 Per-token decode runs a long chain of small memory-bound ops per layer;
 each op boundary pays a dispatch bubble and an HBM round-trip of its
@@ -27,8 +27,8 @@ to the 8-sublane atom and the K dimension streams (same discipline as
 ``kernels/flat_gemm.py`` / ``kernels/fused_ffn.py``). The K-streamed f32
 tile accumulation reassociates the dot relative to the single-dot
 oracles in ``ref.py``, so kernel-vs-oracle equality is dtype-eps bounded
-(like every other Pallas GEMM here), while the XLA fused path dispatches
-the oracles themselves and stays bit-identical to the split chain.
+(like every other Pallas GEMM here), while on XLA the seams dispatch
+the oracles themselves and stay bit-identical to the op chain.
 """
 from __future__ import annotations
 
@@ -84,7 +84,7 @@ def _ingest_kernel(x_ref, scale_ref, wq_ref, wk_ref, wv_ref,
     @pl.when(ki == 0)
     def _init():
         # rmsnorm once into the resident normed-x scratch (cast back to
-        # the activation dtype before the dot, like the split chain);
+        # the activation dtype before the dot, like the op chain);
         # zero K-pad columns keep the sum exact, the divisor is real D
         xf = x_ref[...].astype(jnp.float32)
         var = jnp.sum(xf * xf, axis=-1, keepdims=True) / d_real
@@ -110,7 +110,7 @@ def _ingest_kernel(x_ref, scale_ref, wq_ref, wk_ref, wv_ref,
     @pl.when(ki == n_k - 1)
     def _fin():
         # round the f32 accumulators to the activation dtype *before* the
-        # bias add and rope, mirroring the split chain's rounding points
+        # bias add and rope, mirroring the op chain's rounding points
         # (matmul output cast, bf16 bias add, rope promoting to f32);
         # weight steps dequantize on the f32 accumulators first
         accq, acck, accv = accq_ref[...], acck_ref[...], accv_ref[...]
@@ -290,7 +290,7 @@ def _oproj_kernel(o_ref, wo_ref, resid_ref, *refs,
 
     @pl.when(ki == n_k - 1)
     def _fin():
-        # cast before the add, mirroring the split chain's
+        # cast before the add, mirroring the op chain's
         # `x + matmul(o, wo)` operand dtypes; the weight step dequantizes
         # on the f32 accumulator first
         acc = acc_ref[...]

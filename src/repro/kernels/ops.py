@@ -169,14 +169,15 @@ def decode_ingest(
 ):
     """Fused decode-ingest stage: rmsnorm → QKV → bias → rope in one
     seam (kernels/decode_fuse.py on the Pallas backend, the bit-exact
-    split-chain composition in ``ref.py`` otherwise). Returns
-    q (B,1,HQ,Dh), k/v (B,1,HK,Dh)."""
-    fp = (plan or DEFAULT_PLAN).decode_fusion
+    op-chain composition in ``ref.py`` otherwise; a GEMM seam, so it
+    routes on the plan's matmul backend). Returns q (B,1,HQ,Dh),
+    k/v (B,1,HK,Dh)."""
+    mp = (plan or DEFAULT_PLAN).matmul
     interpret, spec = kernel_target()
     wq, wq_scale = _wparts(wq)
     wk, wk_scale = _wparts(wk)
     wv, wv_scale = _wparts(wv)
-    if fp.backend == "pallas":
+    if mp.backend == "pallas":
         from repro.kernels.decode_fuse import decode_ingest_fused
         b, s, d = x.shape
         q, k, v = decode_ingest_fused(
@@ -208,11 +209,11 @@ def oproj_residual(
 ) -> jax.Array:
     """Fused attention epilogue ``resid + o @ wo`` (the o_proj GEMM with
     the residual add riding its epilogue on the Pallas backend; the
-    bit-exact split composition otherwise)."""
-    fp = (plan or DEFAULT_PLAN).decode_fusion
+    bit-exact op-chain composition otherwise)."""
+    mp = (plan or DEFAULT_PLAN).matmul
     interpret, spec = kernel_target()
     wo, wo_scale = _wparts(wo)
-    if fp.backend == "pallas":
+    if mp.backend == "pallas":
         from repro.kernels.decode_fuse import oproj_residual_fused
         b, s, qd = o.shape
         out = oproj_residual_fused(
@@ -235,15 +236,14 @@ def ffn_norm(
 ) -> jax.Array:
     """Fused mlp-ingest stage: rmsnorm → gate/up GEMMs → act(g)*u in one
     seam (kernels/decode_fuse.py on the Pallas backend; on XLA the
-    oracle composes whichever split chain the plan's ``fused_ffn`` knob
-    selects, so the fused granularities stay bitwise). Returns (B, 1, F)
-    — feed to :func:`oproj_residual` with ``w_down`` for the full seam."""
+    oracle composes whichever op chain the plan's ``fused_ffn`` knob
+    selects, so the seam stays bitwise). Returns (B, 1, F) — feed to
+    :func:`oproj_residual` with ``w_down`` for the full seam."""
     p = plan or DEFAULT_PLAN
-    fp = p.decode_fusion
     interpret, spec = kernel_target()
     w_gate, wg_scale = _wparts(w_gate)
     w_up, wu_scale = _wparts(w_up)
-    if fp.backend == "pallas":
+    if p.matmul.backend == "pallas":
         from repro.kernels.decode_fuse import ffn_norm_fused
         b, s, d = x.shape
         out = ffn_norm_fused(

@@ -620,7 +620,7 @@ def fused_ffn_up_ref(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
 
 
 # ---------------------------------------------------------------------------
-# Decode-fusion stage oracles (kernels/decode_fuse.py)
+# Decode seam oracles (kernels/decode_fuse.py)
 # ---------------------------------------------------------------------------
 
 
@@ -628,8 +628,7 @@ def rmsnorm_ref(x: jax.Array, scale: jax.Array,
                 eps: float) -> jax.Array:
     """Expression-for-expression copy of ``models.layers.rmsnorm`` (the
     kernels layer cannot import models); the fused-ingest oracle composes
-    it so the XLA ``fused``/``looped`` granularities stay bit-identical
-    to the split chain."""
+    it so the seams stay bit-identical to the op chain on XLA."""
     xf = x.astype(jnp.float32)
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
     out = xf * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)
@@ -673,10 +672,10 @@ def decode_ingest_ref(
     wv_scale: jax.Array | None = None,
 ):
     """Oracle for the fused decode-ingest stage: rmsnorm → QKV → bias →
-    rope in one seam. Composes exactly the split chain's expressions in
+    rope in one seam. Composes exactly the op chain's expressions in
     the same order (norm, three f32-accumulated GEMMs, bias add, head
-    reshape, rope on q/k), so on the XLA backend the fused granularities
-    are bitwise equal to split. Returns q (B,1,HQ,Dh), k/v (B,1,HK,Dh).
+    reshape, rope on q/k), so on the XLA backend the seam is bitwise
+    equal to the op chain. Returns q (B,1,HQ,Dh), k/v (B,1,HK,Dh).
     Weight steps (``w*_scale``) dequantize on the f32 accumulators before
     the bias add, matching the kernel epilogue order.
     """
@@ -701,7 +700,7 @@ def decode_ingest_ref(
 def oproj_residual_ref(o: jax.Array, wo: jax.Array, resid: jax.Array,
                        *, w_scale: jax.Array | None = None) -> jax.Array:
     """Oracle for the fused attention epilogue: ``resid + o @ wo`` — the
-    split chain's o_proj GEMM and residual add, same f32 accumulation and
+    op chain's o_proj GEMM and residual add, same f32 accumulation and
     operand order. o: (B, 1, HQ*Dh); wo: (HQ*Dh, D); resid: (B, 1, D).
     ``w_scale`` dequantizes on the f32 accumulator before the residual
     add (kernel epilogue order)."""
@@ -725,13 +724,13 @@ def ffn_norm_ref(
     wu_scale: jax.Array | None = None,
 ) -> jax.Array:
     """Oracle for the fused mlp-ingest stage: rmsnorm → gate/up GEMMs →
-    act(g)*u. ``fused`` selects which split composition to mirror —
-    the plan's ``fused_ffn`` knob decides whether the split chain runs
+    act(g)*u. ``fused`` selects which op-chain composition to mirror —
+    the plan's ``fused_ffn`` knob decides whether the op chain runs
     ``fused_ffn_up_ref`` (f32 epilogue) or two dispatched GEMMs rounded
     to the activation dtype before the activation; the fused seam must
     compose the *same* expressions — with the same reshape placement,
-    since the split/looped scan bodies must trace to identical jaxprs
-    for XLA to round identically — to stay bitwise."""
+    since the seam must trace to the op chain's jaxpr for XLA to round
+    identically — to stay bitwise."""
     b, s, d = x.shape
     f = w_gate.shape[-1]
     h = rmsnorm_ref(x, norm_scale, eps)

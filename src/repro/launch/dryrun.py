@@ -13,8 +13,8 @@ Per cell this produces:
     per-collective bytes decompose linearly in L (layers are identical),
     giving exact full-depth roofline terms (see analysis/roofline.py).
 
-Artifacts are JSON files under ``--out`` consumed by
-benchmarks/roofline_report.py and EXPERIMENTS.md.
+Artifacts are JSON files under ``--out``, read by
+:mod:`repro.analysis.roofline`.
 """
 import os
 
@@ -402,12 +402,6 @@ def main() -> int:
                     help="tune a fresh plan per arch before lowering "
                          "(analytical backend; backend/fallback are still "
                          "forced to xla/off for cost-analysis hygiene)")
-    ap.add_argument("--decode-fusion",
-                    choices=["split", "fused", "looped"], default=None,
-                    help="override the plan's decode-layer stage "
-                         "granularity for the decode cells (the xla "
-                         "backend override keeps the granularity; fused "
-                         "stages dispatch their jnp oracles)")
     ap.add_argument("--weight-dtype",
                     choices=["bf16", "int8", "fp8"], default=None,
                     help="override the plan's GEMM weight storage dtype "
@@ -431,8 +425,7 @@ def main() -> int:
     plans: dict[str, plan_mod.ExecutionPlan] = {}
 
     def plan_for(arch: str) -> Optional[plan_mod.ExecutionPlan]:
-        if not (args.tune or args.plan or args.decode_fusion
-                or args.weight_dtype):
+        if not (args.tune or args.plan or args.weight_dtype):
             return None
         if arch not in plans:
             cfg = configs.get(arch)
@@ -445,11 +438,6 @@ def main() -> int:
                 base = plan_mod.ExecutionPlan.load(args.plan, cfg=cfg)
             else:
                 base = plan_mod.make_plan()
-            if args.decode_fusion is not None:
-                base = dataclasses.replace(
-                    base, decode_fusion=dataclasses.replace(
-                        base.decode_fusion,
-                        granularity=args.decode_fusion))
             if args.weight_dtype is not None:
                 base = dataclasses.replace(
                     base, matmul=dataclasses.replace(

@@ -53,12 +53,6 @@ tiny M the weight stream dominates the tick, so int8 halves it (the
 summary's ``weights=`` segment reports stored bytes per tick and total
 decode-read weight bytes against a ``bf16`` run); accuracy is held to
 the same dtype-derived logits guard as ``--kv-dtype``.
-
-``--decode-fusion split|fused|looped`` overrides the plan's decode-layer
-stage granularity (``DecodeFusionPlan``): ``fused`` collapses
-norm→QKV→rope and o_proj→residual into the fused stage kernels,
-``looped`` additionally runs the whole depth under one ``lax.scan``. The
-summary line reports the effective granularity as ``fusion=...``.
 """
 import argparse
 import sys
@@ -134,13 +128,6 @@ def _parse():
                          "f32 scales and dequantized on the kernels' f32 "
                          "accumulators; default: the plan's "
                          "matmul.weight_dtype")
-    ap.add_argument("--decode-fusion", choices=["split", "fused", "looped"],
-                    default=None,
-                    help="decode-layer stage granularity: split = the "
-                         "per-op chain, fused = fused ingest/epilogue "
-                         "stage kernels per layer, looped = fused stages "
-                         "under one depth scan; default: the plan's "
-                         "decode_fusion.granularity")
     ap.add_argument("--rounds", type=int, default=1,
                     help="resubmit every prompt this many times — round "
                          ">= 2 models returning conversations hitting the "
@@ -218,7 +205,6 @@ def main() -> int:
                  session_cache=args.session_cache or None,
                  kv_dtype=args.kv_dtype,
                  weight_dtype=args.weight_dtype,
-                 decode_fusion=args.decode_fusion,
                  seed=args.seed)
     rng = np.random.default_rng(args.seed)
     sp = SamplingParams(max_new_tokens=args.max_new,
@@ -242,7 +228,6 @@ def main() -> int:
     line = (f"served {len(out)} requests, {total_tokens} tokens in {dt:.2f}s "
             f"({total_tokens / dt:.1f} tok/s, {eng.ticks} decode ticks, "
             f"{eng.scheduler.name} scheduler, "
-            f"fusion={eng.decode_fusion}, "
             f"weights={eng.weight_dtype} "
             f"({eng._weight_bytes_per_tick} B/tick, "
             f"{eng.stats.weight_bytes_decode_read} decode weight bytes "

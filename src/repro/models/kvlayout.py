@@ -31,8 +31,8 @@ from typing import Tuple, Union
 
 def pages_for(positions: int, page_size: int) -> int:
     """Pages needed to store ``positions`` KV entries — the one definition
-    of the page ceil-div, shared by the allocator, the engine's pool
-    sizing, and the benchmarks."""
+    of the page ceil-div, shared by the allocator and the engine's pool
+    sizing."""
     return -(-max(positions, 0) // page_size)
 
 
@@ -40,8 +40,7 @@ def pow2_bucket(n: int, lo: int = 1, hi: Union[int, None] = None) -> int:
     """Round ``n`` up to a power-of-two bucket (floor ``lo``, capped at
     ``hi``) — the one rounding that keeps jit shape families logarithmic
     (the engine's resident-bounded block tables and batched-prefill
-    padding) and lets the benchmarks mirror the engine's bucketing
-    exactly."""
+    padding)."""
     b = lo
     while b < n:
         b *= 2

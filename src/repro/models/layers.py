@@ -207,20 +207,20 @@ def attention_block(
 
 # --- decode-layer stage boundaries -----------------------------------------
 #
-# One decode layer is four explicit stages a fusion backend can claim
-# (DecodeFusionPlan.granularity, see repro.core.plan):
+# One decode layer is four explicit stages:
 #
 #   A. ingest   — norm → QKV → bias → rope          (decode_ingest)
 #   B. attend   — KV scatter → decode attention      (decode_attend[_paged])
 #   C. epilogue — o_proj → residual add              (decode_epilogue)
 #   D. mlp      — norm → gate/up → act → down → res  (decode_mlp)
 #
-# `split` composes each stage from today's op chain; `fused`/`looped`
-# dispatch the A, C and D seams through ops.decode_ingest /
+# The A, C and D seams dispatch through ops.decode_ingest /
 # ops.oproj_residual / ops.ffn_norm (one kernel per seam on the Pallas
-# backend, the bit-identical oracle composition on XLA). Stage B keeps
-# its own plan-governed dispatch (attention scheme/paging/quantization
-# are orthogonal axes).
+# backend, the bit-identical op-chain composition in ref.py on XLA);
+# families the A and D seams do not cover (layernorm, non-glu MLPs)
+# compose those stages from the op chain. Stage B keeps its own
+# plan-governed dispatch (attention scheme/paging/quantization are
+# orthogonal axes).
 
 
 def decode_ingest(
@@ -230,12 +230,11 @@ def decode_ingest(
     """Stage A: pre-attention ingest on the residual stream.
 
     x: (B, 1, D) un-normed; position: (B,). Returns q (B,1,HQ,Dh),
-    k/v (B,1,HK,Dh). The fused seam claims rmsnorm models only —
-    layernorm families keep the split composition (documented fallback).
+    k/v (B,1,HK,Dh). The seam claims rmsnorm models only — layernorm
+    families keep the op chain (documented fallback).
     """
     cfg = ctx.cfg
-    if (ctx.plan.decode_fusion.granularity != "split"
-            and cfg.norm == "rmsnorm"):
+    if cfg.norm == "rmsnorm":
         q, k, v = ops.decode_ingest(
             x, norm_p["scale"], p["wq"], p["wk"], p["wv"], position,
             num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
@@ -285,10 +284,8 @@ def decode_attend(
 
 def decode_epilogue(ctx: LayerCtx, p: Params, o: jax.Array,
                     resid: jax.Array) -> jax.Array:
-    """Stage C: ``resid + o @ wo`` — one fused dispatch when claimed."""
-    if ctx.plan.decode_fusion.granularity != "split":
-        return ops.oproj_residual(o, p["wo"], resid, plan=ctx.plan)
-    return resid + ctx.matmul(o, p["wo"])
+    """Stage C: ``resid + o @ wo`` — one fused dispatch."""
+    return ops.oproj_residual(o, p["wo"], resid, plan=ctx.plan)
 
 
 def decode_mlp(ctx: LayerCtx, norm_p: Params, p: Params,
@@ -296,16 +293,14 @@ def decode_mlp(ctx: LayerCtx, norm_p: Params, p: Params,
     """Stage D: the full MLP half — mlp_norm → gate/up → activation →
     down-projection → residual add.
 
-    When claimed, two fused dispatches: ``ops.ffn_norm`` (norm pulled
-    inside the gate/up pair) and ``ops.oproj_residual`` reused for
+    Two fused dispatches: ``ops.ffn_norm`` (norm pulled inside the
+    gate/up pair) and ``ops.oproj_residual`` reused for
     ``x + h @ w_down`` (the same GEMM-into-residual shape as stage C).
-    The seam claims rmsnorm + glu families only — others keep the split
-    composition (same documented fallback as stage A).
+    The seam claims rmsnorm + glu families only — others keep the op
+    chain (same documented fallback as stage A).
     """
     cfg = ctx.cfg
-    if (ctx.plan.decode_fusion.granularity != "split"
-            and cfg.norm == "rmsnorm"
-            and cfg.activation in ("swiglu", "geglu")):
+    if cfg.norm == "rmsnorm" and cfg.activation in ("swiglu", "geglu"):
         h = ops.ffn_norm(x, norm_p["scale"], p["w_gate"], p["w_up"],
                          activation=cfg.activation,
                          eps=cfg.rms_norm_eps, plan=ctx.plan)

@@ -23,9 +23,6 @@ per-expert [K, N] entries for them.
 """
 from __future__ import annotations
 
-import functools
-from typing import Optional
-
 import jax
 import jax.numpy as jnp
 
@@ -310,7 +307,7 @@ def make_decode_block(groups: int = 0):
         # same ingest → attend → epilogue stage boundaries as the dense
         # family (repro.models.layers); only the FFN half differs — the
         # routed expert dispatch is not a fusable seam, so the MoE block
-        # shares the attention-side fused stages and keeps its own tail
+        # shares the attention-side seams and keeps its own tail
         cfg = ctx.cfg
         q, k, v = L.decode_ingest(ctx, p["attn_norm"], p["attn"], x,
                                   position)
@@ -430,7 +427,7 @@ def prefill(ctx: LayerCtx, params: Params, tokens, lengths, cache, *,
 
 def decode_step(ctx: LayerCtx, params: Params, tokens, cache, lengths, *,
                 block_tables=None, decode_groups=None, positions=None,
-                unroll=None, groups: int = 0):
+                unroll: bool = False, groups: int = 0):
     return tfm.decode_step(
         ctx, params, tokens, cache, lengths, block_tables=block_tables,
         decode_groups=decode_groups, positions=positions, unroll=unroll,

@@ -18,8 +18,8 @@ Everything here is generic ``jax.tree`` plumbing, which is what lets the
 quantized-weight representation ride through untouched: a GEMM leaf that
 ``models/wquant.py`` turned into a ``{"codes", "scale"}`` dict is just
 two stacked leaves ``(L, K, N)`` / ``(L, N)`` to stack/unstack/scan, so
-the looped decode granularity traces the identical scan-body jaxpr
-whether the params are bf16 arrays or (codes, scale) pairs.
+the decode scan body has the same structure whether the params are bf16
+arrays or (codes, scale) pairs.
 """
 from __future__ import annotations
 
@@ -37,8 +37,8 @@ def unstack(tree):
     """Stacked-L pytree -> list of L per-layer pytrees.
 
     ``a[i]`` slicing only — no copy under jit, bitwise round-trip with
-    :func:`restack` (the decode-fusion unrolled path relies on this:
-    per-layer slabs must hold exactly the scanned values).
+    :func:`restack` (the unrolled cost probes rely on this: per-layer
+    slabs must hold exactly the scanned values).
     """
     return [jax.tree.map(lambda a: a[i], tree)
             for i in range(num_layers_of(tree))]

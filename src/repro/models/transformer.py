@@ -82,10 +82,9 @@ def decode_block(ctx: LayerCtx, p: Params, x: jax.Array, position: jax.Array,
     ``decode_groups`` (paged only) switches to the prefix-shared grouped
     attention path.
 
-    The layer runs as three explicit stage boundaries (ingest → attend →
-    epilogue, see :mod:`repro.models.layers`); the plan's
-    ``decode_fusion`` granularity decides whether the ingest and
-    epilogue seams are fused dispatches or the split op chain.
+    The layer runs as explicit stage boundaries (ingest → attend →
+    epilogue → mlp, see :mod:`repro.models.layers`); the ingest,
+    epilogue and mlp seams are one dispatch each.
     """
     q, k, v = L.decode_ingest(ctx, p["attn_norm"], p["attn"], x, position)
     if block_tables is None:
@@ -288,7 +287,7 @@ def decode_step(
     ctx: LayerCtx, params: Params, tokens: jax.Array, cache: dict,
     lengths: jax.Array, *, block_tables: Optional[jax.Array] = None,
     decode_groups=None, positions: Optional[jax.Array] = None,
-    unroll: Optional[bool] = None,
+    unroll: bool = False,
     decode_block_fn: Callable = decode_block,
 ):
     """One decode step. tokens: (B,) -> logits (B, V_padded), new cache.
@@ -303,18 +302,14 @@ def decode_step(
     on the paged layout.
 
     ``positions`` is the per-row absolute position operand (defaults to
-    ``lengths``; the engine passes its device-cached copy). ``unroll=None``
-    lets the plan's ``decode_fusion`` granularity pick the depth-loop
-    strategy: ``fused`` python-unrolls into L traced layer bodies;
-    ``split``/``looped`` run the stacked depth under one ``lax.scan``
-    (an explicit bool overrides the plan). Scan and unroll apply the same
-    per-layer math to the same leading-axis slabs, so the choice never
-    changes outputs — bit-identity across granularities is tier-1
-    enforced.
+    ``lengths``; the engine passes its device-cached copy). The stacked
+    depth runs under one ``lax.scan``; ``unroll=True`` python-unrolls it
+    into L traced layer bodies for the dry-run's cost probes. Both apply
+    the same per-layer math to the same leading-axis slabs; unrolling
+    lets XLA place bf16 roundings at other fusion boundaries, so the two
+    agree to within dtype eps, not bitwise.
     """
     cfg = ctx.cfg
-    if unroll is None:
-        unroll = ctx.plan.decode_fusion.granularity == "fused"
     x = L.embed(ctx, params, tokens[:, None])  # (B, 1, D)
     position = lengths if positions is None else positions
 

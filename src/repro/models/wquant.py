@@ -19,8 +19,8 @@ expression everywhere:
   * a quantized leaf is the dict ``{"codes": (…, K, N) code_dtype,
     "scale": (…, N) f32}`` — a plain pytree node, so the stacked-L
     layer params stack/slice/scan through :mod:`repro.models.stack`'s
-    generic ``tree.map`` plumbing unchanged, and the looped decode
-    granularity keeps tracing the identical scan-body jaxpr.
+    generic ``tree.map`` plumbing unchanged, and the decode scan body
+    keeps its structure.
   * only the GEMM weight leaves named in :data:`WEIGHT_KEYS` quantize;
     bias, norm, embedding and lm-head leaves stay full precision (they
     are tiny or accuracy-critical — the ``kv_dtype`` design's scale-row

@@ -106,7 +106,7 @@ import numpy as np
 
 from repro.models.kvlayout import pages_for, pow2_bucket  # noqa: F401
 # (pages_for re-export: the one page ceil-div definition, shared with
-# layouts/engine/benchmarks)
+# layouts/engine)
 from repro.serving.kvcache import Slot, SlotManager
 from repro.serving.prefix import PrefixIndex, shared_prefix_groups
 from repro.serving.tiers import TieredPool

@@ -95,8 +95,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.config import ModelConfig
-from repro.core.plan import (DEFAULT_PLAN, FUSION_MODES, KV_DTYPES,
-                             WEIGHT_DTYPES, ExecutionPlan)
+from repro.core.plan import (DEFAULT_PLAN, KV_DTYPES, WEIGHT_DTYPES,
+                             ExecutionPlan)
 from repro.kernels import quant
 from repro.models import wquant
 from repro.models.api import get_model
@@ -234,7 +234,6 @@ class Engine:
         plan: Optional[ExecutionPlan] = None,
         kv_dtype: Optional[str] = None,
         weight_dtype: Optional[str] = None,
-        decode_fusion: Optional[str] = None,
         prefix_sharing: bool = False,
         host_pages: Optional[int] = None,
         session_cache: Optional[bool] = None,
@@ -245,22 +244,9 @@ class Engine:
         self.cfg = cfg
         self.api = get_model(cfg)
         self.plan = plan if plan is not None else DEFAULT_PLAN
-        # decode-stage fusion granularity: explicit arg wins, else the
-        # plan's tuned knob rides along untouched (same precedence as
-        # kv_dtype). The override lands *in the plan* because the model
-        # stages read ctx.plan.decode_fusion at trace time.
-        if decode_fusion is not None:
-            if decode_fusion not in FUSION_MODES:
-                raise ValueError(
-                    f"decode_fusion {decode_fusion!r} not in {FUSION_MODES}")
-            self.plan = dataclasses.replace(
-                self.plan,
-                decode_fusion=dataclasses.replace(
-                    self.plan.decode_fusion, granularity=decode_fusion))
-        self.decode_fusion = self.plan.decode_fusion.granularity
         # GEMM weight storage precision: explicit arg wins, else the
         # plan's tuned matmul.weight_dtype rides along (same precedence
-        # as kv_dtype/decode_fusion). The resolved value lands in the
+        # as kv_dtype). The resolved value lands in the
         # plan before LayerCtx so describe()/downstream readers agree;
         # the kernels themselves key off the (codes, scale) leaf
         # structure, not the knob.
@@ -1097,8 +1083,8 @@ class Engine:
         bulk gather), returning how many device pages were freed. The
         demand-driven path (:meth:`_reclaim_session`) makes this
         unnecessary in steady state; it exists for checkpoints and for
-        benchmarks that want host-resident sessions without first
-        running the pool dry."""
+        tests that want host-resident sessions without first running the
+        pool dry."""
         if self.tiers is None or self.slots.session_pages() == 0:
             return 0
         before = dataclasses.replace(self.tiers.stats)

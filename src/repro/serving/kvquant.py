@@ -119,7 +119,7 @@ def scatter_chunk_quantized(codes, steps, new, block_tables, lengths,
 
 
 # ---------------------------------------------------------------------------
-# Whole-page helpers (tests, benchmarks, oracles)
+# Whole-page helpers (tests, oracles)
 # ---------------------------------------------------------------------------
 
 

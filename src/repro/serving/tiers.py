@@ -51,7 +51,7 @@ from typing import Optional
 
 @dataclasses.dataclass
 class TierStats:
-    """Counters for the engine summary / benchmarks."""
+    """Counters for the engine summary and stats."""
 
     demoted: int = 0          # pages accepted into the hierarchy (tier >= 1)
     promoted: int = 0         # pages popped back toward tier 0

@@ -3,6 +3,8 @@ hypothesis shim), lazy growth, block-table consistency, and paged-vs-dense
 engine equivalence through the unified KVLayout path — greedy outputs must
 be token-identical, including runs where overcommit forces preemption and
 re-admission recycles pages."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -409,22 +411,30 @@ def _random_cache(api, layout, rng):
     return jax.tree.map(fill, api.cache_spec(layout))
 
 
+# the two cells' attention shapes at smoke size: qwen's GQA (4/2 heads of
+# 64) and phi3's MHA with head_dim 96
+SMOKE_MODELS = {
+    "qwen": configs.smoke(configs.get("qwen2-0.5b")),
+    "phi3": dataclasses.replace(configs.smoke(configs.get("phi3-mini-3.8b")),
+                                num_heads=2, num_kv_heads=2, head_dim=96),
+}
+
+
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
-@pytest.mark.parametrize("granularity", ["looped", "fused"])
+@pytest.mark.parametrize("model", sorted(SMOKE_MODELS))
 @pytest.mark.parametrize("step", ["decode", "grouped", "chunk"])
-def test_carried_pool_matches_slab_reference(monkeypatch, step, granularity,
-                                             kv):
+def test_carried_pool_matches_slab_reference(monkeypatch, step, model, kv):
     """The paged decode, grouped-decode and chunk steps write into the
     whole stacked pool in place; pool contents and logits must equal the
     per-layer slab reference bit for bit. Row 1 is an empty slot whose
     table is all sentinel: it writes nothing, and layer 0's sentinel must
     not land on layer 1's page 0 (held by rows 0 and 2). int8 pools bring
     their scale pools along; the Pallas kernels run in interpret mode."""
-    cfg = configs.smoke(configs.get("qwen2-0.5b"))
+    cfg = SMOKE_MODELS[model]
     api = get_model(cfg)
     params = api.init_params(jax.random.PRNGKey(0))
     plan = make_plan("pallas", gather_chunk="fused", decode_group="grouped",
-                     kv_dtype=kv, decode_fusion=granularity)
+                     kv_dtype=kv)
     ctx = LayerCtx(cfg=cfg, plan=plan)
     num_pages, ps = 8, 16
     layout = PagedLayout(num_pages, ps, kv)
@@ -444,8 +454,7 @@ def test_carried_pool_matches_slab_reference(monkeypatch, step, granularity,
 
         def run(c):
             return api.prefill_chunk(ctx, params, tokens, chunk_lens, c,
-                                     lengths, block_tables=bt,
-                                     unroll=granularity == "fused")
+                                     lengths, block_tables=bt)
     else:
         lengths = jnp.asarray([ps + 4, 0, 2 * ps + 8], jnp.int32)
         tokens = jnp.asarray([3, 0, 7], jnp.int32)

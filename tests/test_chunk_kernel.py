@@ -349,25 +349,31 @@ def test_prefill_buckets_are_logarithmic(smoke_model):
     assert set(eng._prefill_cache) == {128}
 
 
-def test_chunk_bench_smoke(tmp_path, monkeypatch):
-    """benchmarks.chunk_prefill --quick asserts cross-mode identity and
-    emits a well-formed BENCH_chunk.json with the fused mode ahead."""
-    from benchmarks import chunk_prefill
-    monkeypatch.setattr(chunk_prefill, "OUT_PATH",
-                        str(tmp_path / "BENCH_chunk.json"))
-    result = chunk_prefill.run(quick=True)
-    assert (tmp_path / "BENCH_chunk.quick.json").exists()
-    assert not (tmp_path / "BENCH_chunk.json").exists()
-    assert result["rows"]
-    by_mode = {}
-    for row in result["rows"]:
-        assert {"prompt_len", "batch", "mode", "ttft_s",
-                "kv_bytes_materialized_per_chunk",
-                "bit_identical"} <= set(row)
-        assert row["bit_identical"]
-        by_mode.setdefault((row["prompt_len"], row["batch"]), {})[
-            row["mode"]] = row
-    for cell in by_mode.values():
-        g, f = cell["gather"], cell["fused"]
-        assert (f["kv_bytes_materialized_per_chunk"] * 2
-                <= g["kv_bytes_materialized_per_chunk"])
+def test_engine_identity_gather_fused_at_page_64(smoke_model):
+    """At the cells' page and chunk size (64), prompts of two and four
+    chunks decode to the same greedy tokens through the dense slot cache,
+    the paged dense-gather mode and the fused mode — also in a second
+    wave on the same engine, over pages the first wave freed."""
+    cfg, params = smoke_model
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(1, cfg.vocab_size, size=n).astype(np.int32)
+               for n in (128, 256)]
+    kw = dict(num_slots=2, max_seq=512, prefill_chunk=64)
+    engines = {
+        "dense": Engine(cfg, params, cache_kind="dense", **kw),
+        "gather": Engine(cfg, params, cache_kind="paged", page_size=64,
+                         plan=make_plan(gather_chunk="dense"), **kw),
+        "fused": Engine(cfg, params, cache_kind="paged", page_size=64,
+                        plan=make_plan(gather_chunk="fused",
+                                       fused_threshold=64), **kw),
+    }
+    waves = {}
+    for name, eng in engines.items():
+        waves[name] = []
+        for _ in range(2):
+            out = eng.run([(p, SamplingParams(max_new_tokens=2))
+                           for p in prompts])
+            waves[name].append([out[k] for k in sorted(out)])
+            eng.evict_finished()
+        assert waves[name][0] == waves[name][1], name
+    assert waves["gather"] == waves["dense"] == waves["fused"]

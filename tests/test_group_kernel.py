@@ -423,19 +423,29 @@ def test_engine_grouped_survives_preemption(smoke_model):
     assert grouped.pool.used_pages == 0
 
 
-def test_group_bench_smoke(tmp_path, monkeypatch):
-    """benchmarks.group_decode --quick asserts grouped/ungrouped identity
-    and emits BENCH_group.json with ~Nx prefix-read dedup per N-way cell."""
-    from benchmarks import group_decode
-    monkeypatch.setattr(group_decode, "OUT_PATH",
-                        str(tmp_path / "BENCH_group.json"))
-    result = group_decode.run(quick=True)
-    assert (tmp_path / "BENCH_group.quick.json").exists()
-    assert not (tmp_path / "BENCH_group.json").exists()
-    assert result["rows"]
-    for row in result["rows"]:
-        assert {"group_n", "prefix_len", "decode_tick_s_off",
-                "decode_tick_s_on", "prefix_kv_read_off",
-                "prefix_kv_read_on", "dedup_x", "bit_identical"} <= set(row)
-        assert row["bit_identical"]
-        assert row["dedup_x"] == pytest.approx(row["group_n"])
+def test_engine_grouped_dedup_equals_group_size(smoke_model):
+    """N requests decoding behind one shared page-aligned prefix read its
+    KV once per grouped tick instead of N times: the engine's saved bytes
+    per grouped tick are (N - 1) x prefix pages x page bytes, and greedy
+    tokens equal the ungrouped run's."""
+    cfg, params = smoke_model
+    rng = np.random.default_rng(0)
+    header = rng.integers(1, cfg.vocab_size, size=32).astype(np.int32)
+    for n in (2, 3):
+        prompts = [np.concatenate([header, rng.integers(
+            1, cfg.vocab_size, size=8).astype(np.int32)]) for _ in range(n)]
+        outs, engines = {}, {}
+        for name, plan in (("off", None), ("grouped", GROUPED)):
+            eng = Engine(cfg, params, cache_kind="paged", num_slots=n,
+                         max_seq=128, page_size=16, prefill_chunk=16,
+                         prefix_sharing=True, plan=plan)
+            outs[name] = eng.run([(p, SamplingParams(max_new_tokens=12))
+                                  for p in prompts])
+            engines[name] = eng
+        assert outs["grouped"] == outs["off"]
+        st = engines["grouped"].stats
+        assert st.grouped_requests > 0 and st.grouped_requests % n == 0
+        ticks = st.grouped_requests // n
+        page_bytes = engines["grouped"]._kv_bytes_per_page
+        assert st.prefix_kv_bytes_saved == ticks * (n - 1) * 2 * page_bytes
+        assert engines["off"].stats.prefix_kv_bytes_saved == 0

@@ -13,7 +13,7 @@ from repro import configs, hardware
 from repro.core import dispatch as dsp
 from repro.core import plan as plan_mod
 from repro.core.plan import (
-    DEFAULT_PLAN, ExecutionPlan, PlanError, StalePlanError, make_plan, tune,
+    ExecutionPlan, PlanError, StalePlanError, make_plan, tune,
 )
 
 settings.register_profile("fast", max_examples=20, deadline=None)
@@ -232,25 +232,20 @@ def test_scheme_swap_decode_logits_value_close(smoke_model, cache_kind):
 
 
 # ---------------------------------------------------------------------------
-# Bench artifact smoke (fast lane)
+# Tuned GEMM inflections
 # ---------------------------------------------------------------------------
 
 
-def test_dispatch_bench_smoke(tmp_path, monkeypatch):
-    """benchmarks.dispatch_table --quick tunes, round-trips, and emits a
-    well-formed BENCH_dispatch.json."""
-    from benchmarks import dispatch_table
-    monkeypatch.setattr(dispatch_table, "OUT_PATH",
-                        str(tmp_path / "BENCH_dispatch.json"))
-    result = dispatch_table.run(quick=True)
-    # quick mode lands in the .quick.json sidecar and never clobbers the
-    # committed full-mode artifact
-    assert (tmp_path / "BENCH_dispatch.quick.json").exists()
-    assert not (tmp_path / "BENCH_dispatch.json").exists()
-    assert result["mode"] == "quick"
-    assert result["config"]["measure"] == "analytical"
-    assert result["rows"], "inflection rows must be emitted"
-    for row in result["rows"]:
-        assert {"arch", "name", "k", "n", "m1", "m2"} <= set(row)
-        assert row["m1"] <= row["m2"]
-    assert "llama2-7b" in result["plans"]
+def test_tuned_inflections_ordered_and_round_trip():
+    """For a dense, a GQA, an MoE and a recurrent architecture, the tuned
+    plan holds an [K, N] entry for every GEMM shape of the model with its
+    ImplA->ImplB inflection at or below its ImplB->ImplC one, and
+    serializes to an identical plan."""
+    for arch in ("llama2-7b", "qwen2-0.5b", "dbrx-132b", "rwkv6-1.6b"):
+        cfg = configs.get(arch)
+        plan = tune(cfg)
+        assert ExecutionPlan.from_json(plan.to_json()) == plan
+        assert plan.matmul.default_m1 <= plan.matmul.default_m2
+        for gs in dsp.model_gemm_shapes(cfg):
+            e = plan.matmul.entries[(gs.k, gs.n)]
+            assert 1 <= e.m1 <= e.m2, (arch, gs.name, e)

@@ -510,23 +510,29 @@ def test_victim_signal_tracks_live_refcounts(smoke_model):
     assert b.exclusive_len == b.total_len     # eviction reclaims it all
 
 
-def test_prefix_bench_smoke(tmp_path, monkeypatch):
-    """CI wiring: the prefix-sharing sweep runs at smoke sizes, emits a
-    well-formed BENCH_prefix.json, and shows the collapse the refcounts
-    are for: pages_on < pages_off once a batch shares a prefix."""
-    from benchmarks import prefix_sharing
-    monkeypatch.setattr(prefix_sharing, "OUT_PATH",
-                        str(tmp_path / "BENCH_prefix.json"))
-    result = prefix_sharing.run(quick=True)
-    assert (tmp_path / "BENCH_prefix.quick.json").exists()
-    assert not (tmp_path / "BENCH_prefix.json").exists()
-    assert result["rows"], "sweep cells must be emitted"
-    for row in result["rows"]:
-        assert {"prefix_len", "batch", "pages_off", "pages_on",
-                "saved_prefill_tokens", "capacity_on"} <= set(row)
-        assert row["pages_on"] < row["pages_off"]
-        assert row["saved_prefill_tokens"] > 0
-        assert row["capacity_on"] >= row["capacity_off"]
+def test_page_aligned_prefix_collapses_pages(smoke_model):
+    """A page-aligned shared prefix (two full pages) is held once however
+    many requests share it: with sharing the peak page count drops by
+    exactly the followers' copies of those pages, the followers skip
+    their prefill, and greedy tokens equal the unshared run's."""
+    cfg, params = smoke_model
+    rng = np.random.default_rng(0)
+    header = rng.integers(1, cfg.vocab_size, size=32).astype(np.int32)
+    for n in (2, 4):
+        prompts = [np.concatenate([header, rng.integers(
+            1, cfg.vocab_size, size=8).astype(np.int32)]) for _ in range(n)]
+
+        def reqs():
+            return [(p, SamplingParams(max_new_tokens=4)) for p in prompts]
+
+        on = _engine(cfg, params, sharing=True, num_slots=n)
+        off = _engine(cfg, params, sharing=False, num_slots=n)
+        assert on.run(reqs()) == off.run(reqs())
+        assert on.stats.shared_prefix_pages == 2 * (n - 1)
+        assert on.stats.saved_prefill_tokens == 32 * (n - 1)
+        assert (off.stats.peak_pages_used - on.stats.peak_pages_used
+                == 2 * (n - 1))
+        on.slots.check()
 
 
 def test_prefix_sharing_rejects_bad_configs(smoke_model):

@@ -4,10 +4,11 @@ Phi-3-mini publishes ``rms_norm_eps`` 1e-5, Qwen2 1e-6. The program's
 norms (the XLA blocks, ``ops.decode_ingest``/``ops.ffn_norm`` and the
 two ``decode_fuse`` Pallas kernels) read ``ModelConfig.rms_norm_eps``.
 Here the plain float32 reference (``bench/reference/dense.py``) judges
-the served path at a phi3-shaped small size: chunked prefill into a page
-pool, then decode through the cache, teacher-forced on the reference's
-own greedy tokens, under each decode-fusion granularity. The same
-program with Qwen's epsilon must fall outside the tolerance.
+the served path at a phi3-shaped small size: chunked prefill into the
+cache, then decode through it, teacher-forced on the reference's own
+greedy tokens, in each KV layout: dense slots, a page pool, and a page
+pool whose first page two rows share (decoded by grouped attention). The
+same program with Qwen's epsilon must fall outside the tolerance.
 
 d_model stays at Phi-3's 3072, so the weights drawn at the benchmark's
 scales (``bench/weights.py``: the embedding at ``d_model ** -0.5``) put
@@ -35,7 +36,8 @@ from bench.reference import dense  # noqa: E402
 from repro import configs, hardware  # noqa: E402
 from repro.core import plan as plan_mod  # noqa: E402
 from repro.models.api import get_model  # noqa: E402
-from repro.models.kvlayout import PagedLayout  # noqa: E402
+from repro.kernels.group_attention import DecodeGroups  # noqa: E402
+from repro.models.kvlayout import DenseLayout, PagedLayout  # noqa: E402
 from repro.models.layers import LayerCtx  # noqa: E402
 
 BENCH = os.path.join(ROOT, "bench")
@@ -48,7 +50,7 @@ MODEL = {                      # Phi-3-mini with 2 layers of 2/2 heads
 }
 PAGE, CHUNK, MAX_SEQ = 16, 16, 128
 SEED, PROMPT, NEW = 2**31 + 5, 20, 40
-GRANULARITIES = ("split", "fused", "looped")
+LAYOUTS = ("dense", "paged", "paged+shared")
 
 
 def _program(eps: float):
@@ -70,36 +72,53 @@ def reference():
     return seq, dense.logits(MODEL, w, seq[:-1], PROMPT - 1, MAX_SEQ, 256)
 
 
-def _served(cfg, granularity: str, seq: np.ndarray) -> np.ndarray:
-    """The program's logits at the reference's positions: the prompt in
-    chunks into a page pool, then one decode step per served token."""
+def _served(cfg, layout: str, seq: np.ndarray) -> np.ndarray:
+    """The program's logits at the reference's positions, (rows, N, V):
+    the prompt in chunks into the cache, then one decode step per served
+    token. Under ``paged+shared`` row 1 holds the same sequence with the
+    prompt's first page mapped onto row 0's (prefilled once) and its own
+    pages after it, and decode attends to the shared page once for both
+    rows."""
     plan = plan_mod.tune(cfg, hardware.TPU_V5E, backend="pallas",
                          page_size=PAGE)
-    plan = dataclasses.replace(plan, decode_fusion=plan_mod.DecodeFusionPlan(
-        backend="pallas", granularity=granularity))
     api, ctx = get_model(cfg), LayerCtx(cfg=cfg, plan=plan)
     params = weights.for_program(MODEL, SEED, jax.eval_shape(
         api.init_params, jax.random.PRNGKey(0)))
     pages = MAX_SEQ // PAGE
-    cache = api.init_cache(PagedLayout(pages, PAGE, "bf16"))
-    table = jnp.arange(pages, dtype=jnp.int32)[None]
+    rows = 2 if layout == "paged+shared" else 1
+    table = groups = None
+    if layout == "dense":
+        cache = api.init_cache(DenseLayout(1, MAX_SEQ))
+    else:
+        cache = api.init_cache(PagedLayout(2 * pages, PAGE, "bf16"))
+        table = jnp.arange(pages, dtype=jnp.int32)[None]
+    if rows == 2:
+        table = jnp.asarray([list(range(pages)),
+                             [0, *range(pages, 2 * pages - 1)]], jnp.int32)
+        groups = DecodeGroups(*(jnp.asarray(a, jnp.int32) for a in (
+            [[0]], [1], [PAGE], [2], [[0, 1]], [0, 0], [0, 1],
+            [PAGE, PAGE])))
     chunk = jax.jit(lambda p, t, cl, c, le: api.prefill_chunk(
         ctx, p, t, cl, c, le, block_tables=table))
     step = jax.jit(lambda p, t, c, le: api.decode_step(
-        ctx, p, t, c, le, block_tables=table))
+        ctx, p, t, c, le, block_tables=table, decode_groups=groups))
     for start in range(0, PROMPT, CHUNK):
-        toks = np.zeros((1, CHUNK), np.int32)
+        toks = np.zeros((rows, CHUNK), np.int32)
         part = seq[start:min(start + CHUNK, PROMPT)]
-        toks[0, :len(part)] = part
+        toks[:, :len(part)] = part
+        # the shared page is prefilled by row 0 alone
+        lens = [len(part)] + [len(part) * (start >= PAGE)] * (rows - 1)
         logits, cache = chunk(params, jnp.asarray(toks),
-                              jnp.array([len(part)], jnp.int32), cache,
-                              jnp.array([start], jnp.int32))
-    got = [logits[0]]
+                              jnp.asarray(lens, jnp.int32), cache,
+                              jnp.full((rows,), start, jnp.int32))
+    got = [logits]
     for i in range(NEW - 1):
-        logits, cache = step(params, jnp.asarray(seq[PROMPT + i])[None],
-                             cache, jnp.array([PROMPT + i], jnp.int32))
-        got.append(logits[0])
-    return np.asarray(jnp.stack(got), np.float32)[:, :MODEL["vocab_size"]]
+        logits, cache = step(params, jnp.full((rows,), seq[PROMPT + i],
+                                              jnp.int32),
+                             cache, jnp.full((rows,), PROMPT + i, jnp.int32))
+        got.append(logits)
+    got = np.asarray(jnp.stack(got, axis=1), np.float32)
+    return got[..., :MODEL["vocab_size"]]
 
 
 def _tolerance(want: np.ndarray) -> float:
@@ -123,12 +142,13 @@ def _rms(a: np.ndarray) -> float:
     return float(np.sqrt(np.mean(a ** 2)))
 
 
-@pytest.mark.parametrize("granularity", GRANULARITIES)
-def test_served_logits_match_reference(reference, granularity):
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_served_logits_match_reference(reference, layout):
     seq, want = reference
-    got = _served(_program(MODEL["rms_norm_eps"]), granularity, seq)
+    got = _served(_program(MODEL["rms_norm_eps"]), layout, seq)
     tol = _tolerance(want)
-    assert _rms(got - want) <= tol, (_rms(got - want), tol)
+    for row in got:
+        assert _rms(row - want) <= tol, (_rms(row - want), tol)
     # greedy agrees wherever the reference's best logit leads its second
     # by more than the largest error the rms bound allows one logit
     # (2 L + 1 half-epsilons of max|logit|, doubled for both logits)
@@ -137,17 +157,19 @@ def test_served_logits_match_reference(reference, granularity):
         jnp.finfo(jnp.bfloat16).eps) * np.abs(want).max()
     clear = top2[:, 1] - top2[:, 0] > edge
     assert clear.any()
-    np.testing.assert_array_equal(got.argmax(1)[clear],
-                                  want.argmax(1)[clear])
+    for row in got:
+        np.testing.assert_array_equal(row.argmax(1)[clear],
+                                      want.argmax(1)[clear])
 
 
-@pytest.mark.parametrize("granularity", GRANULARITIES)
-def test_qwen_epsilon_falls_outside_tolerance(reference, granularity):
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_qwen_epsilon_falls_outside_tolerance(reference, layout):
     """The negative control: the same program with 1e-6 in place of the
     published 1e-5 is refused by the tolerance the served path meets."""
     seq, want = reference
-    got = _served(_program(1e-6), granularity, seq)
-    assert _rms(got - want) > _tolerance(want), _rms(got - want)
+    got = _served(_program(1e-6), layout, seq)
+    for row in got:
+        assert _rms(row - want) > _tolerance(want), _rms(row - want)
 
 
 @pytest.mark.parametrize("backend", ("xla", "pallas"))
@@ -166,7 +188,7 @@ def test_fused_seams_take_the_epsilon(backend):
     wg, wu = (jnp.asarray(rng.standard_normal((d, f)) / 16, jnp.bfloat16)
               for _ in range(2))
     pos = jnp.array([3, 7], jnp.int32)
-    plan = plan_mod.make_plan(backend=backend, decode_fusion="fused")
+    plan = plan_mod.make_plan(backend=backend)
     kw = dict(num_heads=h, num_kv_heads=h, head_dim=dh)
     bf = float(jnp.finfo(jnp.bfloat16).eps)
     for eps in (1e-5, 1e-6):

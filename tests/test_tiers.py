@@ -591,22 +591,31 @@ def test_flush_sessions_accounts_stats(smoke_model):
     eng.slots.check()
 
 
-def test_tiers_bench_smoke(tmp_path, monkeypatch):
-    """CI wiring: the tiers benchmark runs at smoke sizes, emits a
-    well-formed BENCH_tiers.json, and shows the two headline results —
-    a warm-session TTFT win and a sane swap-vs-re-prefill crossover."""
-    from benchmarks import kv_tiers
-    monkeypatch.setattr(kv_tiers, "OUT_PATH",
-                        str(tmp_path / "BENCH_tiers.json"))
-    result = kv_tiers.run(quick=True)
-    assert (tmp_path / "BENCH_tiers.quick.json").exists()
-    assert not (tmp_path / "BENCH_tiers.json").exists()
-    for row in result["ttft"]:
-        assert row["speedup"] > 1.0, "session cache must beat re-prefill"
-        assert row["promoted_pages"] > 0
-        assert row["saved_prefill_tokens"] > 0
-    assert result["identity"]["identical"]
-    for arch in result["crossover"]:
-        assert arch["swap_threshold"] >= 1
-        for pt in arch["curve"]:
-            assert pt["swap_s"] > 0 and pt["reprefill_s"] > 0
+def test_swap_threshold_follows_host_link():
+    """The tuned swap-vs-re-prefill crossover: on the v5e's host link a
+    demoted span of one page already promotes for qwen2-0.5b and
+    llama2-7b; both costs are positive and grow with the span; and the
+    threshold only rises as the link slows, until the slowest never
+    swaps (the sweep's ``max_pages + 1`` sentinel)."""
+    import dataclasses
+
+    from repro import hardware
+    from repro.core import dispatch
+    spec = hardware.TPU_V5E
+    for arch in ("qwen2-0.5b", "llama2-7b"):
+        cfg = configs.get(arch)
+        assert dispatch.find_swap_threshold(cfg, page_size=64,
+                                            spec=spec) == 1
+        page_bytes = dispatch.kv_page_bytes(cfg, page_size=64)
+        swap = [dispatch.predict_swap_time(n, page_bytes, spec=spec)
+                for n in (1, 4, 16)]
+        pre = [dispatch.predict_reprefill_time(cfg, n * 64, page_size=64,
+                                               spec=spec)
+               for n in (1, 4, 16)]
+        assert 0 < swap[0] < swap[1] < swap[2]
+        assert 0 < pre[0] < pre[1] < pre[2]
+        thresholds = [dispatch.find_swap_threshold(
+            cfg, page_size=64, spec=dataclasses.replace(spec, host_bw=bw))
+            for bw in (64e9, 16e9, 2e9, 1e9, 5e8, 2e8, 1e6)]
+        assert thresholds == sorted(thresholds), (arch, thresholds)
+        assert thresholds[-1] == 64 + 1, (arch, thresholds)

@@ -210,7 +210,7 @@ def test_flash_prefill_compiles(shape, unified):
 
 
 # ---------------------------------------------------------------------------
-# The decode-layer seams (looped / fused granularities)
+# The decode-layer seams
 # ---------------------------------------------------------------------------
 
 
@@ -323,6 +323,11 @@ def test_fused_ffn_compiles(shape, m, wdtype):
 # 64; four layers each, so every pool is larger than the chip's VMEM, as a
 # served pool is
 STEP_CASES = {"phi3": ("phi3-mini-3.8b", 128), "qwen": ("qwen2-0.5b", 2048)}
+# the steps also at a second page count each whose stored pool is
+# row-major: the 224 pages phi3's pool could hold on one chip, and the
+# qwen cell's 1024
+STEP_POOLS = {**STEP_CASES, "phi3-224": ("phi3-mini-3.8b", 224),
+              "qwen-1024": ("qwen2-0.5b", 1024)}
 STEP_LAYERS = 4
 # copies, slices, transposes: any of them on a pool or a slab moves it all
 MOVES = re.compile(r"copy|slice|transpose")
@@ -330,8 +335,7 @@ PAGED_KERNELS = ("paged_decode_attention", "paged_chunk_attention",
                  "grouped_decode_")
 
 
-def _program_hlo(topo, shape, monkeypatch, arch, num_pages, granularity,
-                 program):
+def _program_hlo(topo, shape, monkeypatch, arch, num_pages, program):
     """Optimized HLO of one program that takes the pool donated, built as
     ``Engine`` builds it — a step (``decode``, ``grouped``, ``chunk``) or
     a page copy (``copy_page``, ``promote_upload``) — for a
@@ -341,8 +345,7 @@ def _program_hlo(topo, shape, monkeypatch, arch, num_pages, granularity,
                         lambda: (False, hardware.TPU_V5E))
     cfg = dataclasses.replace(configs.get(arch), num_layers=STEP_LAYERS)
     api = get_model(cfg)
-    plan = make_plan("pallas", gather_chunk="fused", decode_group="grouped",
-                     decode_fusion=granularity)
+    plan = make_plan("pallas", gather_chunk="fused", decode_group="grouped")
     ctx = LayerCtx(cfg=cfg, plan=plan)
     spec = api.cache_spec(PagedLayout(num_pages, PAGE))
     cache = jax.tree.map(lambda s: shape(s.shape, s.dtype),
@@ -416,13 +419,12 @@ def _assert_in_place(text, cfg, num_pages, stored):
 
 
 @pytest.mark.parametrize("program", ["decode", "grouped", "chunk"])
-@pytest.mark.parametrize("granularity", ["looped", "fused"])
-@pytest.mark.parametrize("case", sorted(STEP_CASES))
+@pytest.mark.parametrize("case", sorted(STEP_POOLS))
 def test_step_updates_pool_in_place(topo, shape, monkeypatch, case,
-                                    granularity, program):
-    arch, num_pages = STEP_CASES[case]
+                                    program):
+    arch, num_pages = STEP_POOLS[case]
     text, cfg, stored = _program_hlo(topo, shape, monkeypatch, arch,
-                                     num_pages, granularity, program)
+                                     num_pages, program)
     _assert_in_place(text, cfg, num_pages, stored)
 
     # every paged kernel reads a 4-D (pages, PS, HK, D) pool operand, the
@@ -444,5 +446,5 @@ def test_page_copies_update_pool_in_place(topo, shape, monkeypatch, case,
     donated pool as the steps do: no relay of the pool around them."""
     arch, num_pages = STEP_CASES[case]
     text, cfg, stored = _program_hlo(topo, shape, monkeypatch, arch,
-                                     num_pages, "looped", program)
+                                     num_pages, program)
     _assert_in_place(text, cfg, num_pages, stored)

@@ -1,7 +1,7 @@
 """Quantized GEMM weight subsystem: per-output-channel round-trips,
 in-kernel dequant parity for every GEMM path, the engine-level logits
-guard across {split, fused, looped} x {dense, paged+sharing}, and the
-bf16 bitwise-identity regression.
+guard across the cache paths the engine serves (dense, paged, shared,
+int8 KV, tiers, preemption), and the bf16 bitwise-identity regression.
 
 The plan's contract (the weight-side twin of test_kvquant.py):
 ``weight_dtype`` may change the bytes behind every GEMM weight read and
@@ -198,8 +198,8 @@ def seam_case():
 
 def _plans():
     mk = lambda be: dataclasses.replace(
-        DEFAULT_PLAN, decode_fusion=dataclasses.replace(
-            DEFAULT_PLAN.decode_fusion, backend=be))
+        DEFAULT_PLAN, matmul=dataclasses.replace(
+            DEFAULT_PLAN.matmul, backend=be))
     return [("pallas", mk("pallas")), ("xla", mk("xla"))]
 
 
@@ -380,16 +380,28 @@ def smoke_model():
     return cfg, api, params
 
 
-def _mk_engine(cfg, params, weight_dtype, *, fusion="split", kind="dense",
-               sharing=False):
+# the served cache paths: engine keywords per case
+CACHE_CASES = {
+    "dense": {},
+    "paged": dict(cache_kind="paged"),
+    "paged+shared": dict(cache_kind="paged", prefix_sharing=True),
+    "paged+int8kv": dict(cache_kind="paged", kv_dtype="int8"),
+    "tiers": dict(cache_kind="paged", prefix_sharing=True, host_pages=32),
+    "preempt": dict(cache_kind="paged", num_pages=9),
+}
+# tokens per request in a round that outgrows the preempt case's pool:
+# three 24-token prompts are admitted on 9 pages of 16, and grow to four
+# pages each
+_PREEMPT_NEW = 40
+
+
+def _mk_engine(cfg, params, weight_dtype, case="dense"):
     from repro.serving.engine import Engine
-    kw = {}
-    if kind == "paged":
-        kw.update(page_size=_PAGE, prefill_chunk=_PAGE,
-                  prefix_sharing=sharing)
-    return Engine(cfg, params, num_slots=3, max_seq=128, cache_kind=kind,
-                  weight_dtype=weight_dtype, decode_fusion=fusion, seed=0,
-                  **kw)
+    kw = dict(CACHE_CASES[case])
+    if kw:
+        kw.update(page_size=_PAGE, prefill_chunk=_PAGE)
+    return Engine(cfg, params, num_slots=3, max_seq=128,
+                  weight_dtype=weight_dtype, seed=0, **kw)
 
 
 def _prompts(cfg, sharing):
@@ -402,6 +414,30 @@ def _prompts(cfg, sharing):
             for _ in range(3)]
     return [rng.integers(1, cfg.vocab_size, size=24).astype(np.int32)
             for _ in range(3)]
+
+
+def _case_prompts(cfg, case):
+    return _prompts(cfg, case in ("paged+shared", "tiers"))
+
+
+def _reqs(prompts, max_new):
+    from repro.serving.request import SamplingParams
+    sp = SamplingParams(max_new_tokens=max_new, temperature=0.0)
+    return [(p.copy(), sp) for p in prompts]
+
+
+def _first_round(eng, case, prompts):
+    """What a case runs before the measured admission: under ``tiers`` a
+    round whose sessions are then flushed to the host tier (the measured
+    admission promotes them back); under ``preempt`` a round that
+    outgrows the pool."""
+    if case == "tiers":
+        eng.run(_reqs(prompts, 4))
+        eng.evict_finished(flush=True)
+        assert eng.tiers.host_used > 0
+    elif case == "preempt":
+        eng.run(_reqs(prompts, _PREEMPT_NEW))
+        assert eng.stats.preemptions > 0, "pool was never under pressure"
 
 
 def _probe_logits(eng, api, prompts):
@@ -425,21 +461,20 @@ def _probe_logits(eng, api, prompts):
     return np.asarray(logits, np.float32)[rows]
 
 
-@pytest.mark.parametrize("kind,sharing",
-                         [("dense", False), ("paged", True)],
-                         ids=["dense", "paged+shared"])
-@pytest.mark.parametrize("fusion", ["split", "fused", "looped"])
-def test_quant_logits_within_guard(smoke_model, fusion, kind, sharing):
+@pytest.mark.parametrize("case", sorted(CACHE_CASES))
+def test_quant_logits_within_guard(smoke_model, case):
     """Teacher-forced decode logits under weight_dtype=int8 (and fp8
     where supported) stay within the dtype-derived guard vs the bf16
-    baseline, across the full granularity x cache matrix."""
+    baseline, on every cache path the engine serves."""
     cfg, api, params = smoke_model
-    prompts = _prompts(cfg, sharing)
+    prompts = _case_prompts(cfg, case)
     out = {}
     for wd in ["bf16"] + SPEC_IDS:
-        eng = _mk_engine(cfg, params, wd, fusion=fusion, kind=kind,
-                         sharing=sharing)
+        eng = _mk_engine(cfg, params, wd, case)
+        _first_round(eng, case, prompts)
         out[wd] = _probe_logits(eng, api, prompts)
+        if case == "tiers":
+            assert eng.stats.promoted_pages > 0, "nothing was promoted"
     scale = max(float(np.abs(out["bf16"]).max()), 1.0)
     for s in SPECS:
         atol = quant.logits_guard_tol(s) * scale
@@ -447,37 +482,43 @@ def test_quant_logits_within_guard(smoke_model, fusion, kind, sharing):
                                    rtol=0)
 
 
-@pytest.mark.parametrize("kind", ["dense", "paged"])
-@pytest.mark.parametrize("fusion", ["split", "fused", "looped"])
-def test_bf16_greedy_bitwise_unchanged(smoke_model, fusion, kind):
+@pytest.mark.parametrize("case", sorted(CACHE_CASES))
+def test_bf16_greedy_bitwise_unchanged(smoke_model, case):
     """weight_dtype='bf16' must be a no-op: greedy tokens identical to
     an engine that never heard of the knob (weight_dtype=None with a
-    default-plan bf16 knob) for every granularity and cache kind."""
-    from repro.serving.request import SamplingParams
+    default-plan bf16 knob) on every cache path — under ``tiers`` on the
+    returning round that promotes its pages, under ``preempt`` through
+    the preemptions."""
     cfg, api, params = smoke_model
-    prompts = _prompts(cfg, False)
-    sp = SamplingParams(max_new_tokens=5, temperature=0.0)
-    reqs = [(p.copy(), sp) for p in prompts]
-    explicit = _mk_engine(cfg, params, "bf16", fusion=fusion, kind=kind)
-    implicit = _mk_engine(cfg, params, None, fusion=fusion, kind=kind)
-    assert implicit.weight_dtype == "bf16"
-    assert explicit.run(reqs) == implicit.run(reqs)
+    prompts = _case_prompts(cfg, case)
+    max_new = _PREEMPT_NEW if case == "preempt" else 5
+    outs = []
+    for wd in ("bf16", None):
+        eng = _mk_engine(cfg, params, wd, case)
+        if case == "tiers":
+            _first_round(eng, case, prompts)
+        out = eng.run(_reqs(prompts, max_new))
+        outs.append([out[k] for k in sorted(out)])
+        assert eng.weight_dtype == "bf16"
+        if case == "tiers":
+            assert eng.stats.promoted_pages > 0, "nothing was promoted"
+        if case == "preempt":
+            assert eng.stats.preemptions > 0, "pool was never under pressure"
+    assert outs[0] == outs[1]
 
 
 def test_quant_greedy_runs_to_length(smoke_model):
-    """int8 engines decode to full length on every granularity (the
-    looped scan-body traces over (codes, scale) dict leaves). Bitwise
-    identity across granularities is a bf16-only contract — quantized
-    granularities are only held to the shared logits guard, which
+    """int8 engines decode to full length on the dense and the paged
+    cache (the decode scan body traces over (codes, scale) dict leaves).
+    Bitwise tokens are a bf16-only contract — quantized weights are only
+    held to the shared logits guard, which
     test_quant_logits_within_guard covers."""
-    from repro.serving.request import SamplingParams
     cfg, api, params = smoke_model
-    sp = SamplingParams(max_new_tokens=5, temperature=0.0)
-    reqs = [(p.copy(), sp) for p in _prompts(cfg, False)]
-    for fusion in ("split", "fused", "looped"):
-        eng = _mk_engine(cfg, params, "int8", fusion=fusion)
+    reqs = _reqs(_prompts(cfg, False), 5)
+    for case in ("dense", "paged"):
+        eng = _mk_engine(cfg, params, "int8", case)
         outs = eng.run(reqs)
-        assert all(len(v) == 5 for v in outs.values()), fusion
+        assert all(len(v) == 5 for v in outs.values()), case
 
 
 def test_engine_weight_byte_accounting(smoke_model):
@@ -535,18 +576,34 @@ def test_describe_mentions_weight_dtype():
     assert "w=" not in make_plan().describe()
 
 
-# ---------------------------------------------------------------------------
-# benchmark smoke
-# ---------------------------------------------------------------------------
+def test_int8_weights_footprint_and_decode_within_guard(smoke_model):
+    """int8 weights are >= 1.9x smaller than bf16 at llama2-7b's
+    published widths (scales included), and eight teacher-forced decode
+    steps through an int8 (and fp8) engine's params stay within the
+    dtype-derived guard of the bf16 steps."""
+    from repro.models.kvlayout import DenseLayout
+    from repro.models.layers import LayerCtx
+    full = configs.get("llama2-7b")
+    assert (dispatch.param_bytes(full, "bf16")
+            / dispatch.param_bytes(full, "int8")) >= 1.9
 
-
-def test_weight_quant_bench_smoke(tmp_path, monkeypatch):
-    from benchmarks import weight_quant
-    monkeypatch.setattr(weight_quant, "OUT_PATH",
-                        str(tmp_path / "BENCH_wquant.json"))
-    result = weight_quant.run(quick=True)
-    assert result["weight_bytes_per_tick"]["bf16"] > 0
-    assert result["byte_reduction"]["int8"] >= 1.9
-    assert result["footprint_reduction"]["int8"] >= 1.9
-    assert (result["max_abs_dlogits"]["int8"]
-            <= result["guard_atol"]["int8"])
+    cfg, api, params = smoke_model
+    slots, steps = 2, 8
+    toks = np.random.default_rng(5).integers(
+        1, cfg.vocab_size, size=(steps, slots)).astype(np.int32)
+    trace = {}
+    for wd in ["bf16"] + SPEC_IDS:
+        eng = _mk_engine(cfg, params, wd)
+        ctx = LayerCtx(cfg=cfg, plan=eng.plan)
+        step = jax.jit(lambda p, t, c, le: api.decode_step(ctx, p, t, c, le))
+        cache = api.init_cache(DenseLayout(slots, 16))
+        got = []
+        for t in range(steps):
+            logits, cache = step(eng.params, jnp.asarray(toks[t]), cache,
+                                 jnp.full((slots,), t, jnp.int32))
+            got.append(np.asarray(logits, np.float32))
+        trace[wd] = np.stack(got)
+    scale = max(float(np.abs(trace["bf16"]).max()), 1.0)
+    for s in SPECS:
+        np.testing.assert_allclose(trace[s.name], trace["bf16"], rtol=0,
+                                   atol=quant.logits_guard_tol(s) * scale)

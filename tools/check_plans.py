@@ -20,9 +20,6 @@ linter runs the same checks ahead of time, over every committed plan:
   * the paged op records an explicit ``kv_dtype`` (pre-quantization
     documents default to bf16 on load, but committed artifacts must say
     what they tuned for);
-  * the ``decode_fusion`` op records an explicit ``granularity`` in
-    ``FUSION_MODES`` (pre-fusion documents default to split on load —
-    same rule: committed artifacts must say what they tuned);
   * the ``matmul`` op records an explicit ``weight_dtype`` in
     ``WEIGHT_DTYPES`` (pre-weight-quant documents default to bf16 on
     load — committed artifacts must say what they tuned);
@@ -47,8 +44,7 @@ sys.path.insert(0, os.path.join(_ROOT, "src"))
 from repro import configs, hardware  # noqa: E402
 from repro.core import plan as plan_mod  # noqa: E402
 from repro.core.plan import (  # noqa: E402
-    FUSION_MODES, KV_DTYPES, PLAN_VERSION, WEIGHT_DTYPES, ExecutionPlan,
-    PlanError,
+    KV_DTYPES, PLAN_VERSION, WEIGHT_DTYPES, ExecutionPlan, PlanError,
 )
 
 
@@ -96,15 +92,6 @@ def check_plan(path: str) -> list:
     elif matmul_doc["weight_dtype"] not in WEIGHT_DTYPES:
         findings.append(f"weight_dtype {matmul_doc['weight_dtype']!r} "
                         f"not in {WEIGHT_DTYPES}")
-
-    fusion_doc = doc.get("ops", {}).get("decode_fusion", {})
-    if "granularity" not in fusion_doc:
-        findings.append("decode_fusion op missing explicit granularity "
-                        "(legacy document — retune)")
-    elif fusion_doc["granularity"] not in FUSION_MODES:
-        findings.append(f"decode_fusion granularity "
-                        f"{fusion_doc['granularity']!r} "
-                        f"not in {FUSION_MODES}")
 
     prov = plan.provenance
     if prov is None:
