@@ -22,6 +22,13 @@ the fused-FFN kernel does not cover:
     rmsnorm pulled inside, so the normed (M, D) activations never
     round-trip HBM between the norm and the GEMM pair.
 
+Each kernel reads its weights as layer ``layer`` of ``(L, K, N)``
+stacks, the layer index a scalar-prefetch operand of the weight
+BlockSpecs (:func:`~repro.kernels.flat_gemm.layer_stacks`): the paged
+layer scan hands down the whole stacked params, and no layer's weight is
+copied out of the stack in a step. A per-layer ``(K, N)`` weight is the
+one-layer stack at layer 0.
+
 Decode M is tiny (the batch), so everything is flat-GEMM shaped: M pads
 to the 8-sublane atom and the K dimension streams (same discipline as
 ``kernels/flat_gemm.py`` / ``kernels/fused_ffn.py``). The K-streamed f32
@@ -40,7 +47,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro import hardware
-from repro.kernels.flat_gemm import pick_bk, pick_bn, round_up
+from repro.kernels.flat_gemm import layer_stacks, pick_bk, pick_bn, round_up
 
 
 def _rope_pairs(t, cos, sin, n_heads: int, head_dim: int):
@@ -63,14 +70,16 @@ def _rope_pairs(t, cos, sin, n_heads: int, head_dim: int):
     return jnp.concatenate(parts, axis=1)
 
 
-def _ingest_kernel(x_ref, scale_ref, wq_ref, wk_ref, wv_ref,
+def _ingest_kernel(layer_ref, x_ref, scale_ref, wq_ref, wk_ref, wv_ref,
                    bq_ref, bk_ref, bv_ref, pos_ref, *refs,
                    d_real: int, bk: int, num_heads: int,
                    num_kv_heads: int, head_dim: int, theta: float,
                    eps: float, use_rope: bool, quantized: bool = False):
+    # ``layer_ref`` (scalar prefetch) only steers the weight index maps.
     # The quantized variant appends three per-output-channel step operands
     # ((1, NQ)/(1, NK) f32, full-width like the biases) after ``pos``; the
     # branches are trace-time, so the bf16 kernel's jaxpr is unchanged.
+    del layer_ref
     if quantized:
         (sq_ref, sk_ref, sv_ref,
          outq_ref, outk_ref, outv_ref,
@@ -139,8 +148,8 @@ def _ingest_kernel(x_ref, scale_ref, wq_ref, wk_ref, wv_ref,
 def decode_ingest_fused(
     x: jax.Array,             # (M, D) residual-stream rows
     norm_scale: jax.Array,    # (D,)
-    wq: jax.Array,            # (D, HQ*Dh)
-    wk: jax.Array,            # (D, HK*Dh)
+    wq: jax.Array,            # (D, HQ*Dh), or an (L, D, HQ*Dh) stack
+    wk: jax.Array,            # (D, HK*Dh), or an (L, D, HK*Dh) stack
     wv: jax.Array,
     positions: jax.Array,     # (M,) int32
     *,
@@ -156,16 +165,19 @@ def decode_ingest_fused(
     wq_scale: jax.Array | None = None,   # (HQ*Dh,) f32 -> wq is codes
     wk_scale: jax.Array | None = None,   # (HK*Dh,) f32 -> wk is codes
     wv_scale: jax.Array | None = None,
+    layer=None,
     block_k: int = 0,
     spec: hardware.HardwareSpec = hardware.TPU_V5E,
     interpret: bool = False,
 ):
     """Fused rmsnorm → QKV → bias → rope. Returns flat q (M, HQ*Dh) and
-    k/v (M, HK*Dh) in x.dtype (the caller owns the head reshape)."""
+    k/v (M, HK*Dh) in x.dtype (the caller owns the head reshape). With a
+    ``layer`` the weights (and steps, ``(L, N)``) are stacks read in
+    place (:func:`~repro.kernels.flat_gemm.layer_stacks`)."""
     assert (wq_scale is None) == (wk_scale is None) == (wv_scale is None), \
         "qkv weights quantize together"
     m, d = x.shape
-    nq, nk = wq.shape[1], wk.shape[1]
+    nq, nk = wq.shape[-1], wk.shape[-1]
     assert nq == num_heads * head_dim and nk == num_kv_heads * head_dim
     dtype_bytes = jnp.dtype(x.dtype).itemsize
 
@@ -182,15 +194,6 @@ def decode_ingest_fused(
     bv = jnp.zeros((nk,), x.dtype) if bv is None else bv
 
     nqp, nkp = round_up(nq, 128), round_up(nk, 128)
-    if nqp != nq:
-        wq = jnp.pad(wq, ((0, 0), (0, nqp - nq)))
-        bq = jnp.pad(bq, (0, nqp - nq))
-    if nkp != nk:
-        wk = jnp.pad(wk, ((0, 0), (0, nkp - nk)))
-        wv = jnp.pad(wv, ((0, 0), (0, nkp - nk)))
-        bk_bias = jnp.pad(bk_bias, (0, nkp - nk))
-        bv = jnp.pad(bv, (0, nkp - nk))
-
     bk = block_k or pick_bk(m_pad, nqp + 2 * nkp, d,
                             dtype_bytes=dtype_bytes, spec=spec)
     # the working set holds three double-buffered weight streams, the
@@ -204,34 +207,46 @@ def decode_ingest_fused(
             + m_pad * (nqp + 2 * nkp) * 4) > budget:
         bk //= 2
         kp = round_up(d, bk)
+    layer_op, (wq, wk, wv, wq_scale, wk_scale, wv_scale) = layer_stacks(
+        layer, (wq, wk, wv, wq_scale, wk_scale, wv_scale),
+        pad=(nqp, nkp, kp) != (nq, nk, d))
+    if nqp != nq:
+        wq = jnp.pad(wq, ((0, 0), (0, 0), (0, nqp - nq)))
+        bq = jnp.pad(bq, (0, nqp - nq))
+    if nkp != nk:
+        wk = jnp.pad(wk, ((0, 0), (0, 0), (0, nkp - nk)))
+        wv = jnp.pad(wv, ((0, 0), (0, 0), (0, nkp - nk)))
+        bk_bias = jnp.pad(bk_bias, (0, nkp - nk))
+        bv = jnp.pad(bv, (0, nkp - nk))
     if kp != d:
         x = jnp.pad(x, ((0, 0), (0, kp - d)))
         norm_scale = jnp.pad(norm_scale, (0, kp - d))
-        wq = jnp.pad(wq, ((0, kp - d), (0, 0)))
-        wk = jnp.pad(wk, ((0, kp - d), (0, 0)))
-        wv = jnp.pad(wv, ((0, kp - d), (0, 0)))
+        wq = jnp.pad(wq, ((0, 0), (0, kp - d), (0, 0)))
+        wk = jnp.pad(wk, ((0, 0), (0, kp - d), (0, 0)))
+        wv = jnp.pad(wv, ((0, 0), (0, kp - d), (0, 0)))
 
     quantized = wq_scale is not None
     operands = [x, norm_scale[None, :], wq, wk, wv,
                 bq[None, :], bk_bias[None, :], bv[None, :], pos]
     in_specs = [
-        pl.BlockSpec((m_pad, kp), lambda k_: (0, 0)),
-        pl.BlockSpec((1, kp), lambda k_: (0, 0)),
-        pl.BlockSpec((bk, nqp), lambda k_: (k_, 0)),
-        pl.BlockSpec((bk, nkp), lambda k_: (k_, 0)),
-        pl.BlockSpec((bk, nkp), lambda k_: (k_, 0)),
-        pl.BlockSpec((1, nqp), lambda k_: (0, 0)),
-        pl.BlockSpec((1, nkp), lambda k_: (0, 0)),
-        pl.BlockSpec((1, nkp), lambda k_: (0, 0)),
-        pl.BlockSpec((m_pad, 1), lambda k_: (0, 0)),
+        pl.BlockSpec((m_pad, kp), lambda k_, l_: (0, 0)),
+        pl.BlockSpec((1, kp), lambda k_, l_: (0, 0)),
+        pl.BlockSpec((None, bk, nqp), lambda k_, l_: (l_[0], k_, 0)),
+        pl.BlockSpec((None, bk, nkp), lambda k_, l_: (l_[0], k_, 0)),
+        pl.BlockSpec((None, bk, nkp), lambda k_, l_: (l_[0], k_, 0)),
+        pl.BlockSpec((1, nqp), lambda k_, l_: (0, 0)),
+        pl.BlockSpec((1, nkp), lambda k_, l_: (0, 0)),
+        pl.BlockSpec((1, nkp), lambda k_, l_: (0, 0)),
+        pl.BlockSpec((m_pad, 1), lambda k_, l_: (0, 0)),
     ]
     if quantized:
         for s, width in ((wq_scale, nqp), (wk_scale, nkp), (wv_scale, nkp)):
-            s = s.astype(jnp.float32).reshape(1, -1)
-            if s.shape[1] != width:
-                s = jnp.pad(s, ((0, 0), (0, width - s.shape[1])))
+            s = s.astype(jnp.float32)[:, None, :]
+            if s.shape[2] != width:
+                s = jnp.pad(s, ((0, 0), (0, 0), (0, width - s.shape[2])))
             operands.append(s)
-            in_specs.append(pl.BlockSpec((1, width), lambda k_: (0, 0)))
+            in_specs.append(pl.BlockSpec(
+                (None, 1, width), lambda k_, l_: (l_[0], 0, 0)))
 
     outq, outk, outv = pl.pallas_call(
         functools.partial(
@@ -239,23 +254,26 @@ def decode_ingest_fused(
             num_kv_heads=num_kv_heads, head_dim=head_dim,
             theta=rope_theta, eps=eps, use_rope=use_rope,
             quantized=quantized),
-        grid=(kp // bk,),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((m_pad, nqp), lambda k_: (0, 0)),
-            pl.BlockSpec((m_pad, nkp), lambda k_: (0, 0)),
-            pl.BlockSpec((m_pad, nkp), lambda k_: (0, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(kp // bk,),
+            in_specs=in_specs,
+            out_specs=[
+                pl.BlockSpec((m_pad, nqp), lambda k_, l_: (0, 0)),
+                pl.BlockSpec((m_pad, nkp), lambda k_, l_: (0, 0)),
+                pl.BlockSpec((m_pad, nkp), lambda k_, l_: (0, 0)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((m_pad, kp), x.dtype),
+                pltpu.VMEM((m_pad, nqp), jnp.float32),
+                pltpu.VMEM((m_pad, nkp), jnp.float32),
+                pltpu.VMEM((m_pad, nkp), jnp.float32),
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((m_pad, nqp), x.dtype),
             jax.ShapeDtypeStruct((m_pad, nkp), x.dtype),
             jax.ShapeDtypeStruct((m_pad, nkp), x.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((m_pad, kp), x.dtype),
-            pltpu.VMEM((m_pad, nqp), jnp.float32),
-            pltpu.VMEM((m_pad, nkp), jnp.float32),
-            pltpu.VMEM((m_pad, nkp), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
@@ -263,14 +281,16 @@ def decode_ingest_fused(
         ),
         name="decode_ingest_fused",
         interpret=interpret,
-    )(*operands)
+    )(layer_op, *operands)
     return outq[:m, :nq], outk[:m, :nk], outv[:m, :nk]
 
 
-def _oproj_kernel(o_ref, wo_ref, resid_ref, *refs,
+def _oproj_kernel(layer_ref, o_ref, wo_ref, resid_ref, *refs,
                   quantized: bool = False):
+    # ``layer_ref`` (scalar prefetch) only steers the weight index maps;
     # quantized appends one (1, B_N) f32 step operand after ``resid``;
     # trace-time branch, bf16 jaxpr unchanged
+    del layer_ref
     if quantized:
         scale_ref, out_ref, acc_ref = refs
     else:
@@ -301,18 +321,21 @@ def _oproj_kernel(o_ref, wo_ref, resid_ref, *refs,
 
 def oproj_residual_fused(
     o: jax.Array,       # (M, Q) attention outputs
-    wo: jax.Array,      # (Q, D)
+    wo: jax.Array,      # (Q, D), or an (L, Q, D) stack read at ``layer``
     resid: jax.Array,   # (M, D) residual stream
     *,
-    w_scale: jax.Array | None = None,   # (D,) f32 -> wo is quantized codes
+    w_scale: jax.Array | None = None,   # (D,) / (L, D) f32 -> wo is codes
+    layer=None,
     block_n: int = 0,
     block_k: int = 0,
     spec: hardware.HardwareSpec = hardware.TPU_V5E,
     interpret: bool = False,
 ) -> jax.Array:
-    """resid + o @ wo with the residual add fused into the GEMM epilogue."""
+    """resid + o @ wo with the residual add fused into the GEMM epilogue.
+    With a ``layer`` the weight is a stack read in place
+    (:func:`~repro.kernels.flat_gemm.layer_stacks`)."""
     m, k = o.shape
-    k2, n = wo.shape
+    k2, n = wo.shape[-2:]
     assert k2 == k and resid.shape == (m, n), (o.shape, wo.shape,
                                                resid.shape)
     dtype_bytes = jnp.dtype(o.dtype).itemsize
@@ -324,52 +347,60 @@ def oproj_residual_fused(
 
     bn = block_n or pick_bn(m_pad, n, k, dtype_bytes=dtype_bytes, spec=spec)
     bk = block_k or pick_bk(m_pad, bn, k, dtype_bytes=dtype_bytes, spec=spec)
-    if n % bn:
-        pad_n = bn - n % bn
-        wo = jnp.pad(wo, ((0, 0), (0, pad_n)))
+    pad_n, pad_k = -n % bn, -k % bk
+    layer_op, (wo, w_scale) = layer_stacks(layer, (wo, w_scale),
+                                           pad=bool(pad_n or pad_k))
+    if pad_n:
+        wo = jnp.pad(wo, ((0, 0), (0, 0), (0, pad_n)))
         resid = jnp.pad(resid, ((0, 0), (0, pad_n)))
-    if k % bk:
-        pad_k = bk - k % bk
+    if pad_k:
         o = jnp.pad(o, ((0, 0), (0, pad_k)))
-        wo = jnp.pad(wo, ((0, pad_k), (0, 0)))
-    kp, np_ = o.shape[1], wo.shape[1]
+        wo = jnp.pad(wo, ((0, 0), (0, pad_k), (0, 0)))
+    kp, np_ = k + pad_k, n + pad_n
 
     quantized = w_scale is not None
     operands = [o, wo, resid]
     in_specs = [
-        pl.BlockSpec((m_pad, bk), lambda n_, k_: (0, k_)),
-        pl.BlockSpec((bk, bn), lambda n_, k_: (k_, n_)),
-        pl.BlockSpec((m_pad, bn), lambda n_, k_: (0, n_)),
+        pl.BlockSpec((m_pad, bk), lambda n_, k_, l_: (0, k_)),
+        pl.BlockSpec((None, bk, bn), lambda n_, k_, l_: (l_[0], k_, n_)),
+        pl.BlockSpec((m_pad, bn), lambda n_, k_, l_: (0, n_)),
     ]
     if quantized:
-        scale = w_scale.astype(jnp.float32).reshape(1, -1)
-        if np_ != n:
-            scale = jnp.pad(scale, ((0, 0), (0, np_ - n)))
+        scale = w_scale.astype(jnp.float32)[:, None, :]
+        if pad_n:
+            scale = jnp.pad(scale, ((0, 0), (0, 0), (0, pad_n)))
         operands.append(scale)
-        in_specs.append(pl.BlockSpec((1, bn), lambda n_, k_: (0, n_)))
+        in_specs.append(pl.BlockSpec(
+            (None, 1, bn), lambda n_, k_, l_: (l_[0], 0, n_)))
 
     out = pl.pallas_call(
         functools.partial(_oproj_kernel, quantized=quantized),
-        grid=(np_ // bn, kp // bk),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((m_pad, bn), lambda n_, k_: (0, n_)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(np_ // bn, kp // bk),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((m_pad, bn),
+                                   lambda n_, k_, l_: (0, n_)),
+            scratch_shapes=[pltpu.VMEM((m_pad, bn), jnp.float32)],
+        ),
         out_shape=jax.ShapeDtypeStruct((m_pad, np_), resid.dtype),
-        scratch_shapes=[pltpu.VMEM((m_pad, bn), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=spec.vmem_limit_bytes,
         ),
         name="oproj_residual_fused",
         interpret=interpret,
-    )(*operands)
+    )(layer_op, *operands)
     return out[:m, :n]
 
 
-def _ffn_norm_kernel(x_ref, scale_ref, wg_ref, wu_ref, *refs,
+def _ffn_norm_kernel(layer_ref, x_ref, scale_ref, wg_ref, wu_ref, *refs,
                      d_real: int, bk: int, activation: str, eps: float,
                      quantized: bool = False):
+    # ``layer_ref`` (scalar prefetch) only steers the weight index maps;
     # quantized appends two (1, B_N) f32 step operands after ``w_up``;
     # trace-time branch, bf16 jaxpr unchanged
+    del layer_ref
     if quantized:
         sg_ref, su_ref, out_ref, xn_ref, accg_ref, accu_ref = refs
     else:
@@ -418,13 +449,14 @@ def _ffn_norm_kernel(x_ref, scale_ref, wg_ref, wu_ref, *refs,
 def ffn_norm_fused(
     x: jax.Array,             # (M, D) residual-stream rows (un-normed)
     norm_scale: jax.Array,    # (D,)
-    w_gate: jax.Array,        # (D, F)
-    w_up: jax.Array,          # (D, F)
+    w_gate: jax.Array,        # (D, F), or an (L, D, F) stack
+    w_up: jax.Array,          # (D, F), or an (L, D, F) stack
     *,
     activation: str = "swiglu",
     eps: float,
-    wg_scale: jax.Array | None = None,  # (F,) f32 -> w_gate is codes
-    wu_scale: jax.Array | None = None,  # (F,) f32 -> w_up is codes
+    wg_scale: jax.Array | None = None,  # (F,) / (L, F) f32 -> codes
+    wu_scale: jax.Array | None = None,  # (F,) / (L, F) f32 -> codes
+    layer=None,
     block_n: int = 0,
     block_k: int = 0,
     spec: hardware.HardwareSpec = hardware.TPU_V5E,
@@ -432,13 +464,14 @@ def ffn_norm_fused(
 ) -> jax.Array:
     """Fused rmsnorm → gate/up GEMMs → act(g)*u. Returns (M, F) in
     x.dtype — feed it to :func:`oproj_residual_fused` with ``w_down``
-    for the full mlp seam."""
+    for the full mlp seam. With a ``layer`` the weights are stacks read
+    in place (:func:`~repro.kernels.flat_gemm.layer_stacks`)."""
     assert (wg_scale is None) == (wu_scale is None), \
         "gate/up weights quantize together"
     m, d = x.shape
-    d2, f = w_gate.shape
-    assert d2 == d and w_up.shape == (d, f), (x.shape, w_gate.shape,
-                                              w_up.shape)
+    d2, f = w_gate.shape[-2:]
+    assert d2 == d and w_up.shape == w_gate.shape, (x.shape, w_gate.shape,
+                                                    w_up.shape)
     dtype_bytes = jnp.dtype(x.dtype).itemsize
 
     m_pad = round_up(max(m, 1), 8)
@@ -464,44 +497,51 @@ def ffn_norm_fused(
         kp = round_up(d, bk)
 
     fp = round_up(f, bn)
+    layer_op, (w_gate, w_up, wg_scale, wu_scale) = layer_stacks(
+        layer, (w_gate, w_up, wg_scale, wu_scale), pad=(fp, kp) != (f, d))
     if fp != f:
-        w_gate = jnp.pad(w_gate, ((0, 0), (0, fp - f)))
-        w_up = jnp.pad(w_up, ((0, 0), (0, fp - f)))
+        w_gate = jnp.pad(w_gate, ((0, 0), (0, 0), (0, fp - f)))
+        w_up = jnp.pad(w_up, ((0, 0), (0, 0), (0, fp - f)))
     if kp != d:
         x = jnp.pad(x, ((0, 0), (0, kp - d)))
         norm_scale = jnp.pad(norm_scale, (0, kp - d))
-        w_gate = jnp.pad(w_gate, ((0, kp - d), (0, 0)))
-        w_up = jnp.pad(w_up, ((0, kp - d), (0, 0)))
+        w_gate = jnp.pad(w_gate, ((0, 0), (0, kp - d), (0, 0)))
+        w_up = jnp.pad(w_up, ((0, 0), (0, kp - d), (0, 0)))
 
     quantized = wg_scale is not None
     operands = [x, norm_scale[None, :], w_gate, w_up]
     in_specs = [
-        pl.BlockSpec((m_pad, kp), lambda n_, k_: (0, 0)),
-        pl.BlockSpec((1, kp), lambda n_, k_: (0, 0)),
-        pl.BlockSpec((bk, bn), lambda n_, k_: (k_, n_)),
-        pl.BlockSpec((bk, bn), lambda n_, k_: (k_, n_)),
+        pl.BlockSpec((m_pad, kp), lambda n_, k_, l_: (0, 0)),
+        pl.BlockSpec((1, kp), lambda n_, k_, l_: (0, 0)),
+        pl.BlockSpec((None, bk, bn), lambda n_, k_, l_: (l_[0], k_, n_)),
+        pl.BlockSpec((None, bk, bn), lambda n_, k_, l_: (l_[0], k_, n_)),
     ]
     if quantized:
         for s in (wg_scale, wu_scale):
-            s = s.astype(jnp.float32).reshape(1, -1)
+            s = s.astype(jnp.float32)[:, None, :]
             if fp != f:
-                s = jnp.pad(s, ((0, 0), (0, fp - f)))
+                s = jnp.pad(s, ((0, 0), (0, 0), (0, fp - f)))
             operands.append(s)
-            in_specs.append(pl.BlockSpec((1, bn), lambda n_, k_: (0, n_)))
+            in_specs.append(pl.BlockSpec(
+                (None, 1, bn), lambda n_, k_, l_: (l_[0], 0, n_)))
 
     out = pl.pallas_call(
         functools.partial(_ffn_norm_kernel, d_real=d, bk=bk,
                           activation=activation, eps=eps,
                           quantized=quantized),
-        grid=(fp // bn, kp // bk),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((m_pad, bn), lambda n_, k_: (0, n_)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(fp // bn, kp // bk),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((m_pad, bn),
+                                   lambda n_, k_, l_: (0, n_)),
+            scratch_shapes=[
+                pltpu.VMEM((m_pad, kp), x.dtype),
+                pltpu.VMEM((m_pad, bn), jnp.float32),
+                pltpu.VMEM((m_pad, bn), jnp.float32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((m_pad, fp), x.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((m_pad, kp), x.dtype),
-            pltpu.VMEM((m_pad, bn), jnp.float32),
-            pltpu.VMEM((m_pad, bn), jnp.float32),
-        ],
         compiler_params=pltpu.CompilerParams(
             # the N dim must run sequentially too: every N block reads
             # the normed-x scratch written at grid step (0, 0)
@@ -510,5 +550,5 @@ def ffn_norm_fused(
         ),
         name="ffn_norm_fused",
         interpret=interpret,
-    )(*operands)
+    )(layer_op, *operands)
     return out[:m, :f]

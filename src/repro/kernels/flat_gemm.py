@@ -71,6 +71,31 @@ def pick_bk(m: int, bn: int, k: int, *, dtype_bytes: int = 2,
     return min(best, k)
 
 
+def layer_stacks(layer, leaves, *, pad: bool):
+    """``(layer operand, leaves)`` for a kernel that reads its weights as
+    layer ``layer`` of ``(L, K, N)`` stacks (steps ``(L, N)``).
+
+    The layer index rides in as a scalar-prefetch operand and the weight
+    BlockSpecs take ``(None, bk, bn)`` blocks at ``(layer_ref[0], k, n)``,
+    so the kernel reads the stack in place and no layer is ever copied
+    out of it. ``layer is None`` marks per-layer leaves (``(K, N)``
+    weights, ``(N,)`` steps): they become one-layer stacks, a bitcast,
+    read at layer 0. A kernel that must ``pad`` a weight (a width no
+    block divides) takes that one layer out of the stack first, so the
+    pad copies a layer, never the whole stack. ``None`` leaves pass
+    through.
+    """
+    if layer is None:
+        leaves = [None if a is None else a[None] for a in leaves]
+        layer = 0
+    elif pad:
+        leaves = [None if a is None else
+                  jax.lax.dynamic_slice_in_dim(a, layer, 1, axis=0)
+                  for a in leaves]
+        layer = 0
+    return jnp.asarray(layer, jnp.int32).reshape(1), leaves
+
+
 def _flat_gemm_kernel(x_ref, w_ref, out_ref, acc_ref):
     ki = pl.program_id(1)
     n_k = pl.num_programs(1)

@@ -30,15 +30,17 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro import hardware
-from repro.kernels.flat_gemm import pick_bk, pick_bn, round_up
+from repro.kernels.flat_gemm import layer_stacks, pick_bk, pick_bn, round_up
 
 
-def _fused_ffn_kernel(x_ref, wg_ref, wu_ref, *refs,
+def _fused_ffn_kernel(layer_ref, x_ref, wg_ref, wu_ref, *refs,
                       activation: str, quantized: bool = False):
+    # ``layer_ref`` (scalar prefetch) only steers the weight index maps.
     # Quantized variant appends two per-output-channel step operands
     # ((1, B_N) f32) after the weights; the branches are trace-time, so
     # the bf16 kernel's jaxpr is unchanged. Steps apply on the f32
     # accumulators *before* the activation (dequant-then-nonlinearity).
+    del layer_ref
     if quantized:
         sg_ref, su_ref, out_ref, accg_ref, accu_ref = refs
     else:
@@ -76,25 +78,28 @@ def _fused_ffn_kernel(x_ref, wg_ref, wu_ref, *refs,
 
 def fused_ffn_up(
     x: jax.Array,        # (M, K)
-    w_gate: jax.Array,   # (K, N)
-    w_up: jax.Array,     # (K, N)
+    w_gate: jax.Array,   # (K, N), or an (L, K, N) stack read at ``layer``
+    w_up: jax.Array,     # (K, N), or an (L, K, N) stack read at ``layer``
     *,
     activation: str = "swiglu",
-    wg_scale: jax.Array | None = None,  # (N,) f32 -> w_gate is codes
-    wu_scale: jax.Array | None = None,  # (N,) f32 -> w_up is codes
+    wg_scale: jax.Array | None = None,  # (N,) / (L, N) f32 -> codes
+    wu_scale: jax.Array | None = None,  # (N,) / (L, N) f32 -> codes
+    layer=None,
     block_n: int = 0,
     block_k: int = 0,
     out_dtype=None,
     spec: hardware.HardwareSpec = hardware.TPU_V5E,
     interpret: bool = False,
 ) -> jax.Array:
-    """h = act(x @ w_gate) * (x @ w_up), epilogue fused in VMEM."""
+    """h = act(x @ w_gate) * (x @ w_up), epilogue fused in VMEM. With a
+    ``layer`` the weights are stacks read in place
+    (:func:`~repro.kernels.flat_gemm.layer_stacks`)."""
     assert (wg_scale is None) == (wu_scale is None), \
         "gate/up weights quantize together"
     m, k = x.shape
-    k2, n = w_gate.shape
-    assert (k2, n) == w_up.shape == (k, n), (x.shape, w_gate.shape,
-                                             w_up.shape)
+    k2, n = w_gate.shape[-2:]
+    assert k2 == k and w_up.shape == w_gate.shape, (x.shape, w_gate.shape,
+                                                    w_up.shape)
     out_dtype = out_dtype or x.dtype
     dtype_bytes = jnp.dtype(x.dtype).itemsize
 
@@ -112,48 +117,54 @@ def fused_ffn_up(
             2 * (m_pad * bk + 2 * bk * bn) * dtype_bytes
             + 2 * m_pad * bn * 4) > budget:
         bk //= 2
-    if n % bn:
-        pad_n = bn - n % bn
-        w_gate = jnp.pad(w_gate, ((0, 0), (0, pad_n)))
-        w_up = jnp.pad(w_up, ((0, 0), (0, pad_n)))
-    if k % bk:
-        pad_k = bk - k % bk
+    pad_n, pad_k = -n % bn, -k % bk
+    layer_op, (w_gate, w_up, wg_scale, wu_scale) = layer_stacks(
+        layer, (w_gate, w_up, wg_scale, wu_scale), pad=bool(pad_n or pad_k))
+    if pad_n:
+        w_gate = jnp.pad(w_gate, ((0, 0), (0, 0), (0, pad_n)))
+        w_up = jnp.pad(w_up, ((0, 0), (0, 0), (0, pad_n)))
+    if pad_k:
         x = jnp.pad(x, ((0, 0), (0, pad_k)))
-        w_gate = jnp.pad(w_gate, ((0, pad_k), (0, 0)))
-        w_up = jnp.pad(w_up, ((0, pad_k), (0, 0)))
-    kp, np_ = x.shape[1], w_gate.shape[1]
+        w_gate = jnp.pad(w_gate, ((0, 0), (0, pad_k), (0, 0)))
+        w_up = jnp.pad(w_up, ((0, 0), (0, pad_k), (0, 0)))
+    kp, np_ = k + pad_k, n + pad_n
 
     quantized = wg_scale is not None
     operands = [x, w_gate, w_up]
     in_specs = [
-        pl.BlockSpec((m_pad, bk), lambda n_, k_: (0, k_)),
-        pl.BlockSpec((bk, bn), lambda n_, k_: (k_, n_)),
-        pl.BlockSpec((bk, bn), lambda n_, k_: (k_, n_)),
+        pl.BlockSpec((m_pad, bk), lambda n_, k_, l_: (0, k_)),
+        pl.BlockSpec((None, bk, bn), lambda n_, k_, l_: (l_[0], k_, n_)),
+        pl.BlockSpec((None, bk, bn), lambda n_, k_, l_: (l_[0], k_, n_)),
     ]
     if quantized:
         for s in (wg_scale, wu_scale):
-            s = s.astype(jnp.float32).reshape(1, -1)
-            if np_ != n:
-                s = jnp.pad(s, ((0, 0), (0, np_ - n)))
+            s = s.astype(jnp.float32)[:, None, :]
+            if pad_n:
+                s = jnp.pad(s, ((0, 0), (0, 0), (0, pad_n)))
             operands.append(s)
-            in_specs.append(pl.BlockSpec((1, bn), lambda n_, k_: (0, n_)))
+            in_specs.append(pl.BlockSpec(
+                (None, 1, bn), lambda n_, k_, l_: (l_[0], 0, n_)))
 
     out = pl.pallas_call(
         functools.partial(_fused_ffn_kernel, activation=activation,
                           quantized=quantized),
-        grid=(np_ // bn, kp // bk),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((m_pad, bn), lambda n_, k_: (0, n_)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(np_ // bn, kp // bk),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((m_pad, bn),
+                                   lambda n_, k_, l_: (0, n_)),
+            scratch_shapes=[
+                pltpu.VMEM((m_pad, bn), jnp.float32),
+                pltpu.VMEM((m_pad, bn), jnp.float32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((m_pad, np_), out_dtype),
-        scratch_shapes=[
-            pltpu.VMEM((m_pad, bn), jnp.float32),
-            pltpu.VMEM((m_pad, bn), jnp.float32),
-        ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=spec.vmem_limit_bytes,
         ),
         name="fused_ffn_up",
         interpret=interpret,
-    )(*operands)
+    )(layer_op, *operands)
     return out[:m, :n]

@@ -80,6 +80,22 @@ def _wparts(w):
     return w, None
 
 
+def at_layer(tree, layer):
+    """Layer ``layer`` of stacked params, sliced by XLA, which fuses the
+    slice into its consumer (``layer is None``: ``tree`` is one layer's
+    params already).
+
+    The paged layer scan hands each block the whole stacked params and
+    its layer index: the GEMM seams pass their weight stacks and the
+    index on to the kernels, which read the layer in place
+    (:func:`~repro.kernels.flat_gemm.layer_stacks`); everything else —
+    norm scales, biases, XLA-dot weights, the XLA oracles' weights — goes
+    through here."""
+    if layer is None:
+        return tree
+    return jax.tree.map(lambda a: a[layer], tree)
+
+
 # ---------------------------------------------------------------------------
 # GEMM front door (T3)
 # ---------------------------------------------------------------------------
@@ -124,11 +140,14 @@ def fused_ffn(
     w_up: jax.Array,     # (K, N)
     *,
     activation: str = "swiglu",
+    layer=None,
     plan: Optional[ExecutionPlan] = None,
 ) -> jax.Array:
     """act(x @ w_gate) * (x @ w_up) — the single fused epilogue kernel
     when the plan's ``fused_ffn`` entry says ``fused`` on the Pallas
-    backend (kernels/fused_ffn.py), oracle math otherwise."""
+    backend (kernels/fused_ffn.py), oracle math otherwise. With a
+    ``layer`` the weights are ``(L, K, N)`` stacks, which the kernel
+    reads in place."""
     fp = (plan or DEFAULT_PLAN).fused_ffn
     interpret, spec = kernel_target()
     w_gate, wg_scale = _wparts(w_gate)
@@ -141,8 +160,10 @@ def fused_ffn(
         from repro.kernels.fused_ffn import fused_ffn_up
         out = fused_ffn_up(x2, w_gate, w_up, activation=activation,
                            wg_scale=wg_scale, wu_scale=wu_scale,
-                           spec=spec, interpret=interpret)
+                           layer=layer, spec=spec, interpret=interpret)
     else:
+        w_gate, w_up, wg_scale, wu_scale = at_layer(
+            (w_gate, w_up, wg_scale, wu_scale), layer)
         out = ref.fused_ffn_up_ref(x2, w_gate, w_up, activation=activation,
                                    wg_scale=wg_scale, wu_scale=wu_scale)
     return out.reshape(*lead, n)
@@ -165,12 +186,15 @@ def decode_ingest(
     bq: jax.Array | None = None,
     bk: jax.Array | None = None,
     bv: jax.Array | None = None,
+    layer=None,
     plan: Optional[ExecutionPlan] = None,
 ):
     """Fused decode-ingest stage: rmsnorm → QKV → bias → rope in one
     seam (kernels/decode_fuse.py on the Pallas backend, the bit-exact
     op-chain composition in ``ref.py`` otherwise; a GEMM seam, so it
-    routes on the plan's matmul backend). Returns q (B,1,HQ,Dh),
+    routes on the plan's matmul backend). With a ``layer`` the three
+    weights are ``(L, D, N)`` stacks, which the kernel reads in place;
+    the norm scale and biases are that layer's. Returns q (B,1,HQ,Dh),
     k/v (B,1,HK,Dh)."""
     mp = (plan or DEFAULT_PLAN).matmul
     interpret, spec = kernel_target()
@@ -186,11 +210,13 @@ def decode_ingest(
             head_dim=head_dim, rope_theta=rope_theta, eps=eps,
             use_rope=use_rope, bq=bq, bk_bias=bk, bv=bv,
             wq_scale=wq_scale, wk_scale=wk_scale, wv_scale=wv_scale,
-            spec=spec, interpret=interpret,
+            layer=layer, spec=spec, interpret=interpret,
         )
         return (q.reshape(b, s, num_heads, head_dim),
                 k.reshape(b, s, num_kv_heads, head_dim),
                 v.reshape(b, s, num_kv_heads, head_dim))
+    wq, wk, wv, wq_scale, wk_scale, wv_scale = at_layer(
+        (wq, wk, wv, wq_scale, wk_scale, wv_scale), layer)
     return ref.decode_ingest_ref(
         x, norm_scale, wq, wk, wv, positions,
         num_heads=num_heads, num_kv_heads=num_kv_heads, head_dim=head_dim,
@@ -205,11 +231,13 @@ def oproj_residual(
     wo: jax.Array,      # (HQ*Dh, D)
     resid: jax.Array,   # (B, 1, D)
     *,
+    layer=None,
     plan: Optional[ExecutionPlan] = None,
 ) -> jax.Array:
     """Fused attention epilogue ``resid + o @ wo`` (the o_proj GEMM with
     the residual add riding its epilogue on the Pallas backend; the
-    bit-exact op-chain composition otherwise)."""
+    bit-exact op-chain composition otherwise). With a ``layer``, ``wo``
+    is an ``(L, Q, D)`` stack, which the kernel reads in place."""
     mp = (plan or DEFAULT_PLAN).matmul
     interpret, spec = kernel_target()
     wo, wo_scale = _wparts(wo)
@@ -218,9 +246,10 @@ def oproj_residual(
         b, s, qd = o.shape
         out = oproj_residual_fused(
             o.reshape(b * s, qd), wo, resid.reshape(b * s, -1),
-            w_scale=wo_scale, spec=spec, interpret=interpret,
+            w_scale=wo_scale, layer=layer, spec=spec, interpret=interpret,
         )
         return out.reshape(resid.shape)
+    wo, wo_scale = at_layer((wo, wo_scale), layer)
     return ref.oproj_residual_ref(o, wo, resid, w_scale=wo_scale)
 
 
@@ -232,12 +261,15 @@ def ffn_norm(
     *,
     activation: str = "swiglu",
     eps: float,
+    layer=None,
     plan: Optional[ExecutionPlan] = None,
 ) -> jax.Array:
     """Fused mlp-ingest stage: rmsnorm → gate/up GEMMs → act(g)*u in one
     seam (kernels/decode_fuse.py on the Pallas backend; on XLA the
     oracle composes whichever op chain the plan's ``fused_ffn`` knob
-    selects, so the seam stays bitwise). Returns (B, 1, F) — feed to
+    selects, so the seam stays bitwise). With a ``layer`` the weights
+    are ``(L, D, F)`` stacks, which the kernel reads in place; the norm
+    scale is that layer's. Returns (B, 1, F) — feed to
     :func:`oproj_residual` with ``w_down`` for the full seam."""
     p = plan or DEFAULT_PLAN
     interpret, spec = kernel_target()
@@ -249,10 +281,12 @@ def ffn_norm(
         out = ffn_norm_fused(
             x.reshape(b * s, d), norm_scale, w_gate, w_up,
             activation=activation, eps=eps,
-            wg_scale=wg_scale, wu_scale=wu_scale, spec=spec,
+            wg_scale=wg_scale, wu_scale=wu_scale, layer=layer, spec=spec,
             interpret=interpret,
         )
         return out.reshape(b, s, -1)
+    w_gate, w_up, wg_scale, wu_scale = at_layer(
+        (w_gate, w_up, wg_scale, wu_scale), layer)
     return ref.ffn_norm_ref(x, norm_scale, w_gate, w_up,
                             activation=activation, eps=eps,
                             fused=p.fused_ffn.fused,

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from repro.config import ModelConfig, SoftmaxPhiConfig
 from repro.core.plan import DEFAULT_PLAN, ExecutionPlan
 from repro.kernels import ops, quant
+from repro.kernels.ops import at_layer
 from repro.models.kvlayout import PagedLayout
 
 Params = dict
@@ -225,28 +226,31 @@ def attention_block(
 
 def decode_ingest(
     ctx: LayerCtx, norm_p: Params, p: Params, x: jax.Array,
-    position: jax.Array, *, use_rope: bool = True,
+    position: jax.Array, *, use_rope: bool = True, layer=None,
 ):
     """Stage A: pre-attention ingest on the residual stream.
 
-    x: (B, 1, D) un-normed; position: (B,). Returns q (B,1,HQ,Dh),
-    k/v (B,1,HK,Dh). The seam claims rmsnorm models only — layernorm
-    families keep the op chain (documented fallback).
+    x: (B, 1, D) un-normed; position: (B,). With a ``layer``, ``norm_p``
+    and ``p`` are stacked params (:func:`at_layer`). Returns q
+    (B,1,HQ,Dh), k/v (B,1,HK,Dh). The seam claims rmsnorm models only —
+    layernorm families keep the op chain (documented fallback).
     """
     cfg = ctx.cfg
     if cfg.norm == "rmsnorm":
+        scale, bq, bk, bv = at_layer(
+            (norm_p["scale"], p.get("bq"), p.get("bk"), p.get("bv")), layer)
         q, k, v = ops.decode_ingest(
-            x, norm_p["scale"], p["wq"], p["wk"], p["wv"], position,
+            x, scale, p["wq"], p["wk"], p["wv"], position,
             num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
             head_dim=cfg.head_dim, rope_theta=cfg.rope_theta,
             eps=cfg.rms_norm_eps, use_rope=use_rope,
-            bq=p.get("bq"), bk=p.get("bk"), bv=p.get("bv"),
-            plan=ctx.plan,
+            bq=bq, bk=bk, bv=bv, layer=layer, plan=ctx.plan,
         )
         return (ctx.shard(q, "act_qkv"), ctx.shard(k, "act_kv"),
                 ctx.shard(v, "act_kv"))
-    h = norm(cfg, norm_p, x)
-    return attention_qkv(ctx, p, h, position[:, None], use_rope=use_rope)
+    h = norm(cfg, at_layer(norm_p, layer), x)
+    return attention_qkv(ctx, at_layer(p, layer), h, position[:, None],
+                         use_rope=use_rope)
 
 
 def decode_attend(
@@ -283,13 +287,14 @@ def decode_attend(
 
 
 def decode_epilogue(ctx: LayerCtx, p: Params, o: jax.Array,
-                    resid: jax.Array) -> jax.Array:
-    """Stage C: ``resid + o @ wo`` — one fused dispatch."""
-    return ops.oproj_residual(o, p["wo"], resid, plan=ctx.plan)
+                    resid: jax.Array, *, layer=None) -> jax.Array:
+    """Stage C: ``resid + o @ wo`` — one fused dispatch (``layer`` as in
+    :func:`decode_ingest`)."""
+    return ops.oproj_residual(o, p["wo"], resid, layer=layer, plan=ctx.plan)
 
 
 def decode_mlp(ctx: LayerCtx, norm_p: Params, p: Params,
-               x: jax.Array) -> jax.Array:
+               x: jax.Array, *, layer=None) -> jax.Array:
     """Stage D: the full MLP half — mlp_norm → gate/up → activation →
     down-projection → residual add.
 
@@ -297,17 +302,19 @@ def decode_mlp(ctx: LayerCtx, norm_p: Params, p: Params,
     gate/up pair) and ``ops.oproj_residual`` reused for
     ``x + h @ w_down`` (the same GEMM-into-residual shape as stage C).
     The seam claims rmsnorm + glu families only — others keep the op
-    chain (same documented fallback as stage A).
+    chain (same documented fallback as stage A). ``layer`` as in
+    :func:`decode_ingest`.
     """
     cfg = ctx.cfg
     if cfg.norm == "rmsnorm" and cfg.activation in ("swiglu", "geglu"):
-        h = ops.ffn_norm(x, norm_p["scale"], p["w_gate"], p["w_up"],
-                         activation=cfg.activation,
-                         eps=cfg.rms_norm_eps, plan=ctx.plan)
+        h = ops.ffn_norm(x, at_layer(norm_p["scale"], layer), p["w_gate"],
+                         p["w_up"], activation=cfg.activation,
+                         eps=cfg.rms_norm_eps, layer=layer, plan=ctx.plan)
         h = ctx.shard(h, "act_ffn")
-        return ops.oproj_residual(h, p["w_down"], x, plan=ctx.plan)
-    h = norm(cfg, norm_p, x)
-    return x + mlp_block(ctx, p, h)
+        return ops.oproj_residual(h, p["w_down"], x, layer=layer,
+                                  plan=ctx.plan)
+    h = norm(cfg, at_layer(norm_p, layer), x)
+    return x + mlp_block(ctx, p, h, layer=layer)
 
 
 def attention_decode_block(
@@ -531,23 +538,31 @@ def mlp_params(cfg: ModelConfig, key, d_ff: int | None = None) -> Params:
     }
 
 
-def mlp_block(ctx: LayerCtx, p: Params, x: jax.Array) -> jax.Array:
+def mlp_block(ctx: LayerCtx, p: Params, x: jax.Array, *,
+              layer=None) -> jax.Array:
+    """The MLP on normed ``x``. With a ``layer``, ``p`` is stacked
+    (:func:`at_layer`): the fused gate/up kernel reads its weight stacks
+    in place, and the XLA dots take the layer's slices, which XLA
+    prefetches into VMEM itself."""
     cfg = ctx.cfg
-    if cfg.activation in ("swiglu", "geglu"):
-        if ctx.plan.fused_ffn.fused:
-            # T2 extension: single fused kernel for gate+up+epilogue —
-            # the (M, F) gate/up tensors never round-trip HBM
-            h = ops.fused_ffn(x, p["w_gate"], p["w_up"],
-                              activation=cfg.activation, plan=ctx.plan)
-            h = ctx.shard(h, "act_ffn")
-        else:
-            g = ctx.matmul(x, p["w_gate"])
-            u = ctx.matmul(x, p["w_up"])
-            g = ctx.shard(g, "act_ffn")
-            u = ctx.shard(u, "act_ffn")
-            act = (jax.nn.silu(g) if cfg.activation == "swiglu"
-                   else jax.nn.gelu(g))
-            h = act * u
+    glu = cfg.activation in ("swiglu", "geglu")
+    if glu and ctx.plan.fused_ffn.fused:
+        # T2 extension: single fused kernel for gate+up+epilogue —
+        # the (M, F) gate/up tensors never round-trip HBM
+        h = ops.fused_ffn(x, p["w_gate"], p["w_up"],
+                          activation=cfg.activation, layer=layer,
+                          plan=ctx.plan)
+        h = ctx.shard(h, "act_ffn")
+        return ctx.matmul(h, at_layer(p["w_down"], layer))
+    p = at_layer(p, layer)
+    if glu:
+        g = ctx.matmul(x, p["w_gate"])
+        u = ctx.matmul(x, p["w_up"])
+        g = ctx.shard(g, "act_ffn")
+        u = ctx.shard(u, "act_ffn")
+        act = (jax.nn.silu(g) if cfg.activation == "swiglu"
+               else jax.nn.gelu(g))
+        h = act * u
     else:
         h = ctx.matmul(x, p["w_up"])
         h = ctx.shard(h, "act_ffn")

@@ -308,9 +308,11 @@ def make_decode_block(groups: int = 0):
         # family (repro.models.layers); only the FFN half differs — the
         # routed expert dispatch is not a fusable seam, so the MoE block
         # shares the attention-side seams and keeps its own tail
+        # with tables, ``p`` is the whole stacked params (as in the
+        # dense family's decode_block): the seams read it at ``layer``
         cfg = ctx.cfg
         q, k, v = L.decode_ingest(ctx, p["attn_norm"], p["attn"], x,
-                                  position)
+                                  position, layer=layer)
         if block_tables is None:
             o, ck, cv = L.decode_attend(
                 ctx, q, k, v, cache["k"], cache["v"], lengths
@@ -321,10 +323,10 @@ def make_decode_block(groups: int = 0):
                 ctx, q, k, v, cache, layer, block_tables, lengths,
                 decode_groups=decode_groups,
             )
-        x = L.decode_epilogue(ctx, p["attn"], o, x)
-        h = L.norm(cfg, p["mlp_norm"], x)
-        y, _ = moe_block(ctx, p["moe"], h, groups=groups or ctx.moe_groups,
-                         zero_drop=True)
+        x = L.decode_epilogue(ctx, p["attn"], o, x, layer=layer)
+        h = L.norm(cfg, L.at_layer(p["mlp_norm"], layer), x)
+        y, _ = moe_block(ctx, L.at_layer(p["moe"], layer), h,
+                         groups=groups or ctx.moe_groups, zero_drop=True)
         return ctx.shard(x + y, "act_resid"), cache
 
     return decode_block
@@ -345,6 +347,7 @@ def make_chunk_block(groups: int = 0):
     def chunk_block(ctx: LayerCtx, p: Params, x, cache, lengths,
                     chunk_lens, block_tables=None, layer=None):
         cfg = ctx.cfg
+        p = L.at_layer(p, layer)       # no kernel here reads a stack
         h = L.norm(cfg, p["attn_norm"], x)
         if block_tables is None:
             a, ck, cv = L.attention_chunk_block(

@@ -7,8 +7,10 @@ Uniform block interfaces:
   * cached decode-style: ``block_fn(p_i, x, cache_i) -> (x, new_cache_i)``
     where ``cache_i`` is the per-layer slice of a stacked cache pytree.
   * in-place decode-style (the paged pool):
-    ``block_fn(p_i, x, cache, layer) -> (x, cache)`` — the whole stacked
-    cache rides in the carry and each layer writes its own part of it.
+    ``block_fn(params, x, cache, layer) -> (x, cache)`` — the whole
+    stacked params and cache, and the layer's index: the cache rides in
+    the carry and each layer writes its own part of it, and the GEMM
+    kernels read the layer's weights out of the stacks in place.
 
 ``unroll=False`` uses ``lax.scan`` over the stacked-L params (compact HLO —
 the only while-loop in the whole program, with a known trip count);
@@ -112,18 +114,20 @@ def run_stack_in_place(layers_params, x, cache, block_fn: Callable,
     place: the cache is carried whole (never sliced into per-layer ``xs``
     and reassembled from ``ys``), and ``block_fn`` gets the layer index to
     address its own part. Only what the layers write changes, so a donated
-    cache is updated in its own buffer. ``unroll=True`` passes the index
-    as a Python int."""
+    cache is updated in its own buffer. The params are not sliced either:
+    the scan runs over the layer index alone and ``block_fn`` gets the
+    whole stack, so a kernel can read its layer's weights in place (a
+    scanned slice of a stack reaches a Pallas kernel only as a copy).
+    ``unroll=True`` passes the index as a Python int."""
+    num_layers = num_layers_of(layers_params)
     if unroll:
-        for layer, p_i in enumerate(unstack(layers_params)):
-            x, cache = block_fn(p_i, x, cache, layer)
+        for layer in range(num_layers):
+            x, cache = block_fn(layers_params, x, cache, layer)
         return x, cache
 
-    def layer_scan_body(carry, xs):
-        p_i, layer = xs
-        return block_fn(p_i, *carry, layer), None
+    def layer_scan_body(carry, layer):
+        return block_fn(layers_params, *carry, layer), None
 
-    layers = jnp.arange(num_layers_of(layers_params), dtype=jnp.int32)
     (x, cache), _ = jax.lax.scan(layer_scan_body, (x, cache),
-                                 (layers_params, layers))
+                                 jnp.arange(num_layers, dtype=jnp.int32))
     return x, cache

@@ -75,10 +75,12 @@ def decode_block(ctx: LayerCtx, p: Params, x: jax.Array, position: jax.Array,
     """One-token decode block over either KV layout.
 
     ``block_tables is None`` means ``cache`` is this layer's dense (B, S,
-    HK, Dh) slot cache; with tables it is the whole stacked (L, NP, PS,
-    HK, Dh) page pool, addressed through the (B, NB) logical→physical map
-    at layer ``layer`` and updated in place. The discriminator is resolved
-    at trace time — each engine layout compiles exactly one path.
+    HK, Dh) slot cache and ``p`` its params; with tables ``cache`` is the
+    whole stacked (L, NP, PS, HK, Dh) page pool, addressed through the
+    (B, NB) logical→physical map at layer ``layer`` and updated in place,
+    and ``p`` the whole stacked params, which the seams read at ``layer``
+    (``ops.at_layer``). The discriminator is resolved at trace time —
+    each engine layout compiles exactly one path.
     ``decode_groups`` (paged only) switches to the prefix-shared grouped
     attention path.
 
@@ -86,7 +88,8 @@ def decode_block(ctx: LayerCtx, p: Params, x: jax.Array, position: jax.Array,
     epilogue → mlp, see :mod:`repro.models.layers`); the ingest,
     epilogue and mlp seams are one dispatch each.
     """
-    q, k, v = L.decode_ingest(ctx, p["attn_norm"], p["attn"], x, position)
+    q, k, v = L.decode_ingest(ctx, p["attn_norm"], p["attn"], x, position,
+                              layer=layer)
     if block_tables is None:
         o, ck, cv = L.decode_attend(
             ctx, q, k, v, cache["k"], cache["v"], lengths
@@ -97,8 +100,8 @@ def decode_block(ctx: LayerCtx, p: Params, x: jax.Array, position: jax.Array,
             ctx, q, k, v, cache, layer, block_tables, lengths,
             decode_groups=decode_groups,
         )
-    x = L.decode_epilogue(ctx, p["attn"], o, x)
-    x = L.decode_mlp(ctx, p["mlp_norm"], p["mlp"], x)
+    x = L.decode_epilogue(ctx, p["attn"], o, x, layer=layer)
+    x = L.decode_mlp(ctx, p["mlp_norm"], p["mlp"], x, layer=layer)
     return ctx.shard(x, "act_resid"), cache
 
 
@@ -106,23 +109,24 @@ def chunk_block(ctx: LayerCtx, p: Params, x: jax.Array, cache: dict,
                 lengths: jax.Array, chunk_lens: jax.Array,
                 block_tables: Optional[jax.Array] = None, layer=None):
     """Chunked-prefill block (decode-shaped path) over either KV layout
-    (``cache``/``layer`` as in :func:`decode_block`)."""
+    (``p``/``cache``/``layer`` as in :func:`decode_block`)."""
     cfg = ctx.cfg
-    h = L.norm(cfg, p["attn_norm"], x)
+    h = L.norm(cfg, L.at_layer(p["attn_norm"], layer), x)
+    attn = L.at_layer(p["attn"], layer)
     if block_tables is None:
         a, ck, cv = L.attention_chunk_block(
-            ctx, p["attn"], h, cache["k"], cache["v"], lengths,
+            ctx, attn, h, cache["k"], cache["v"], lengths,
             chunk_lens
         )
         cache = {"k": ck, "v": cv}
     else:
         a, cache = L.attention_chunk_block_paged(
-            ctx, p["attn"], h, cache, layer, block_tables, lengths,
+            ctx, attn, h, cache, layer, block_tables, lengths,
             chunk_lens,
         )
     x = x + a
-    h = L.norm(cfg, p["mlp_norm"], x)
-    x = x + L.mlp_block(ctx, p["mlp"], h)
+    h = L.norm(cfg, L.at_layer(p["mlp_norm"], layer), x)
+    x = x + L.mlp_block(ctx, p["mlp"], h, layer=layer)
     return ctx.shard(x, "act_resid"), cache
 
 
@@ -271,10 +275,11 @@ def prefill(
 
 
 def _run_layers(layers_params, x, cache, block_tables, block_fn, *, unroll):
-    """The cached depth loop for either KV layout. The paged pool is
-    carried whole and written in place (``stack.run_stack_in_place``);
-    a dense slot cache goes through the scan per layer slice.
-    ``block_fn(p_i, x, cache, layer)``."""
+    """The cached depth loop for either KV layout. The paged pool and
+    the stacked params are carried whole, the pool written and the
+    weights read in place (``stack.run_stack_in_place``): ``block_fn(
+    params, x, cache, layer)``. A dense slot cache goes through the scan
+    per layer slice: ``block_fn(p_i, x, c_i, None)``."""
     if block_tables is not None:
         return stack.run_stack_in_place(layers_params, x, cache, block_fn,
                                         unroll=unroll)

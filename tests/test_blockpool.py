@@ -388,10 +388,13 @@ def test_paged_engine_rejects_recurrent_families():
 def _slab_stack(layers_params, x, cache, block_fn, *, unroll=False):
     """Per-layer slab reference for ``stack.run_stack_in_place``: the depth
     loop slices each layer's (NP, ...) slab out and stacks the new slabs
-    back, and every layer sees a one-layer pool — nothing it writes can
-    reach another layer's pages."""
+    back, and every layer sees a one-layer pool and one-layer params —
+    nothing it writes can reach another layer's pages."""
+    def one_layer(tree):
+        return jax.tree.map(lambda a: a[None], tree)
+
     def per_slab(p_i, x, c_i):
-        x, c = block_fn(p_i, x, jax.tree.map(lambda a: a[None], c_i), 0)
+        x, c = block_fn(one_layer(p_i), x, one_layer(c_i), 0)
         return x, jax.tree.map(lambda a: a[0], c)
 
     return stack.run_stack_cached(layers_params, x, cache, per_slab,

@@ -15,7 +15,10 @@ them, on four-layer stacks at phi3's and qwen's attention widths, and
 read the optimized HLO: the pool must be updated in place (no copy,
 slice or transpose of a pool or a layer's slab, the cache outputs
 aliased to the donated inputs) and every paged kernel must read it as a
-``(pages, PS, HK, D)`` operand.
+``(pages, PS, HK, D)`` operand. At full depth, under each model's
+committed plan, the weights must be read in place too: no weight- or
+stack-sized copy, slice or pad, and every GEMM seam kernel reads whole
+``(L, K, N)`` weight stacks.
 
 The topology is described inside a module fixture (never at import), so
 only the worker that runs this file loads the TPU compiler, and every
@@ -34,7 +37,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import configs, hardware
-from repro.core.plan import make_plan
+from repro.core.plan import ExecutionPlan, default_plan_path, make_plan
 from repro.kernels.chunk_attention import (
     paged_chunk_attention_sync,
     paged_chunk_attention_unified_max,
@@ -71,8 +74,8 @@ NUM_PAGES = SLOTS * NB
 HQ, HK, DH = CFG.num_heads, CFG.num_kv_heads, CFG.head_dim
 D, F = CFG.d_model, CFG.d_ff
 VOCAB = 152064            # lm-head width after models.layers.vocab_padded
-PLAN = os.path.join(os.path.dirname(os.path.dirname(__file__)), "plans",
-                    "qwen2-0.5b-tpu-v5e.json")
+PLANS = os.path.join(os.path.dirname(os.path.dirname(__file__)), "plans")
+PLAN = os.path.join(PLANS, "qwen2-0.5b-tpu-v5e.json")
 
 
 @pytest.fixture(scope="module")
@@ -335,17 +338,20 @@ PAGED_KERNELS = ("paged_decode_attention", "paged_chunk_attention",
                  "grouped_decode_")
 
 
-def _program_hlo(topo, shape, monkeypatch, arch, num_pages, program):
+def _program_hlo(topo, shape, monkeypatch, arch, num_pages, program, *,
+                 num_layers=STEP_LAYERS, plan=None):
     """Optimized HLO of one program that takes the pool donated, built as
     ``Engine`` builds it — a step (``decode``, ``grouped``, ``chunk``) or
     a page copy (``copy_page``, ``promote_upload``) — for a
-    ``STEP_LAYERS``-layer stack at ``arch``'s widths, on the pool in the
-    shapes the engine stores it in on a v5e."""
+    ``num_layers``-layer stack at ``arch``'s widths, on the pool in the
+    shapes the engine stores it in on a v5e, under ``plan`` (default: all
+    Pallas, fused chunk gather, grouped decode)."""
     monkeypatch.setattr(ops, "kernel_target",
                         lambda: (False, hardware.TPU_V5E))
-    cfg = dataclasses.replace(configs.get(arch), num_layers=STEP_LAYERS)
+    cfg = dataclasses.replace(configs.get(arch), num_layers=num_layers)
     api = get_model(cfg)
-    plan = make_plan("pallas", gather_chunk="fused", decode_group="grouped")
+    plan = plan or make_plan("pallas", gather_chunk="fused",
+                             decode_group="grouped")
     ctx = LayerCtx(cfg=cfg, plan=plan)
     spec = api.cache_spec(PagedLayout(num_pages, PAGE))
     cache = jax.tree.map(lambda s: shape(s.shape, s.dtype),
@@ -448,3 +454,87 @@ def test_page_copies_update_pool_in_place(topo, shape, monkeypatch, case,
     text, cfg, stored = _program_hlo(topo, shape, monkeypatch, arch,
                                      num_pages, program)
     _assert_in_place(text, cfg, num_pages, stored)
+
+
+# ---------------------------------------------------------------------------
+# Whole step programs at full depth: the GEMM seams read the stacked
+# weights in place
+# ---------------------------------------------------------------------------
+
+SEAMS = {"decode": ("decode_ingest_fused", "oproj_residual_fused",
+                    "ffn_norm_fused"),
+         "grouped": ("decode_ingest_fused", "oproj_residual_fused",
+                     "ffn_norm_fused"),
+         "chunk": ("fused_ffn_up",)}
+# copies, slices and pads: any of them on a weight moves it all
+WEIGHT_MOVES = re.compile(r"copy|slice|pad")
+
+
+def _weight_shapes(cfg):
+    """The (K, N) of every GEMM weight of a layer."""
+    d = cfg.d_model
+    return {(d, cfg.q_dim), (d, cfg.kv_dim), (cfg.q_dim, d),
+            (d, cfg.d_ff), (cfg.d_ff, d)}
+
+
+def _top_level(text):
+    """``(name, output type, opcode, line)`` of every instruction outside
+    fusion bodies: what the program runs. A slice inside a fusion (an
+    XLA dot reading its layer's weight) moves nothing by itself."""
+    fused = set(re.findall(r"\bfusion\(.*?calls=%([\w.\-]+)", text))
+    out, comp = [], None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) ", line)
+        if head and not line.startswith(" "):
+            comp = head[1]
+            continue
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([\w\-]+)\(",
+                     line)
+        if m and comp not in fused:
+            out.append((m[1], m[2], m[3], line))
+    return out
+
+
+@pytest.mark.parametrize("program", sorted(SEAMS))
+@pytest.mark.parametrize("case", sorted(STEP_CASES))
+def test_step_reads_weights_in_place(topo, shape, monkeypatch, case,
+                                     program):
+    """The served step programs, at full depth under the model's
+    committed plan, hold no copy, slice or pad whose output is a layer's
+    weight or a weight stack, and every GEMM seam kernel takes its
+    weights as whole ``(L, K, N)`` stacks, read at the layer index."""
+    arch, num_pages = STEP_CASES[case]
+    full = configs.get(arch)
+    plan = ExecutionPlan.load(default_plan_path(full, hardware.TPU_V5E,
+                                                PLANS), cfg=full)
+    text, cfg, _ = _program_hlo(topo, shape, monkeypatch, arch, num_pages,
+                                program, num_layers=full.num_layers,
+                                plan=plan)
+    weights = _weight_shapes(cfg)
+
+    def weight_dims(types):
+        return [dims for dims in (tuple(int(x) for x in t.split(","))
+                                  for t in re.findall(r"bf16\[([\d,]+)\]",
+                                                      types))
+                if dims[-2:] in weights]
+
+    instructions = _top_level(text)
+    moved = [line.strip()[:160] for name, out, op, line in instructions
+             if WEIGHT_MOVES.search(name + " " + op) and weight_dims(out)]
+    assert not moved, "weight-sized moves:\n" + "\n".join(moved)
+
+    seen = set()
+    for name, _, _, line in instructions:
+        kernel = re.match(r"(" + "|".join(SEAMS[program]) + r")(\.\d+)?$",
+                          name)
+        if not kernel:
+            continue
+        seen.add(kernel[1])
+        operands = re.search(
+            r"operand_layout_constraints=\{((?:[^{}]|\{[^{}]*\})*)\}",
+            line)[1]
+        dims = weight_dims(operands)
+        assert dims, line[:160]
+        assert all(len(d) == 3 and d[0] == cfg.num_layers for d in dims), \
+            (name, dims)
+    assert seen == set(SEAMS[program])
